@@ -308,7 +308,7 @@ def save_json(name: str, obj) -> None:
 # queue_wait_p50_s / queue_wait_p95_s and the sched_* counters (chunks,
 # budget-limited steps, aging promotions, peak step prefill tokens),
 # BENCH_serving adds compile_cache cold/warm prefill+decode compile seconds
-# (EngineConfig.compile_cache_dir), and the oversubscribed mixed-prompt
+# (the persistent compilation cache), and the oversubscribed mixed-prompt
 # chunked-prefill arm lands as BENCH_serving_sched.json (token identity vs
 # the monolithic oracle, itl_p95 <= 2x itl_p50 tail bound, ttft_p95
 # improvement).

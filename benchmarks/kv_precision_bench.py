@@ -37,6 +37,7 @@ import numpy as np
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs.base import ModelConfig
 from repro.models import transformer as T
 from repro.serving import (
@@ -232,6 +233,7 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true",
                     help="smaller pools / fewer requests (CI smoke)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # Lane-bound granularity: the token ratio at hd=128 is 1.94, but the
     # lane bound floors it — a budget below 10 int8 lanes rounds the int4

@@ -46,6 +46,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs.base import ModelConfig
 from repro.models.attention import attention_decode, attention_params_shape
 from repro.serving import kv_cache as kvc
@@ -133,6 +134,7 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true", help="fewer repeats")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     reps = 5 if args.quick else 11
 
     metrics = {}

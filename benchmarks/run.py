@@ -15,6 +15,7 @@ import argparse
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
 from . import (
     roofline,
     serving_throughput,
@@ -45,6 +46,7 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default="", help="comma-separated table names")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     names = [n.strip() for n in args.only.split(",") if n.strip()] or list(TABLES)
 
     failures = []

@@ -38,6 +38,7 @@ import numpy as np
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import smoke_config
 from repro.core.apply import quantize_params
 from repro.core.recipe import QuantRecipe
@@ -120,6 +121,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     add_log_level_arg(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     setup_logging(args.log_level)
 
     n_req = args.n_requests or (6 if args.quick else 10)

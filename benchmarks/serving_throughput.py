@@ -28,8 +28,8 @@ trajectory tracks:
   (one chunk bounds any decode stall), short-class ``ttft_p95`` strictly
   improved;
 * **compile cache** — cold-vs-warm prefill/decode compile seconds through
-  ``EngineConfig.compile_cache_dir`` (the JAX persistent compilation
-  cache), reported in ``BENCH_serving``;
+  the JAX persistent compilation cache (``repro.launch.compile_cache``),
+  reported in ``BENCH_serving``;
 * **observability overhead** (schema v8) — the same workload rerun with
   the span ring + metrics registry live (``trace=True``), exporting
   ``results/TRACE_serving.json`` (Chrome trace, Perfetto-loadable),
@@ -63,6 +63,7 @@ import numpy as np
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import smoke_config
 from repro.core.apply import quantize_params
 from repro.core.recipe import QuantRecipe
@@ -364,13 +365,18 @@ def run_sched_arm(cfg, params, ecfg, *, quick, seed):
 
 def run_compile_cache_arm(cfg, params, ecfg, *, lengths, max_new):
     """Cold-vs-warm compile seconds through the JAX persistent compilation
-    cache (``EngineConfig.compile_cache_dir``): the warm engine re-traces
-    its jits (fresh python wrappers) but deserializes the executables the
-    cold engine persisted, so its compile seconds collapse to trace time."""
-    import tempfile
+    cache: the warm engine re-traces its jits (fresh python wrappers) but
+    deserializes the executables the cold engine persisted, so its compile
+    seconds collapse to trace time. The arm owns a fixed subdirectory of the
+    cache and empties it first, so its cold engine starts cold; the process
+    keeps that directory afterwards."""
+    import shutil
 
-    cache_dir = tempfile.mkdtemp(prefix="repro-compile-cache-")
-    arm = ecfg.replace(compile_cache_dir=cache_dir, attn_probe=False)
+    from repro.launch.compile_cache import cache_root, enable_compile_cache
+
+    shutil.rmtree(cache_root() / "compile_cache_arm", ignore_errors=True)
+    cache_dir = enable_compile_cache("compile_cache_arm")
+    arm = ecfg.replace(attn_probe=False)
     _, cold = run_engine(cfg, params, arm, lengths=lengths, max_new=max_new)
     _, warm = run_engine(cfg, params, arm, lengths=lengths, max_new=max_new)
     log.info(
@@ -518,6 +524,7 @@ def main(argv=None):
     add_engine_config_args(ap, defaults=EngineConfig(max_batch=4, max_len=128),
                            skip=("spec", "attn_probe", "trace"))
     args = ap.parse_args(argv)
+    enable_compile_cache()
     setup_logging(args.log_level)
 
     n_req = args.n_requests or (6 if args.quick else 16)

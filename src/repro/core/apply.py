@@ -42,6 +42,9 @@ from .recipe import QuantRecipe
 __all__ = [
     "fake_quantize_params",
     "quantize_params",
+    "quant_slice",
+    "slice_form",
+    "stack_linears",
     "path_str",
     "act_scales_from_collector",
 ]
@@ -155,47 +158,51 @@ def fake_quantize_params(params, recipe: QuantRecipe):
     return jax.tree_util.tree_map_with_path(visit, params)
 
 
+def quant_slice(w: np.ndarray, recipe: QuantRecipe) -> OCSQuantLinear:
+    """One [Cin, Cout] slice through the recipe's OCS + quantize pipeline."""
+    return make_ocs_quant_linear(
+        w,
+        recipe.ocs_ratio,
+        recipe.w_bits,
+        qa=recipe.qa_split,
+        clip_method=recipe.w_clip,
+        per_channel=recipe.per_channel,
+        pad_to=recipe.pad_to,
+    )
+
+
+def slice_form(lin: OCSQuantLinear, recipe: QuantRecipe) -> OCSQuantLinear:
+    """One slice as it sits inside a stacked leaf: scales broadcast-ready
+    against the values (per-channel ``[1, Cout]``, per-tensor ``[1, 1]``)."""
+    ws = lin.weight.scale
+    scale = ws[None, :] if lin.weight.channel_axis == 1 else ws[None, None]
+    qp = QuantParams(values=lin.weight.values, scale=scale, bits=recipe.w_bits,
+                     channel_axis=None)
+    return OCSQuantLinear(
+        weight=qp, spec=lin.spec, n_orig=lin.n_orig, a_bits=recipe.a_bits
+    )
+
+
+def stack_linears(lins, lead: Tuple[int, ...], recipe: QuantRecipe) -> OCSQuantLinear:
+    """Restack per-slice linears (row-major over the ``lead`` stack dims)
+    into one OCSQuantLinear whose leaves carry ``lead`` again, so
+    ``lax.scan`` or a per-layer index keeps slicing them; index ``i`` of the
+    result is :func:`slice_form` of slice ``i``. ``lead == ()`` returns the
+    single slice as is."""
+    if not lead:
+        return lins[0]
+    forms = [slice_form(l, recipe) for l in lins]
+    return jax.tree.map(
+        lambda *xs: jnp.stack(xs).reshape(lead + xs[0].shape), *forms
+    )
+
+
 def _quant_linear_stacked(w, recipe: QuantRecipe) -> OCSQuantLinear:
     """Build a (possibly stacked) OCSQuantLinear from [..., Cin, Cout]."""
     w = np.asarray(w, dtype=np.float32)
-    lead = w.shape[:-2]
     flat = w.reshape((-1,) + w.shape[-2:])
-    lins = [
-        make_ocs_quant_linear(
-            flat[i],
-            recipe.ocs_ratio,
-            recipe.w_bits,
-            qa=recipe.qa_split,
-            clip_method=recipe.w_clip,
-            per_channel=recipe.per_channel,
-            pad_to=recipe.pad_to,
-        )
-        for i in range(flat.shape[0])
-    ]
-    if not lead:
-        return lins[0]
-
-    # Restack: values/scales/specs get the leading dims back so lax.scan can
-    # slice per step. Scales are stored broadcast-ready against the values.
-    def stack(get):
-        return jnp.stack([get(l) for l in lins]).reshape(
-            lead + get(lins[0]).shape
-        )
-
-    values = stack(lambda l: l.weight.values)
-    if lins[0].weight.channel_axis == 1:  # per-channel: [Cout] -> [..., 1, Cout]
-        scale = stack(lambda l: l.weight.scale[None, :])
-    else:  # per-tensor: scalar -> [..., 1, 1]
-        scale = stack(lambda l: l.weight.scale[None, None])
-    qp = QuantParams(values=values, scale=scale, bits=recipe.w_bits, channel_axis=None)
-    spec = OCSSpec(
-        src=stack(lambda l: l.spec.src),
-        mult=stack(lambda l: l.spec.mult),
-        bias=stack(lambda l: l.spec.bias),
-    )
-    return OCSQuantLinear(
-        weight=qp, spec=spec, n_orig=int(w.shape[-2]), a_bits=recipe.a_bits
-    )
+    lins = [quant_slice(flat[i], recipe) for i in range(flat.shape[0])]
+    return stack_linears(lins, w.shape[:-2], recipe)
 
 
 def quantize_params(params, recipe: QuantRecipe):

@@ -22,6 +22,9 @@ import numpy as np
 
 __all__ = ["StreamingHistogram", "ChannelStats"]
 
+# Elements per pass over a large host array (a 16 MiB float32 temporary).
+CHUNK = 1 << 22
+
 
 class StreamingHistogram:
     """Histogram of |x| over [0, range) with automatic range doubling."""
@@ -34,20 +37,27 @@ class StreamingHistogram:
         self.max_seen = 0.0
 
     def update(self, x: np.ndarray) -> None:
-        ax = np.abs(np.asarray(x, dtype=np.float32)).ravel()
-        if ax.size == 0:
+        flat = np.asarray(x, dtype=np.float32).ravel()
+        if flat.size == 0:
             return
-        m = float(ax.max())
+        # Chunked passes: the temporaries stay CHUNK elements however large
+        # x is (a weight slice is hundreds of MB; bins are integer counts,
+        # so the chunking changes no result).
+        chunks = [flat[i:i + CHUNK] for i in range(0, flat.size, CHUNK)]
+        m = float(np.max([np.abs(c).max() for c in chunks]))
         self.max_seen = max(self.max_seen, m)
         if self.range == 0.0:
             self.range = m if m > 0 else 1.0
         while m > self.range:
             self._double_range()
-        idx = np.minimum(
-            (ax * (self.n_bins / self.range)).astype(np.int64), self.n_bins - 1
-        )
-        np.add.at(self.counts, idx, 1)
-        self.total += ax.size
+        # Bin index = min(floor(|x| * n / range), n - 1), computed in place
+        # (clamping before the integer cast gives the same bins).
+        for c in chunks:
+            ax = np.abs(c)
+            np.multiply(ax, self.n_bins / self.range, out=ax)
+            np.minimum(ax, self.n_bins - 1, out=ax)
+            self.counts += np.bincount(ax.astype(np.int32), minlength=self.n_bins)
+        self.total += flat.size
 
     def _double_range(self) -> None:
         # Fold pairs of bins together: [0,R) -> [0,2R) with exact mass transfer.

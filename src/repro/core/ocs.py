@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .clipping import find_clip
-from .histogram import ChannelStats, StreamingHistogram
+from .histogram import CHUNK, ChannelStats, StreamingHistogram
 from .quantizer import QuantParams, qmax, quantize_tensor
 
 __all__ = [
@@ -117,44 +117,68 @@ def expand_activations(x: jnp.ndarray, spec: OCSSpec) -> jnp.ndarray:
 # Weight OCS (host-side, offline)
 
 
-def _split_rows_once(w: np.ndarray, src: np.ndarray, idx: int, delta: float, qa: bool):
-    """Split row ``idx`` of expanded weight ``w`` into two rows."""
-    row = w[idx]
-    if qa and delta > 0:
-        # (w - Δ/2)/2 , (w + Δ/2)/2 — exact quantization preservation (Eq. 6/7).
-        r1 = (row - 0.5 * delta) / 2.0
-        r2 = (row + 0.5 * delta) / 2.0
-    else:
-        r1 = row / 2.0
-        r2 = row / 2.0
-    w = np.concatenate([w, r2[None]], axis=0)
-    w[idx] = r1
-    src = np.concatenate([src, src[idx : idx + 1]], axis=0)
-    return w, src
+def _row_absmax(w: np.ndarray) -> np.ndarray:
+    """max |w| of each row, a block of rows at a time (no |w|-sized copy)."""
+    rows = max(1, CHUNK // max(1, math.prod(w.shape[1:])))
+    return np.concatenate([np.abs(w[i:i + rows]).max(axis=1)
+                           for i in range(0, max(w.shape[0], 1), rows)])
 
 
-def _run_splits(w: np.ndarray, n_splits: int, delta: float, qa: bool):
-    src = np.arange(w.shape[0], dtype=np.int32)
-    w = w.copy()
-    for _ in range(n_splits):
-        # Channel containing the current global max |value| (§3.4).
-        idx = int(np.argmax(np.abs(w).max(axis=1)))
-        w, src = _split_rows_once(w, src, idx, delta, qa)
-    return w, src
+def _run_splits(w: np.ndarray, n_splits: int, delta: float, qa: bool,
+                out: Optional[np.ndarray] = None):
+    """Split ``n_splits`` times, each time the row holding the current global
+    max |value| (§3.4); split rows are appended after the originals.
+
+    Linear in the matrix size: the per-row max |value| is kept as a vector
+    and only the two rows a split touches are re-reduced, and the output is
+    allocated once (or is ``out``, every row of which is overwritten).
+    ``argmax`` over that vector picks the first of tied rows, as it would
+    over the whole matrix.
+    """
+    c = w.shape[0]
+    shape = (c + n_splits,) + w.shape[1:]
+    if out is None:
+        out = np.empty(shape, dtype=w.dtype)
+    elif out.shape != shape or out.dtype != w.dtype:
+        raise ValueError(f"out is {out.dtype}{out.shape}, want {w.dtype}{shape}")
+    out[:c] = w
+    src = np.empty(c + n_splits, dtype=np.int32)
+    src[:c] = np.arange(c, dtype=np.int32)
+    row_max = np.empty(c + n_splits, dtype=w.dtype)
+    row_max[:c] = _row_absmax(w)
+    for n in range(c, c + n_splits):
+        idx = int(np.argmax(row_max[:n]))
+        row = out[idx]
+        if qa and delta > 0:
+            # (w - Δ/2)/2 , (w + Δ/2)/2 — exact quantization preservation
+            # (Eq. 6/7).
+            r1 = (row - 0.5 * delta) / 2.0
+            r2 = (row + 0.5 * delta) / 2.0
+        else:
+            r1 = row / 2.0
+            r2 = row / 2.0
+        out[idx] = r1
+        out[n] = r2
+        src[n] = src[idx]
+        row_max[idx] = np.abs(r1).max()
+        row_max[n] = np.abs(r2).max()
+    return out, src
 
 
 def _run_splits_grouped(
-    w: np.ndarray, n_total: int, delta: float, qa: bool, groups: int
+    w: np.ndarray, n_total: int, delta: float, qa: bool, groups: int,
+    out: Optional[np.ndarray] = None,
 ):
     """Split within ``groups`` contiguous channel groups (TP-shard locality).
 
     Each group receives ``ceil(n_total / groups)`` splits of *its own* current
     max channel, so duplicated channels stay on the same tensor-parallel shard
     as their source and the expanded dim stays evenly shardable. ``groups=1``
-    reproduces the paper's global selection exactly.
+    reproduces the paper's global selection exactly, into ``out`` if given
+    (grouped splits allocate their own result).
     """
     if groups <= 1:
-        return _run_splits(w, n_total, delta, qa)
+        return _run_splits(w, n_total, delta, qa, out=out)
     c = w.shape[0]
     if c % groups:
         raise ValueError(f"channels {c} not divisible by groups {groups}")
@@ -201,9 +225,11 @@ def split_weights(
     thresh = find_clip(w_est, bits, clip_method)
     delta = thresh / qmax(bits)
     if qa:
+        # Each pass rewrites the previous one's buffer: only its clip
+        # threshold is kept.
         w_exp, src = w_est, src_est
         for _ in range(max(1, fixed_point_iters)):
-            w_exp, src = _run_splits_grouped(w, n, delta, True, groups)
+            w_exp, src = _run_splits_grouped(w, n, delta, True, groups, out=w_exp)
             new_thresh = find_clip(w_exp, bits, clip_method)
             new_delta = new_thresh / qmax(bits)
             if abs(new_delta - delta) <= 1e-7 * max(delta, 1e-12):

@@ -55,6 +55,7 @@ def storage_dtype(bits: int):
     return jnp.int32
 
 
+@partial(jax.jit, static_argnames=("channel_axis",))
 def _reduce_absmax(x: jnp.ndarray, channel_axis: Optional[int]) -> jnp.ndarray:
     if channel_axis is None:
         return jnp.max(jnp.abs(x))
@@ -113,6 +114,9 @@ def quantize_int(
     return q.astype(storage_dtype(bits))
 
 
+_quantize_int = jax.jit(quantize_int, static_argnames=("bits", "channel_axis"))
+
+
 def dequantize(
     q: jnp.ndarray,
     scale: jnp.ndarray,
@@ -166,6 +170,11 @@ def quantize_tensor(
     channel_axis: Optional[int] = None,
     clip: Optional[jnp.ndarray] = None,
 ) -> QuantParams:
+    """The range reduction and the integer values each run as one compiled
+    program: on a large host array the elementwise steps fuse instead of
+    each materializing a float copy. (The scale's division by ``qmax`` stays
+    eager: compiled, a division by a constant becomes a multiplication by
+    its rounded reciprocal.)"""
     scale = compute_scale(x, bits, channel_axis=channel_axis, clip=clip)
-    q = quantize_int(x, scale, bits, channel_axis=channel_axis)
+    q = _quantize_int(x, scale, bits, channel_axis=channel_axis)
     return QuantParams(values=q, scale=scale, bits=bits, channel_axis=channel_axis)

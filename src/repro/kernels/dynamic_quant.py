@@ -26,12 +26,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["dynamic_quant_kernel", "dynamic_quant", "VMEM_BUDGET_BYTES"]
+from .budget import VMEM_BUDGET_BYTES, reroute
 
-# Per-program VMEM budget for the one-pass formulation: the [bm, K] f32 tile
-# plus the int8 output tile, double-buffered. ~16 MiB per core on v5e; keep
-# half for Mosaic scratch and the neighbouring kernels.
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+__all__ = ["dynamic_quant_kernel", "dynamic_quant", "VMEM_BUDGET_BYTES"]
 
 
 def _kernel(x_ref, q_ref, s_ref, *, qmax: float):
@@ -90,7 +87,10 @@ def dynamic_quant(
     reads of x instead of one, but correct at any K.
     """
     m, k = x.shape
-    if 2 * bm * k * (x.dtype.itemsize + 1) > vmem_budget_bytes:
+    # The [bm, K] input tile plus the int8 output tile, double-buffered.
+    need = 2 * bm * k * (x.dtype.itemsize + 1)
+    if need > vmem_budget_bytes:
+        reroute("dynamic_quant", x.shape, need, vmem_budget_bytes)
         return _dynamic_quant_xla(x, bits)
     pad = (-m) % bm
     if pad:

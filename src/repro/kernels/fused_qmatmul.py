@@ -45,8 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import compiler_params
-from .dynamic_quant import VMEM_BUDGET_BYTES  # one budget for both kernels
+from .budget import VMEM_BUDGET_BYTES, reroute
+from .paged_attention import unpack_nibbles
 
 __all__ = [
     "fused_qmatmul_kernel",
@@ -128,7 +128,7 @@ def fused_qmatmul_kernel(
             pltpu.VMEM((bm, ke), jnp.int8),  # quantized expanded row tile
             pltpu.VMEM((bm, 1), jnp.float32),  # per-row scales
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -189,6 +189,8 @@ def fused_quant_matmul(
     # times 2 for double buffering of the streamed operands.
     tile_bytes = bm * kp * 4 + bm * (kp + sp) + 2 * (kp + sp) * bn
     if tile_bytes > vmem_budget_bytes:
+        reroute("fused_quant_matmul", (m, kdim, ke, n), tile_bytes,
+                vmem_budget_bytes)
         return _xla_fallback(x, w8, src_tail, w_scale, bits, out_dtype)
 
     ws = jnp.broadcast_to(jnp.asarray(w_scale, jnp.float32).reshape(1, -1), (1, n))
@@ -266,9 +268,7 @@ def _w4a8_kernel(
 
     # Unpack the packed nibble block in VMEM: split-half layout means the
     # low nibbles are K rows [0, ke/2) and the high nibbles [ke/2, ke).
-    b8 = w4_ref[...].astype(jnp.int8)
-    lo = jnp.right_shift(jnp.left_shift(b8, 4), 4)
-    hi = jnp.right_shift(b8, 4)
+    lo, hi = (n.astype(jnp.int8) for n in unpack_nibbles(w4_ref[...]))
     half = ke // 2
     acc4 = jax.lax.dot_general(
         q_ref[:, :half], lo, (((1,), (0,)), ((), ())),
@@ -338,7 +338,7 @@ def w4a8_qmatmul_kernel(
             pltpu.VMEM((bm, max(t_pad, 1)), jnp.int8),  # outlier q gather
             pltpu.VMEM((bm, 1), jnp.float32),  # per-row scales
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -400,6 +400,8 @@ def w4a8_quant_matmul(
         + 2 * max(tp, 1) * bn            # w8 blocks (int8, dbl-buf)
     )
     if tile_bytes > vmem_budget_bytes:
+        reroute("w4a8_quant_matmul", (m, kdim, ke, n), tile_bytes,
+                vmem_budget_bytes)
         return w4a8_matmul_ref(
             x, w4, s4, w8, s8, src_tail, outlier_idx, bits, out_dtype
         )

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import compiler_params
 
 __all__ = ["ocs_matmul_kernel", "ocs_quant_matmul"]
 
@@ -120,7 +119,7 @@ def ocs_matmul_kernel(
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.int32 if int_path else jnp.float32)
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -55,15 +55,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .dynamic_quant import VMEM_BUDGET_BYTES
+from .budget import VMEM_BUDGET_BYTES, reroute
 
 __all__ = [
     "TRASH_PAGE",
     "KV4_QMAX",
     "quant_rows",
     "pack_int4",
+    "unpack_nibbles",
     "unpack_int4",
     "pool_kind",
+    "page_size",
     "append_rows",
     "paged_attention_gather_ref",
     "paged_attention_xla",
@@ -99,30 +101,39 @@ def quant_rows(x: jnp.ndarray, qmax: float = 127.0):
     return q.astype(jnp.int8), scale[..., 0]
 
 
-def pack_int4(q: jnp.ndarray) -> jnp.ndarray:
-    """Pack int8 nibble values (in [-8, 7]) two-per-byte along the last axis.
+def pack_int4(q: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """Pack int8 nibble values (in [-8, 7]) two-per-byte along ``axis``.
 
-    Split-half convention: byte ``j`` of a C-channel row holds channel ``j``
-    in its low nibble and channel ``j + C/2`` in its high nibble. Pack and
-    unpack are then contiguous half-row slices + a concat — no strided
-    interleave, which keeps the in-kernel (Mosaic) forms trivial.
+    Split-half convention: byte ``j`` holds element ``j`` in its low nibble
+    and element ``j + C/2`` in its high nibble (``C`` = the axis length).
+    Pack and unpack are then contiguous half slices + a concat — no strided
+    interleave, which keeps the in-kernel (Mosaic) forms trivial. The bit
+    operations run in int32 and narrow at the end: Mosaic has no 8-bit
+    vector shifts.
     """
-    c = q.shape[-1]
-    lo = q[..., : c // 2].astype(jnp.uint8) & jnp.uint8(0xF)
-    hi = q[..., c // 2 :].astype(jnp.uint8) & jnp.uint8(0xF)
-    return lo | jnp.left_shift(hi, 4)
+    axis = axis % q.ndim
+    c = q.shape[axis]
+    lo = jax.lax.slice_in_dim(q, 0, c // 2, axis=axis).astype(jnp.int32) & 0xF
+    hi = jax.lax.slice_in_dim(q, c // 2, c, axis=axis).astype(jnp.int32) & 0xF
+    return (lo | jnp.left_shift(hi, 4)).astype(jnp.uint8)
 
 
-def unpack_int4(b: jnp.ndarray) -> jnp.ndarray:
-    """Inverse of :func:`pack_int4`: uint8 [..., C/2] -> int8 [..., C].
+def unpack_nibbles(b: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Packed bytes -> (low, high) nibbles, each sign-extended, as int32.
 
-    Sign extension by int8 *arithmetic* shifts (``(b << 4) >> 4`` for the low
-    nibble, ``b >> 4`` for the high) — no lookup table, no compare/select.
+    Arithmetic shifts in int32 (``(w << 28) >> 28`` for the low nibble,
+    ``(w << 24) >> 28`` for the high) — no lookup table, no compare/select,
+    and no 8-bit vector shift, which Mosaic cannot lower.
     """
-    b8 = b.astype(jnp.int8)
-    lo = jnp.right_shift(jnp.left_shift(b8, 4), 4)
-    hi = jnp.right_shift(b8, 4)
-    return jnp.concatenate([lo, hi], axis=-1)
+    w = b.astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(w, 28), 28)
+    hi = jnp.right_shift(jnp.left_shift(w, 24), 28)
+    return lo, hi
+
+
+def unpack_int4(b: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """Inverse of :func:`pack_int4`: uint8 [.., C/2, ..] -> int8 [.., C, ..]."""
+    return jnp.concatenate(unpack_nibbles(b), axis=axis).astype(jnp.int8)
 
 
 def pool_kind(pool) -> str:
@@ -137,6 +148,16 @@ def pool_kind(pool) -> str:
     return "float"
 
 
+def page_size(pool) -> int:
+    """Tokens per page. Packed int4 pages store two tokens per byte row
+    (``[P, KV, ps/2, hd]``: row ``r`` holds token ``r`` in its low nibbles and
+    token ``r + ps/2`` in its high ones), so the value tile is lane-dense;
+    the scale arrays always have one entry per token."""
+    if "k_scale" in pool:
+        return pool["k_scale"].shape[2]
+    return pool["k"].shape[2]
+
+
 def append_rows(pool: Dict, k_new, v_new, table, pos) -> Dict:
     """XLA scatter of Q tokens' K/V rows through the block table.
 
@@ -145,7 +166,7 @@ def append_rows(pool: Dict, k_new, v_new, table, pos) -> Dict:
     ``serving.kv_cache.append_tokens`` (same clamp, same quant grid) minus
     the sharding constraint, which the model layer re-applies.
     """
-    ps = pool["k"].shape[2]
+    ps = page_size(pool)
     t = table.shape[1]
     qn = k_new.shape[1]
     lin = jnp.clip(pos[:, None] + jnp.arange(qn)[None, :], 0, t * ps - 1)
@@ -163,8 +184,22 @@ def append_rows(pool: Dict, k_new, v_new, table, pos) -> Dict:
     elif kind == "int4":
         k_q, k_s = quant_rows(k_new, qmax=KV4_QMAX)
         v_q, v_s = quant_rows(v_new, qmax=KV4_QMAX)
-        out["k"] = pool["k"].at[pidx, :, slot, :].set(pack_int4(k_q))
-        out["v"] = pool["v"].at[pidx, :, slot, :].set(pack_int4(v_q))
+        half = ps // 2
+        hi = (slot >= half)[:, :, None, None]
+        row = slot % half
+        for name, q in (("k", k_q), ("v", v_q)):
+            # Token ``slot`` owns one nibble of byte row ``slot % (ps/2)``:
+            # merge it into the current byte, one token at a time (two
+            # tokens of a verify window may share a row).
+            vals = out[name]
+            for j in range(qn):
+                cur = vals[pidx[:, j], :, row[:, j], :].astype(jnp.int32)
+                nib = q[:, j].astype(jnp.int32) & 0xF  # [B, KV, hd]
+                new = jnp.where(hi[:, j], (cur & 0x0F) | jnp.left_shift(nib, 4),
+                                (cur & 0xF0) | nib)
+                vals = vals.at[pidx[:, j], :, row[:, j], :].set(
+                    new.astype(jnp.uint8))
+            out[name] = vals
         out["k_scale"] = pool["k_scale"].at[pidx, :, slot].set(k_s)
         out["v_scale"] = pool["v_scale"].at[pidx, :, slot].set(v_s)
     else:
@@ -243,7 +278,7 @@ def paged_attention_gather_ref(pool, table, pos, q, k_new, v_new) -> Tuple:
     kernel (the legacy ``attention_decode`` int8 path additionally quantizes
     q and the softmax weights — that path lives on in the model layer)."""
     b, qn, h, hd = q.shape
-    kvh, ps = pool["k"].shape[1:3]
+    kvh, ps = pool["k"].shape[1], page_size(pool)
     t = table.shape[1]
     new_pool = append_rows(pool, k_new, v_new, table, pos)
     kind = pool_kind(pool)
@@ -260,10 +295,10 @@ def paged_attention_gather_ref(pool, table, pos, q, k_new, v_new) -> Tuple:
         qr = qn * rep
         rd = jnp.repeat(table != TRASH_PAGE, ps, axis=1)[:, None, :, None]
         kf = _dequant_zero_trash(
-            unpack_int4(flat(new_pool["k"][table])),
+            flat(unpack_int4(new_pool["k"][table], axis=-2)),
             flat(new_pool["k_scale"][table]), rd)
         vf = _dequant_zero_trash(
-            unpack_int4(flat(new_pool["v"][table])),
+            flat(unpack_int4(new_pool["v"][table], axis=-2)),
             flat(new_pool["v_scale"][table]), rd)
         q2 = _q_rows(q, kvh)  # [B, KV, QR, hd]
         bound = pos[:, None] + (jnp.arange(qr) // rep)[None, :]  # [B, QR]
@@ -327,7 +362,7 @@ def paged_attention_xla(
     masked out of every softmax (see the body comment).
     """
     b, qn, h, hd = q.shape
-    kvh, ps = pool["k"].shape[1:3]
+    kvh, ps = pool["k"].shape[1], page_size(pool)
     t = table.shape[1]
     rep = h // kvh
     qr = qn * rep
@@ -353,8 +388,8 @@ def paged_attention_xla(
             cols = jax.lax.dynamic_slice(table, (0, i), (b, 1))  # [B, 1]
             ok = cols != TRASH_PAGE
             safe = jnp.where(ok, cols, 1)
-            kf = unpack_int4(new_pool["k"][safe]).astype(jnp.float32)
-            vf = unpack_int4(new_pool["v"][safe]).astype(jnp.float32)
+            kf = unpack_int4(new_pool["k"][safe], axis=-2).astype(jnp.float32)
+            vf = unpack_int4(new_pool["v"][safe], axis=-2).astype(jnp.float32)
             kf = kf * new_pool["k_scale"][safe][..., None]
             vf = vf * new_pool["v_scale"][safe][..., None]
             # [B, 1, KV, ps, hd] -> [B, KV, ps, hd]
@@ -477,95 +512,123 @@ def _paged_attn_kernel(
     scaled = kind in ("int8", "int4")
     if scaled:
         (ks_in, vs_in, out_ref, k_out, v_out, ks_out, vs_out,
-         k_scr, v_scr, ks_scr, vs_scr, kw_scr, vw_scr, ksw_scr, vsw_scr,
-         sems) = rest
+         k_scr, v_scr, ks_scr, vs_scr, sems) = rest
     else:
-        (out_ref, k_out, v_out, k_scr, v_scr, kw_scr, vw_scr, sems) = rest
+        (out_ref, k_out, v_out, k_scr, v_scr, sems) = rest
     b = pl.program_id(0)
     g = pl.program_id(1)
     pos_b = pos_ref[b]
     qr = qn * rep
+    half = ps // 2
+
+    def copy_pages(pid, to_vmem: bool):
+        # Page tiles of (page pid, head g), plus the page's scale row. Reads
+        # go through the *output* refs (the aliased buffers), so every
+        # append is visible to the loads after it.
+        pairs = [(k_out.at[pid, g], k_scr), (v_out.at[pid, g], v_scr)]
+        if scaled:
+            pairs += [(ks_out.at[pl.ds(pid, 1)], ks_scr),
+                      (vs_out.at[pl.ds(pid, 1)], vs_scr)]
+        dmas = [
+            pltpu.make_async_copy(*((h, v) if to_vmem else (v, h)), sems.at[i])
+            for i, (h, v) in enumerate(pairs)
+        ]
+        for d in dmas:
+            d.start()
+        for d in dmas:
+            d.wait()
+
+    def scale_col(s_scr):
+        # This head's per-token scales as a [ps, 1] column, picked out of the
+        # page's lane-dense scale row by a select + lane sum (exact: one
+        # nonzero term per row; scales are positive and finite on real pages).
+        lanes = s_scr.shape[-1]
+        pick = (jax.lax.broadcasted_iota(jnp.int32, (ps, lanes), 1)
+                == g * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, lanes), 0))
+        return jnp.sum(jnp.where(pick, s_scr[...], 0.0), axis=1, keepdims=True)
+
+    def page_values(scr):
+        # Page tile -> [ps, hd] f32. Packed int4 tiles hold token r in the
+        # low nibble and token r + ps/2 in the high nibble of row r; nibbles
+        # are sign-extended by 32-bit shifts (see unpack_nibbles).
+        if kind == "int4":
+            lo, hi = unpack_nibbles(scr[...])
+            return jnp.concatenate([lo, hi], axis=0).astype(jnp.float32)
+        return scr[...].astype(jnp.float32)
 
     # ---- fused append: this program owns (lane b, head g)'s Q rows. Pages
     # past the prompt are never shared across lanes, so the only rows this
     # program ever reads back below are its own writes (waited on here).
+    # Each row is a read-modify-write of the whole page tile, in a 32-bit
+    # type: Mosaic can neither DMA one row of an 8-bit page (it is not
+    # aligned to the tiling) nor select on 8-bit vectors.
     for j in range(qn):
         lin = jnp.minimum(jnp.maximum(pos_b + j, 0), t * ps - 1)
         pid = table_ref[b, lin // ps]
         slot = lin % ps
         kr = kn_ref[0, 0, j : j + 1, :].astype(jnp.float32)  # [1, hd]
         vr = vn_ref[0, 0, j : j + 1, :].astype(jnp.float32)
-        if scaled:
+        copy_pages(pid, to_vmem=True)
+        if not scaled:
+            hit = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) == slot
+            for row, scr in ((kr, k_scr), (vr, v_scr)):
+                scr[...] = jnp.where(
+                    hit, row, scr[...].astype(jnp.float32)
+                ).astype(scr.dtype)
+        else:
             # quant_rows, inlined: same grid as every other pool writer
-            # (qmax 127 for int8 pages, KV4_QMAX for packed int4 pages —
-            # int4 rows are packed with pack_int4's split-half convention).
+            # (qmax 127 for int8 pages, KV4_QMAX for packed int4 pages).
             qm = 127.0 if kind == "int8" else KV4_QMAX
-            for row, w_scr, s_scr in ((kr, kw_scr, ksw_scr),
-                                      (vr, vw_scr, vsw_scr)):
+            lanes = ks_scr.shape[-1]
+            s_hit = (jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+                     == g * ps + slot)
+            for row, scr, s_scr in ((kr, k_scr, ks_scr), (vr, v_scr, vs_scr)):
                 amax = jnp.max(jnp.abs(row), axis=-1, keepdims=True)
                 sc = jnp.maximum(amax, 1e-30) * (1.0 / qm)
                 qrow = jnp.clip(
                     jnp.floor(row * (1.0 / sc) + 0.5), -qm, qm
-                ).astype(jnp.int8)
-                w_scr[...] = pack_int4(qrow) if kind == "int4" else qrow
-                s_scr[...] = sc
-            copies = (
-                (kw_scr, k_out.at[pid, g, pl.ds(slot, 1), :], 0),
-                (vw_scr, v_out.at[pid, g, pl.ds(slot, 1), :], 1),
-                (ksw_scr, ks_out.at[pid, g, pl.ds(slot, 1), :], 2),
-                (vsw_scr, vs_out.at[pid, g, pl.ds(slot, 1), :], 3),
-            )
-        else:
-            kw_scr[...] = kr.astype(kw_scr.dtype)
-            vw_scr[...] = vr.astype(vw_scr.dtype)
-            copies = (
-                (kw_scr, k_out.at[pid, g, pl.ds(slot, 1), :], 0),
-                (vw_scr, v_out.at[pid, g, pl.ds(slot, 1), :], 1),
-            )
-        dmas = [pltpu.make_async_copy(src, dst, sems.at[i])
-                for src, dst, i in copies]
-        for d in dmas:
-            d.start()
-        for d in dmas:
-            d.wait()
+                ).astype(jnp.int32)
+                cur = scr[...].astype(jnp.int32)
+                if kind == "int4":
+                    hi = slot >= half
+                    nib = qrow & 0xF
+                    new = jnp.where(hi, (cur & 0x0F) | jnp.left_shift(nib, 4),
+                                    (cur & 0xF0) | nib)
+                    r = slot - jnp.where(hi, half, 0)
+                    hit = jax.lax.broadcasted_iota(jnp.int32, (half, 1), 0) == r
+                else:
+                    new = qrow
+                    hit = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) == slot
+                scr[...] = jnp.where(hit, new, cur).astype(scr.dtype)
+                s_scr[...] = jnp.where(s_hit, sc, s_scr[...])
+        copy_pages(pid, to_vmem=False)
 
     # ---- flash loop over this lane's active pages only.
     qv = q_ref[0, 0]  # [QR, hd] f32, pre-scaled
     bound = pos_b + jax.lax.broadcasted_iota(jnp.int32, (qr, 1), 0) // rep
     n_active = jnp.minimum(t, (pos_b + qn - 1) // ps + 1)
 
-    def load_page(pid):
-        # Page tile loads: reads go through the *output* refs (the aliased
-        # buffer) so the fused append above is visible.
-        loads = [
-            pltpu.make_async_copy(k_out.at[pid, g], k_scr, sems.at[0]),
-            pltpu.make_async_copy(v_out.at[pid, g], v_scr, sems.at[1]),
-        ]
-        if scaled:
-            loads += [
-                pltpu.make_async_copy(ks_out.at[pid, g], ks_scr, sems.at[2]),
-                pltpu.make_async_copy(vs_out.at[pid, g], vs_scr, sems.at[3]),
-            ]
-        for d in loads:
-            d.start()
-        for d in loads:
-            d.wait()
+    def load_tiles(ti):
+        pid = table_ref[b, ti]
+        copy_pages(pid, to_vmem=True)
+        readable = pid != TRASH_PAGE
+        kf = page_values(k_scr)
+        vf = page_values(v_scr)
+        if scaled:  # in-VMEM dequant with the per-token scales ([ps, 1])
+            kf = kf * scale_col(ks_scr)
+            vf = vf * scale_col(vs_scr)
+        kf = jnp.where(readable, kf, 0.0)  # select: NaN poison dies here
+        vf = jnp.where(readable, vf, 0.0)
+        gpos = ti * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        return kf, vf, (gpos <= bound) & readable
 
     if kind == "int4":
-        # int4 tier: unpack nibbles in VMEM, dequantize, and run the shared
-        # _int4_flash_step recurrence on 2-D per-(b, g) slices — the same op
-        # sequence the XLA fallback and the gather oracle run batched, so
-        # the three paths agree bitwise (the tier's exactness contract).
+        # int4 tier: the shared _int4_flash_step recurrence on 2-D per-(b, g)
+        # slices — the same op sequence the XLA fallback and the gather
+        # oracle run batched, so the three paths agree bitwise (the tier's
+        # exactness contract).
         def body(ti, carry):
-            pid = table_ref[b, ti]
-            load_page(pid)
-            readable = pid != TRASH_PAGE
-            kf = unpack_int4(k_scr[...]).astype(jnp.float32) * ks_scr[...]
-            vf = unpack_int4(v_scr[...]).astype(jnp.float32) * vs_scr[...]
-            kf = jnp.where(readable, kf, 0.0)  # select: NaN poison dies here
-            vf = jnp.where(readable, vf, 0.0)
-            gpos = ti * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-            vis = (gpos <= bound) & readable
+            kf, vf, vis = load_tiles(ti)
             return _int4_flash_step(qv, kf, vf, vis, carry)
 
         m0 = jnp.full((qr,), NEG_INF, jnp.float32)
@@ -575,26 +638,13 @@ def _paged_attn_kernel(
         out_ref[0, 0] = _int4_finish(m, l, acc)
         return
 
-    int8 = kind == "int8"
-
     def body(ti, carry):
         m, l, acc = carry
-        pid = table_ref[b, ti]
-        load_page(pid)
-        readable = pid != TRASH_PAGE
-        kf = k_scr[...].astype(jnp.float32)
-        vf = v_scr[...].astype(jnp.float32)
-        if int8:  # in-VMEM dequant with the per-token scales ([ps, 1])
-            kf = kf * ks_scr[...]
-            vf = vf * vs_scr[...]
-        kf = jnp.where(readable, kf, 0.0)  # select: NaN poison dies here
-        vf = jnp.where(readable, vf, 0.0)
+        kf, vf, vis = load_tiles(ti)
         s = jax.lax.dot_general(  # [QR, ps]
             qv, kf, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        gpos = ti * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        vis = (gpos <= bound) & readable
         s = s + jnp.where(vis, 0.0, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -613,6 +663,14 @@ def _paged_attn_kernel(
     out_ref[0, 0] = acc / jnp.maximum(l, 1e-30)
 
 
+def _scale_rows(scale: jnp.ndarray) -> jnp.ndarray:
+    """[P, KV, ps] page scales -> [P, L] lane-dense rows (L = KV*ps padded
+    to 128 lanes), so one page's scales move as one aligned row DMA."""
+    p, kvh, ps = scale.shape
+    rows = scale.reshape(p, kvh * ps)
+    return jnp.pad(rows, ((0, 0), (0, (-kvh * ps) % 128)))
+
+
 def paged_attention_kernel(
     pool, table, pos, q, k_new, v_new, *, interpret: bool = False
 ) -> Tuple:
@@ -621,7 +679,8 @@ def paged_attention_kernel(
     Returns (out [B, Q, H, hd] f32, new pool — appended in place via
     input/output aliasing)."""
     b, qn, h, hd = q.shape
-    p_pages, kvh, ps, hdp = pool["k"].shape  # hdp = hd (hd//2 packed int4)
+    p_pages, kvh, tile_rows, _ = pool["k"].shape  # ps/2 rows when int4
+    ps = page_size(pool)
     t = table.shape[1]
     rep = h // kvh
     qr = qn * rep
@@ -634,7 +693,7 @@ def paged_attention_kernel(
     pdt = pool["k"].dtype
 
     blk = lambda shape: pl.BlockSpec(shape, lambda i, j, *_: (i, j, 0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [blk((1, 1, qr, hd)), blk((1, 1, qn, hd)), blk((1, 1, qn, hd)),
                 any_spec, any_spec]
     inputs = [q2, kn2, vn2, pool["k"], pool["v"]]
@@ -647,34 +706,18 @@ def paged_attention_kernel(
     # Input indices include the 2 scalar-prefetch args (table, pos).
     aliases = {5: 1, 6: 2}
     scratch = [
-        pltpu.VMEM((ps, hdp), pdt),  # k page tile
-        pltpu.VMEM((ps, hdp), pdt),  # v page tile
+        pltpu.VMEM((tile_rows, hd), pdt),  # k page tile
+        pltpu.VMEM((tile_rows, hd), pdt),  # v page tile
     ]
     if scaled:
-        # Scales carried as [P, KV, ps, 1] so row tiles stay 2-D.
-        ks4 = pool["k_scale"][..., None]
-        vs4 = pool["v_scale"][..., None]
-        inputs += [ks4, vs4]
+        ks2 = _scale_rows(pool["k_scale"])
+        vs2 = _scale_rows(pool["v_scale"])
+        inputs += [ks2, vs2]
         in_specs += [any_spec, any_spec]
         out_specs += [any_spec, any_spec]
-        out_shape += [
-            jax.ShapeDtypeStruct(ks4.shape, jnp.float32),
-            jax.ShapeDtypeStruct(vs4.shape, jnp.float32),
-        ]
+        out_shape += [jax.ShapeDtypeStruct(ks2.shape, jnp.float32)] * 2
         aliases.update({7: 3, 8: 4})
-        scratch += [
-            pltpu.VMEM((ps, 1), jnp.float32),  # k scale tile
-            pltpu.VMEM((ps, 1), jnp.float32),  # v scale tile
-        ]
-    scratch += [
-        pltpu.VMEM((1, hdp), pdt),  # append row staging (k)
-        pltpu.VMEM((1, hdp), pdt),  # append row staging (v)
-    ]
-    if scaled:
-        scratch += [
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ]
+        scratch += [pltpu.VMEM((1, ks2.shape[1]), jnp.float32)] * 2
     scratch += [pltpu.SemaphoreType.DMA((4,))]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -696,8 +739,8 @@ def paged_attention_kernel(
     out = res[0]
     new_pool = {"k": res[1], "v": res[2]}
     if scaled:
-        new_pool["k_scale"] = res[3][..., 0]
-        new_pool["v_scale"] = res[4][..., 0]
+        new_pool["k_scale"] = res[3][:, : kvh * ps].reshape(p_pages, kvh, ps)
+        new_pool["v_scale"] = res[4][:, : kvh * ps].reshape(p_pages, kvh, ps)
     return _rows_out(out, qn), new_pool
 
 
@@ -721,11 +764,15 @@ def paged_attention(
     lives in :func:`repro.kernels.ops.paged_attention`.
     """
     b, qn, h, hd = q.shape
-    ps, hdp = pool["k"].shape[2:]  # hdp: stored width (hd//2 for packed int4)
+    _, kvh, rows, _ = pool["k"].shape  # rows: ps/2 for packed int4
     itemsize = jnp.dtype(pool["k"].dtype).itemsize
-    qr = qn * (h // pool["k"].shape[1])
-    tile_bytes = 2 * (2 * ps * hdp * itemsize + 2 * ps * 4) + 2 * qr * hd * 4
+    qr = qn * (h // kvh)
+    # k/v page tiles, the two scale rows, and the q/out row blocks.
+    scale_row = 4 * (kvh * page_size(pool) + (-kvh * page_size(pool)) % 128)
+    tile_bytes = 2 * (2 * rows * hd * itemsize + 2 * scale_row) + 2 * qr * hd * 4
     if tile_bytes > vmem_budget_bytes:
+        reroute("paged_attention", pool["k"].shape, tile_bytes,
+                vmem_budget_bytes)
         return paged_attention_xla(
             pool, table, pos, q, k_new, v_new, block_tokens=block_tokens
         )

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import compiler_params
 
 __all__ = ["quant_matmul_kernel", "quant_matmul"]
 
@@ -106,7 +105,7 @@ def quant_matmul_kernel(
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.int32 if int_path else jnp.float32)
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

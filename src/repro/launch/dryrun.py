@@ -41,8 +41,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import SHAPES, get_config, list_archs  # noqa: E402
 from repro.core.apply import abstract_quantize_params, path_str  # noqa: E402
-from repro.core.recipe import QuantRecipe  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch.weights import SERVE_RECIPE  # noqa: E402
 from repro.launch.steps import (  # noqa: E402
     TrainHyper,
     make_prefill_step,
@@ -65,9 +65,6 @@ PEAK_FLOPS = 197e12  # bf16
 HBM_BW = 819e9  # bytes/s
 ICI_BW = 50e9  # bytes/s/link
 
-SERVE_RECIPE = QuantRecipe(
-    w_bits=8, w_clip="mse", ocs_ratio=0.02, per_channel=True, a_bits=None, pad_to=128
-)
 
 # Assignment skip rules (see DESIGN.md §6).
 FULL_ATTN_ARCHS = {

@@ -39,6 +39,8 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, list_archs, smoke_config
 from repro.core.apply import quantize_params
 from repro.core.recipe import QuantRecipe
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.weights import load_quantized
 from repro.models import transformer as T
 from repro.obs.log import add_log_level_arg, get_logger, setup_logging
 from repro.optim import adamw_init
@@ -226,26 +228,36 @@ def main(argv=None):
         raise SystemExit("serve.py: --trace-out requires --trace")
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     rng = np.random.default_rng(args.seed)
+    enable_compile_cache()
 
-    params = T.init_params(cfg, jax.random.PRNGKey(args.seed))
-    if args.ckpt_dir:
-        ckpt = CheckpointManager(args.ckpt_dir, async_write=False)
-        (params, _opt), meta = ckpt.restore((params, adamw_init(params)))
-        params = jax.tree.map(jnp.asarray, params)
-        log.info("restored %s step %s", meta.get("arch"), ckpt.latest_step())
-
+    recipe = QuantRecipe(
+        w_bits=args.bits, w_clip=args.clip, ocs_ratio=args.ocs_ratio,
+        per_channel=True, pad_to=128,
+    )
+    params = None
+    t0 = time.time()
+    if not args.smoke and not args.ckpt_dir:
+        # Full width: the float tree never exists whole. Seeded slices are
+        # quantized on the host one at a time; only int8 reaches the device.
+        if args.float_serve or args.compare_float:
+            raise SystemExit(
+                "serve.py: --float-serve/--compare-float need the whole float "
+                f"tree, which does not fit one device at {cfg.name}'s full "
+                "width; use --smoke"
+            )
+        qparams = load_quantized(cfg, args.seed, recipe)
+    else:
+        params = T.init_params(cfg, jax.random.PRNGKey(args.seed))
+        if args.ckpt_dir:
+            ckpt = CheckpointManager(args.ckpt_dir, async_write=False)
+            (params, _opt), meta = ckpt.restore((params, adamw_init(params)))
+            params = jax.tree.map(jnp.asarray, params)
+            log.info("restored %s step %s", meta.get("arch"), ckpt.latest_step())
+        qparams = params if args.float_serve else quantize_params(params, recipe)
     if not args.float_serve:
-        recipe = QuantRecipe(
-            w_bits=args.bits, w_clip=args.clip, ocs_ratio=args.ocs_ratio,
-            per_channel=True, pad_to=1,
-        )
-        t0 = time.time()
-        qparams = quantize_params(params, recipe)
         get_logger("launch.ptq").info(
             "quantized in %.1fs (w%d, ocs r=%s, clip=%s)",
             time.time() - t0, args.bits, args.ocs_ratio, args.clip)
-    else:
-        qparams = params
 
     ecfg = _engine_config(args, cfg)
     if args.float_serve and ecfg.matmul_mode != "dequant":

@@ -590,10 +590,16 @@ def attention_decode(
         dynamically quantize the folded p per row -> one s8 x s8 dot.
         Exact: out = sum_s p[s] v8[s] vs[s] = (p*vs) @ v8."""
         if not int8_cache:
-            return jnp.einsum(
-                "bqgrs,bgsd->bqgrd", p_seq.astype(v_cache.dtype), v_cache,
-                preferred_element_type=jnp.float32,
-            )
+            # One [B, KV] batch of (Q*rep, S) x (S, hd) matmuls: the (q, r)
+            # rows merged next to the batch dims. XLA's CPU backend refuses
+            # a bf16 x bf16 -> f32 dot with the rows split around the batch
+            # dims; only the small p is transposed, never the cache.
+            qn_, rep_, s_ = p_seq.shape[1], p_seq.shape[3], p_seq.shape[4]
+            p2 = jnp.moveaxis(p_seq.astype(v_cache.dtype), 1, 2).reshape(
+                b, kvh, qn_ * rep_, s_)
+            o = jnp.einsum("bgxs,bgsd->bgxd", p2, v_cache,
+                           preferred_element_type=jnp.float32)
+            return jnp.moveaxis(o.reshape(b, kvh, qn_, rep_, hd), 2, 1)
         p_fold = p_seq * cvs[:, None, :, None, :]
         p8, p_s = _quant_rows(p_fold)
         o32 = jnp.einsum("bqgrs,bgsd->bqgrd", p8, v_cache,

@@ -37,10 +37,10 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.sharding.compat import shard_map
 from repro.sharding.specs import activation_rules, logical
 from .layers import dense
 

@@ -101,6 +101,33 @@ def model_params_shape(cfg: ModelConfig) -> Dict:
     return shapes
 
 
+def param_init_rule(cfg: ModelConfig, path: str, shape) -> Tuple[str, float]:
+    """How parameter ``path`` (``core.apply.path_str`` form) is initialized:
+    ``(kind, std)`` with kind one of ``ones`` / ``zeros`` (in the tree's
+    dtype), ``ones_f32`` / ``zeros_f32`` / ``a_log`` (always float32), or
+    ``normal`` (``std`` times a standard normal).
+
+    The one rule behind :func:`init_params` (``jax.random``) and the
+    host-side seeded loader (``repro.launch.weights``, numpy, one slice at a
+    time); only the random source differs.
+    """
+    p = path.lower()
+    vector = len(shape) == 1 or (len(shape) == 2 and shape[0] == cfg.n_layers)
+    if "scale" in p or "norm" in p:
+        return "ones", 0.0
+    if "a_log" in p:
+        return "a_log", 0.0
+    if "dt_bias" in p or p.endswith("conv_b"):
+        return "zeros_f32", 0.0
+    if p.endswith("/d") or p.split("/")[-1] == "d":
+        return "ones_f32", 0.0
+    if vector:
+        return "zeros", 0.0
+    if "embed" in p or "meta_tokens" in p:
+        return "normal", 0.02
+    return "normal", 1.0 / math.sqrt(shape[-2])
+
+
 def init_params(cfg: ModelConfig, key, dtype=jnp.float32):
     from repro.core.apply import path_str
 
@@ -110,27 +137,33 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.float32):
     keys = jax.random.split(key, len(flat))
 
     def init_one(k, path, shape):
-        p = path_str(path).lower()
-        vector = len(shape) == 1 or (len(shape) == 2 and shape[0] == cfg.n_layers)
-        if "scale" in p or "norm" in p:
-            return jnp.ones(shape, dtype)
-        if "a_log" in p:
+        kind, std = param_init_rule(cfg, path_str(path), shape)
+        if kind == "normal":
+            return (jax.random.normal(k, shape, jnp.float32) * std).astype(dtype)
+        if kind == "a_log":
             base = jnp.log(jnp.linspace(1.0, 16.0, shape[-1], dtype=jnp.float32))
             return jnp.broadcast_to(base, shape).astype(jnp.float32)
-        if "dt_bias" in p or p.endswith("conv_b"):
-            return jnp.zeros(shape, jnp.float32)
-        if p.endswith("/d") or p.split("/")[-1] == "d":
-            return jnp.ones(shape, jnp.float32)
-        if vector:
-            return jnp.zeros(shape, dtype)
-        if "embed" in p or "meta_tokens" in p:
-            std = 0.02
-        else:
-            std = 1.0 / math.sqrt(shape[-2])
-        return (jax.random.normal(k, shape, jnp.float32) * std).astype(dtype)
+        fill = jnp.ones if kind.startswith("ones") else jnp.zeros
+        return fill(shape, jnp.float32 if kind.endswith("_f32") else dtype)
 
     leaves = [init_one(k, path, shape) for k, (path, shape) in zip(keys, flat)]
     return treedef.unflatten(leaves)
+
+
+def layer_params(params, i: int):
+    """Layer ``i``'s parameters.
+
+    ``params["layers"]`` is either one tree of stacked ``[L, ...]`` leaves
+    (training, ``lax.scan``) or a list of per-layer trees — the serving
+    layout of :func:`repro.launch.weights.load_quantized`. The list costs
+    nothing to index, where slicing a stacked leaf that feeds a Pallas call
+    copies the slice first (a custom call cannot read a view of a larger
+    buffer), doubling the weight traffic of every decode step.
+    """
+    layers = params["layers"]
+    if isinstance(layers, (list, tuple)):
+        return layers[i]
+    return jax.tree.map(lambda a: a[i], layers)
 
 
 # ---------------------------------------------------------------------------
@@ -258,6 +291,8 @@ def forward(
     positions = _positions(cfg, b, s + n_meta)
 
     flags = _hymba_flags(cfg) if cfg.block == "hymba" else None
+    if isinstance(params["layers"], (list, tuple)):
+        scan = False  # per-layer trees: nothing to scan over
     if scan and flags is not None:
         # Segmented scan: contiguous runs of same-kind layers (the 3 global
         # layers become their own segments) so the window/global choice is
@@ -280,7 +315,7 @@ def forward(
         x, _ = jax.lax.scan(body, x, params["layers"])
     else:
         for i in range(cfg.n_layers):
-            p_i = jax.tree.map(lambda a: a[i], params["layers"])
+            p_i = layer_params(params, i)
             f_i = bool(flags[i]) if flags is not None else None
             x = _block(cfg, p_i, x, positions, f_i)
 
@@ -461,7 +496,7 @@ def decode_tokens(
         if i >= n_run:
             new_layers.append(caches["layers"][i])  # drafter skips the tail
             continue
-        p_i = jax.tree.map(lambda a: a[i], params["layers"])
+        p_i = layer_params(params, i)
         if cfg.block == "hymba":
             window = 0 if bool(flags[i]) else cfg.hymba.swa_window
             x, nc = _decode_block(cfg, p_i, x, caches["layers"][i], pos, window)
@@ -595,7 +630,7 @@ def prefill_with_cache(
     caches = init_cache(cfg, b, max_len, dtype=cache_dtype)
 
     for i in range(cfg.n_layers):
-        p = jax.tree.map(lambda a: a[i], params["layers"])
+        p = layer_params(params, i)
         # The exact forward body (_block) — chunked prefill cannot drift
         # from forward/decode_step structure.
         x, (k, v) = _block(cfg, p, x, positions, return_kv=True)
@@ -661,7 +696,7 @@ def prefill_chunk_with_cache(
 
     new_layers = []
     for i in range(cfg.n_layers):
-        p = jax.tree.map(lambda a: a[i], params["layers"])
+        p = layer_params(params, i)
         cache = caches["layers"][i]["attn"]
         kv_prefix = None
         if prefix_pad:
@@ -755,7 +790,7 @@ def prefill_into_pages(
     b, s = tokens.shape
     if b != 1:
         raise ValueError("paged prefill is per-request (page_ids are per-seq)")
-    n_hit = prefix_ids.shape[0] * pools[0]["k"].shape[2]
+    n_hit = prefix_ids.shape[0] * _kvc.page_size(pools[0])
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
     p_len = None
     if prefix_len is not None and n_hit:
@@ -767,7 +802,7 @@ def prefill_into_pages(
 
     new_pools = []
     for i in range(cfg.n_layers):
-        p = jax.tree.map(lambda a: a[i], params["layers"])
+        p = layer_params(params, i)
         kv_prefix = _kvc.gather_prefix(pools[i], prefix_ids) if n_hit else None
         if kv_prefix is not None and p_len is not None:
             # Trash-page pad rows may hold arbitrary stale K/V (even NaN from

@@ -31,9 +31,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.sharding.compat import shard_map
 
 __all__ = ["CompressionState", "init_compression", "compressed_psum", "pod_allreduce"]
 
@@ -70,12 +69,7 @@ def pod_allreduce(
     Returns (averaged grads, new CompressionState). Must be called in a
     context where ``axis`` is a manual (shard_map) mesh axis.
     """
-    # lax.axis_size is a newer alias; psum(1) is the portable spelling.
-    n = (
-        jax.lax.axis_size(axis)
-        if hasattr(jax.lax, "axis_size")
-        else jax.lax.psum(1, axis)
-    )
+    n = jax.lax.axis_size(axis)
 
     def one(g, e):
         v = g.astype(jnp.float32) + e

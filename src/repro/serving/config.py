@@ -62,9 +62,10 @@ class ConfigError(ValueError):
 class KernelChoice(str, enum.Enum):
     """The one kernel-selection vocabulary (config == CLI == stats).
 
-    * ``AUTO``   — defer to the deprecated module-global shims
-      (``layers.USE_PALLAS_SERVING`` / ``attention.USE_PALLAS_PAGED_ATTN``),
-      read once at engine construction, never at dispatch;
+    * ``AUTO``   — ``PALLAS`` for both on a TPU backend; elsewhere defer to
+      the deprecated module-global shims (``layers.USE_PALLAS_SERVING`` /
+      ``attention.USE_PALLAS_PAGED_ATTN``). Resolved once at engine
+      construction, never at dispatch;
     * ``PALLAS`` — the fused Pallas kernels (Mosaic on TPU; off-TPU the
       ``kernels.ops`` dispatch lowers them to their XLA formulations);
     * ``XLA``    — force the pure-XLA formulation even on TPU (what GSPMD
@@ -91,26 +92,32 @@ class KernelChoice(str, enum.Enum):
             ) from None
 
 
+def _on_tpu() -> bool:
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
 def _default_matmul_kernel() -> KernelChoice:
-    """AUTO resolution for the matmul backend: the deprecated module shim."""
+    """AUTO resolution for the matmul backend: the Pallas kernels on a TPU,
+    the deprecated module shim elsewhere."""
     from repro.models import layers
 
-    return KernelChoice.PALLAS if layers.USE_PALLAS_SERVING else KernelChoice.XLA
+    if _on_tpu() or layers.USE_PALLAS_SERVING:
+        return KernelChoice.PALLAS
+    return KernelChoice.XLA
 
 
 def _default_attn_kernel() -> KernelChoice:
-    """AUTO resolution for paged decode attention: the deprecated shim.
-
-    The flag-off default is the legacy *gather* path — the engine-level
-    bit-exactness oracle — exactly as before this config existed.
-    """
+    """AUTO resolution for paged decode attention: the Pallas kernel on a
+    TPU; elsewhere the deprecated shim, whose flag-off default is the legacy
+    *gather* path — the engine-level bit-exactness oracle the CPU tests are
+    stated over."""
     from repro.models import attention
 
-    return (
-        KernelChoice.PALLAS
-        if attention.USE_PALLAS_PAGED_ATTN
-        else KernelChoice.GATHER
-    )
+    if _on_tpu() or attention.USE_PALLAS_PAGED_ATTN:
+        return KernelChoice.PALLAS
+    return KernelChoice.GATHER
 
 
 @dataclasses.dataclass(frozen=True)
@@ -330,13 +337,6 @@ class EngineConfig:
             "starve long prompts)",
         },
     )
-    compile_cache_dir: str = dataclasses.field(
-        default="",
-        metadata={
-            "help": "JAX persistent compilation cache directory ('' = off); "
-            "warm restarts skip the multi-second prefill/decode compiles",
-        },
-    )
     heartbeat_path: str = dataclasses.field(
         default="",
         metadata={
@@ -549,14 +549,14 @@ def add_engine_config_args(
             g.add_argument(
                 _flag("matmul_kernel"), default=default.matmul.value,
                 choices=[c for c in choices if c != "gather"],
-                help="quantized-matmul backend (auto = the deprecated "
-                "layers.USE_PALLAS_SERVING shim)",
+                help="quantized-matmul backend (auto = pallas on a TPU, "
+                "else the deprecated layers.USE_PALLAS_SERVING shim)",
             )
             g.add_argument(
                 _flag("attn_kernel"), default=default.attn.value, choices=choices,
-                help="paged decode-attention backend (auto = the deprecated "
-                "attention.USE_PALLAS_PAGED_ATTN shim; gather = the legacy "
-                "bit-exactness oracle)",
+                help="paged decode-attention backend (auto = pallas on a "
+                "TPU, else the deprecated attention.USE_PALLAS_PAGED_ATTN "
+                "shim; gather = the legacy bit-exactness oracle)",
             )
         elif meta.get("spec"):
             sd = default if default is not None else SpecConfig()

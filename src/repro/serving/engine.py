@@ -345,28 +345,6 @@ class _Slot:
         return self.req is not None and self.prefill_pos >= 0
 
 
-def _enable_compile_cache(cache_dir: str) -> None:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (process
-    global — compile caching is a process property, not an engine one; the
-    last engine built wins, which is harmless since entries are keyed by
-    computation). Thresholds drop to zero so even the small smoke-config
-    traces persist; best-effort — a jaxlib without the knobs serves cold.
-
-    The memoized cache handle must be dropped first: jax initializes the
-    persistent cache once, lazily, at the first compile — in a process
-    that already compiled something before this engine existed, a bare
-    ``jax.config.update`` is silently ignored."""
-    try:
-        from jax.experimental.compilation_cache import compilation_cache
-
-        compilation_cache.reset_cache()
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception:
-        pass
-
-
 def _fold_legacy_kwargs(config: Optional[EngineConfig], legacy: Dict) -> EngineConfig:
     """One release of backwards compatibility: map deprecated ``ServingEngine``
     kwargs onto :class:`EngineConfig` fields behind a ``DeprecationWarning``."""
@@ -548,8 +526,6 @@ class ServingEngine:
         self._drift_broken = False
         self._drift_last_step = -1
         self._profiling = False
-        if config.compile_cache_dir:
-            _enable_compile_cache(config.compile_cache_dir)
         self.max_batch = config.max_batch
         self.max_len = config.max_len
         self.matmul_mode = config.matmul_mode
@@ -2283,7 +2259,7 @@ class ServingEngine:
         if not self.attn_probe:
             return 0.0
         if self._attn_probe_fn is None:
-            p0 = jax.tree.map(lambda a: a[0], self.params["layers"])["attn"]
+            p0 = T.layer_params(self.params, 0)["attn"]
 
             def impl(p, pool, table, pos, x):
                 with layers.serving_mode(

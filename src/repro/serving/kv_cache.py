@@ -53,6 +53,7 @@ from repro.kernels.paged_attention import (
     KV4_QMAX,
     append_rows as _append_rows,
     pack_int4,
+    page_size,
     quant_rows as _quant_rows_q,
     unpack_int4,
 )
@@ -118,16 +119,20 @@ def init_page_pool(
     shape = (n_pages, cfg.n_kv_heads, page_size, cfg.hd)
     if cfg.kv_bits is not None:
         if cfg.kv_bits == 4:
-            # Packed nibbles: byte j of a row holds channel j (low nibble)
-            # and channel j + hd//2 (high nibble) — the split-half layout
-            # pack_int4/unpack_int4 implement. uint8 dtype is the tier
-            # discriminator (int8 pools quantize at qmax=127, packed pools
-            # at qmax=7); scales keep the int8 layout.
-            if cfg.hd % 2:
-                raise ValueError(f"kv_bits=4 needs an even head dim, got {cfg.hd}")
+            # Packed nibbles, two tokens per byte: row r of a page holds
+            # token r (low nibble) and token r + page_size/2 (high nibble)
+            # of every channel — pack_int4/unpack_int4's split-half layout
+            # along the token axis, so a page tile stays hd lanes wide.
+            # uint8 dtype is the tier discriminator (int8 pools quantize at
+            # qmax=127, packed pools at qmax=7); scales keep the int8 layout.
+            if page_size % 2:
+                raise ValueError(
+                    f"kv_bits=4 needs an even page size, got {page_size}"
+                )
+            packed = (n_pages, cfg.n_kv_heads, page_size // 2, cfg.hd)
             return {
-                "k": jnp.zeros(shape[:3] + (cfg.hd // 2,), jnp.uint8),
-                "v": jnp.zeros(shape[:3] + (cfg.hd // 2,), jnp.uint8),
+                "k": jnp.zeros(packed, jnp.uint8),
+                "v": jnp.zeros(packed, jnp.uint8),
                 "k_scale": jnp.zeros(shape[:3], jnp.float32),
                 "v_scale": jnp.zeros(shape[:3], jnp.float32),
             }
@@ -231,16 +236,15 @@ def gather_pages(pool: Dict, table) -> Tuple:
     untouched, preserving the bit-exact reconstruction contract.
     """
     b, t = table.shape
-    n_kv, ps, hd = pool["k"].shape[1:]
+    n_kv, hd = pool["k"].shape[1], pool["k"].shape[3]
+    ps = page_size(pool)
     packed = pool["k"].dtype == jnp.uint8
-    if packed:
-        hd = hd * 2  # pool stores two nibbles per byte; callers see int8 rows
 
     trash = jnp.repeat(table == TRASH_PAGE, ps, axis=1)  # [B, T*ps]
 
     def flat4(x):  # [B, T, KV, ps, hd] -> [B, KV, T*ps, hd]
-        if packed:
-            x = unpack_int4(x)
+        if packed:  # two tokens per byte row; callers see int8 rows
+            x = unpack_int4(x, axis=-2)
         x = jnp.moveaxis(x, 2, 1).reshape(b, n_kv, t * ps, hd)
         return jnp.where(trash[:, None, :, None], jnp.zeros((), x.dtype), x)
 
@@ -262,7 +266,7 @@ def write_prompt_pages(pool: Dict, k, v, page_ids) -> Dict:
     0``); page_ids: ``[S // page_size]`` — the sequence's pages in order,
     padded with the trash page for bucket positions past the allocation.
     """
-    ps = pool["k"].shape[2]
+    ps = page_size(pool)
     s, n_kv, hd = k.shape[1:]
     nb = s // ps
 
@@ -277,8 +281,8 @@ def write_prompt_pages(pool: Dict, k, v, page_ids) -> Dict:
         # agree bitwise.
         k_q, k_s = _quant_rows_q(k_p, qmax=KV4_QMAX)
         v_q, v_s = _quant_rows_q(v_p, qmax=KV4_QMAX)
-        out["k"] = pool["k"].at[page_ids].set(pack_int4(k_q))
-        out["v"] = pool["v"].at[page_ids].set(pack_int4(v_q))
+        out["k"] = pool["k"].at[page_ids].set(pack_int4(k_q, axis=-2))
+        out["v"] = pool["v"].at[page_ids].set(pack_int4(v_q, axis=-2))
         out["k_scale"] = pool["k_scale"].at[page_ids].set(k_s)
         out["v_scale"] = pool["v_scale"].at[page_ids].set(v_s)
     elif pool["k"].dtype == jnp.int8:
@@ -302,14 +306,13 @@ def gather_prefix(pool: Dict, prefix_ids) -> Tuple:
     concatenates on the key side (prefix tokens precede every suffix query,
     so the always-visible prefix semantics are exactly causal here).
     """
-    n_hit, n_kv, ps, hd = (prefix_ids.shape[0],) + pool["k"].shape[1:]
+    n_hit, n_kv, hd = prefix_ids.shape[0], pool["k"].shape[1], pool["k"].shape[3]
+    ps = page_size(pool)
     packed = pool["k"].dtype == jnp.uint8
-    if packed:
-        hd = hd * 2
 
     def flat(vals, scale):  # [H, KV, ps, hd] -> [1, H*ps, KV, hd]
         if packed:
-            vals = unpack_int4(vals)
+            vals = unpack_int4(vals, axis=-2)
         x = vals.astype(jnp.float32)
         if scale is not None:
             x = x * scale[..., None]

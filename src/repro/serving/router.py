@@ -58,6 +58,8 @@ import time
 from collections import deque
 from typing import Deque, Dict, Iterator, List, Optional, Sequence, Tuple
 
+import jax
+
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import TraceRing
 from repro.runtime.health import StepTimer
@@ -261,11 +263,20 @@ class ReplicaSet:
     @classmethod
     def build(cls, cfg, params, econfig: EngineConfig, n: int,
               config: Optional[RouterConfig] = None) -> "ReplicaSet":
+        """``n`` engines, replica ``i`` on ``jax.devices()[i % count]``: its
+        own copy of the params, its page pools and its jitted calls all live
+        on that device (with fewer devices than replicas, replicas share)."""
         if n < 1:
             raise ValueError(f"need >= 1 replica, got {n}")
-        return cls(
-            [ServingEngine(cfg, params, econfig) for _ in range(n)], config
-        )
+        devices = jax.devices()
+        engines = []
+        for i in range(n):
+            dev = devices[i % len(devices)]
+            with jax.default_device(dev):
+                engines.append(
+                    ServingEngine(cfg, jax.device_put(params, dev), econfig)
+                )
+        return cls(engines, config)
 
     def __len__(self) -> int:
         return len(self.replicas)

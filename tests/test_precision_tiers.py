@@ -101,8 +101,8 @@ def test_quant_rows_int4_grid():
 def _mk_int4_pool(rng, P, KV, ps, hd):
     """Random packed int4 pages: arbitrary bytes unpack to nibbles in [-8, 7]."""
     return {
-        "k": jnp.asarray(rng.randint(0, 256, (P, KV, ps, hd // 2)), jnp.uint8),
-        "v": jnp.asarray(rng.randint(0, 256, (P, KV, ps, hd // 2)), jnp.uint8),
+        "k": jnp.asarray(rng.randint(0, 256, (P, KV, ps // 2, hd)), jnp.uint8),
+        "v": jnp.asarray(rng.randint(0, 256, (P, KV, ps // 2, hd)), jnp.uint8),
         "k_scale": jnp.asarray(rng.rand(P, KV, ps) * 0.1 + 0.01, jnp.float32),
         "v_scale": jnp.asarray(rng.rand(P, KV, ps) * 0.1 + 0.01, jnp.float32),
     }
@@ -169,11 +169,11 @@ def test_int4_trash_page_invariant(force):
 
 
 def test_int4_pool_init_layout():
-    """kv_bits=4 pools pack two channels per byte; scales keep int8 layout."""
+    """kv_bits=4 pools pack two tokens per byte; scales keep int8 layout."""
     cfg = dataclasses.replace(smoke_config("deepseek-7b"), kv_bits=4)
     pool = kvc.init_page_pool(cfg, 5, 8)
     assert pool["k"].dtype == jnp.uint8
-    assert pool["k"].shape == (5, cfg.n_kv_heads, 8, cfg.hd // 2)
+    assert pool["k"].shape == (5, cfg.n_kv_heads, 4, cfg.hd)  # 2 tokens/row
     assert pool["k_scale"].shape == (5, cfg.n_kv_heads, 8)
     assert pa.pool_kind(pool) == "int4"
     # bytes/token halves the value payload vs int8; scales are unchanged.

@@ -195,17 +195,49 @@ def test_mid_prefill_preemption_resumes_exactly(dense_setup):
 # Satellite: persistent compilation cache
 
 
-def test_compile_cache_dir_populates(dense_setup, tmp_path):
-    """EngineConfig.compile_cache_dir turns on the jax persistent
-    compilation cache: a fresh directory gains entries after one request."""
+def test_compile_cache_dir_populates(dense_setup, tmp_path, monkeypatch):
+    """The entry points' compile-cache helper turns on the jax persistent
+    compilation cache under JAX_COMPILATION_CACHE_DIR: a fresh directory
+    gains entries after one request."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch.compile_cache import ENV_VAR, enable_compile_cache
+
     cfg, params = dense_setup
-    cache = tmp_path / "cc"
-    eng = ServingEngine(cfg, params, EngineConfig(
-        max_batch=1, max_len=32, compile_cache_dir=str(cache)))
-    eng.submit(Request(uid=0, prompt=[1, 2, 3], max_new_tokens=3))
-    eng.run()
-    assert cache.exists() and any(cache.iterdir()), (
-        "persistent compilation cache wrote nothing")
+    monkeypatch.setenv(ENV_VAR, str(tmp_path))
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_entry_size_bytes",
+        "jax_persistent_cache_min_compile_time_secs")}
+    try:
+        cache = enable_compile_cache("cc")
+        assert cache == tmp_path / "cc"
+        eng = ServingEngine(cfg, params, EngineConfig(max_batch=1, max_len=32))
+        eng.submit(Request(uid=0, prompt=[1, 2, 3], max_new_tokens=3))
+        eng.run()
+        assert cache.exists() and any(cache.iterdir()), (
+            "persistent compilation cache wrote nothing")
+    finally:
+        compilation_cache.reset_cache()
+        for k, v in saved.items():
+            jax.config.update(k, v)
+
+
+def test_compile_cache_placement(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set the helper sets nothing (JAX reads
+    the variable itself); without it the cache is .jax_cache/ in the
+    checkout, a fixed path."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache as cc
+
+    monkeypatch.setenv(cc.ENV_VAR, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert cc.enable_compile_cache() == tmp_path
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv(cc.ENV_VAR)
+    root = Path(__file__).resolve().parents[1]
+    assert cc.cache_root() == root / ".jax_cache"
 
 
 # ---------------------------------------------------------------------------
