@@ -90,7 +90,7 @@ def make_train_step(cfg: ModelConfig, hyper: TrainHyper):
 def make_serve_step(cfg: ModelConfig):
     def serve_step(params, caches, token):
         """token: [B, 1] -> (next_token [B, 1], logits [B, V], new caches)."""
-        logits, new_caches = T.decode_step(params, token, caches, cfg)
+        logits, new_caches = T.decode_step(T.per_layer(params), token, caches, cfg)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
         return nxt, logits, new_caches
 

@@ -8,8 +8,8 @@ slice)``, runs the recipe's OCS + quantize pipeline on that slice alone on
 the host CPU, and puts each finished layer of a leaf on the device. Only the
 quantized tree ever reaches the device. Slices are independent, so a thread
 pool quantizes them in parallel (numpy and XLA's CPU ops release the GIL),
-as many at a time as a host-memory budget allows; the result does not
-depend on the order they finish in.
+as many at a time as the host's memory allows; the result does not depend
+on the order they finish in.
 
 :func:`init_params_host` builds the whole float tree from the same slices:
 for small models, the float comparison, and the check that
@@ -45,16 +45,16 @@ SERVE_RECIPE = QuantRecipe(
     pad_to=128,
 )
 
-# Host memory the slices in flight may hold together. A one-chip TPU v5e
-# host has 40 GiB; glm4-9b's lm_head slice alone needs about 9 GiB of it
-# (see _host_cost), and the finished int8 groups, the float embedding and
-# the runtime live next to the slices in flight.
-HOST_BUDGET_BYTES = 16 * 2**30
-# Host memory a new slice must leave free. Memory a finished slice released
-# can take a while to show as available again (on a one-chip TPU v5e host:
-# 25 GB more in use than the process held, after 40 s of 8 threads), so
-# slices start only while the host reports the room.
-HOST_RESERVE_BYTES = 8 * 2**30
+# Threads that quantize slices at once, at most; fewer where the process may
+# use fewer CPUs.
+MAX_WORKERS = 8
+# Host memory a new slice must leave free. The host's free memory shrinks by
+# more than the slices hold, and recovers only tens of seconds after they
+# end, so a slice starts only while the host reports memory for it and this
+# reserve; one that cannot fit runs alone. A one-chip TPU v5e host reports
+# 46.8 GB available at the start and ends a process at 40 GiB in use; with
+# 8 GiB here the glm4-9b load peaked at 37.8 GiB.
+HOST_RESERVE_BYTES = 10 * 2**30
 
 
 def _host_available() -> Optional[int]:
@@ -71,14 +71,19 @@ def _host_available() -> Optional[int]:
 
 @contextlib.contextmanager
 def _retain_freed_memory():
-    """Keep memory the slices free inside the process, for reuse by the next
-    slice, instead of returning it to the host as each slice ends (glibc:
-    no mmap-backed blocks, no heap trimming); return it once, at the end.
+    """For the load only, keep the memory slices free inside the process for
+    the next slice to reuse (glibc: no mmap-backed blocks, no heap
+    trimming), then return it once. A no-op without glibc's ``mallopt``.
 
-    Slices allocate and free hundreds of MB each. On a host that takes
-    seconds to show returned memory as available again, eight threads of
-    that churn pile up tens of GB of memory in use that no process holds.
-    A no-op where the C library has no ``mallopt``.
+    With the admission rule of :func:`load_quantized` alone, the host's free
+    memory fell about 1 GB/s while eight threads quantized slices and
+    recovered only in bursts: the glm4-9b load on a one-chip TPU v5e host
+    (40 GiB) was killed at the limit 145 s in.
+
+    glibc cannot report these settings, so they return to its defaults,
+    not to values read beforehand. Setting them also stops glibc from
+    moving its mmap threshold for the rest of the process: the threshold
+    stays where it was when the load began.
     """
     try:
         libc = ctypes.CDLL(None)
@@ -159,21 +164,19 @@ def load_quantized(
     recipe: QuantRecipe = SERVE_RECIPE,
     *,
     device=None,
-    workers: Optional[int] = None,
-    host_bytes: int = HOST_BUDGET_BYTES,
 ):
     """The quantized tree of :func:`init_params_host`'s weights, built one
     slice at a time on the host and placed on ``device`` (default: the first
-    device) as soon as a layer of a leaf is complete. ``params["layers"]`` is
-    a list of per-layer trees (``models.transformer.layer_params``); stacked
-    again, the tree is equal bit for bit to
-    ``quantize_params(init_params_host(cfg, seed), recipe)``.
+    device) as soon as a layer of a leaf is complete. The tree is in the
+    serving layout, ``params["layers"]`` a list of per-layer trees
+    (``models.transformer.per_layer``); stacked again, it is equal bit for
+    bit to ``quantize_params(init_params_host(cfg, seed), recipe)``.
 
-    ``workers`` threads quantize slices concurrently (default: the CPUs this
-    process may use, at most 8). A slice is started only while the host
-    memory its work needs (:func:`_host_cost`) fits ``host_bytes`` together
-    with the slices in flight, and the host reports that much available
-    beyond ``HOST_RESERVE_BYTES``; a slice that cannot fit runs alone.
+    Up to ``MAX_WORKERS`` threads quantize slices at once. A slice starts
+    only while the host reports the memory its work needs
+    (:func:`_host_cost`) available beyond ``HOST_RESERVE_BYTES``; a slice
+    that cannot fit runs alone. Freed memory stays in the process until
+    the load ends (:func:`_retain_freed_memory`).
     """
     device = device if device is not None else jax.devices()[0]
     cpu = jax.devices("cpu")[0]
@@ -188,13 +191,13 @@ def load_quantized(
         with jax.default_device(cpu):
             return jax.block_until_ready(quant_slice(w, recipe))
 
-    def per_layer(leaf: int) -> bool:
+    def layer_leaf(leaf: int) -> bool:
         return leaves[leaf][0].startswith("layers/")
 
     # Slices are placed in groups: one layer (all its experts) of a stacked
     # per-layer matrix, else the whole leaf (a per-layer [L, d] vector is
     # one slice and is split into layers when placed).
-    by_layer = [per_layer(leaf) and bool(_lead(shape))
+    by_layer = [layer_leaf(leaf) and bool(_lead(shape))
                 for leaf, (_, shape) in enumerate(leaves)]
     n_groups = [shape[0] if by_layer[leaf] else 1
                 for leaf, (_, shape) in enumerate(leaves)]
@@ -212,13 +215,13 @@ def load_quantized(
         inner = lead[1:] if by_layer[leaf] else lead
         if not _quantized(path, shape, recipe):
             full = np.stack(parts).reshape(inner + shape[len(lead):])
-            if per_layer(leaf) and not lead:
+            if layer_leaf(leaf) and not lead:
                 return [to_device(row) for row in full]
             return to_device(full)
         with jax.default_device(cpu):
             if inner:
                 return to_device(stack_linears(parts, inner, recipe))
-            if per_layer(leaf):
+            if layer_leaf(leaf):
                 return to_device(slice_form(parts[0], recipe))
             return to_device(parts[0])
 
@@ -231,25 +234,22 @@ def load_quantized(
     parts: List[List] = [[None] * n for n in counts]
     out: List[List] = [[None] * g for g in n_groups]
     missing = [[k] * g for k, g in zip(size, n_groups)]
-    workers = workers or min(len(os.sched_getaffinity(0)), 8)
-    running, in_flight = {}, 0
+    workers = min(len(os.sched_getaffinity(0)), MAX_WORKERS)
+    running = {}
     with _retain_freed_memory(), ThreadPoolExecutor(workers) as pool:
         while jobs or running:
             avail, admitted = _host_available(), 0
             while jobs and len(running) < workers:
                 cost = _host_cost(leaves[jobs[0][0]], recipe)
-                if running and (in_flight + cost > host_bytes or (
-                        avail is not None
-                        and avail - admitted < cost + HOST_RESERVE_BYTES)):
+                if running and avail is not None and (
+                        avail - admitted < cost + HOST_RESERVE_BYTES):
                     break
                 job = jobs.popleft()
-                running[pool.submit(build, *job)] = (job, cost)
-                in_flight += cost
+                running[pool.submit(build, *job)] = job
                 admitted += cost  # not allocated yet: avail cannot show it
             done, _ = wait(running, return_when=FIRST_COMPLETED)
             for fut in done:
-                (leaf, i), cost = running.pop(fut)
-                in_flight -= cost
+                leaf, i = running.pop(fut)
                 parts[leaf][i] = fut.result()
                 g, k = i // size[leaf], size[leaf]
                 missing[leaf][g] -= 1
@@ -259,7 +259,7 @@ def load_quantized(
     params = treedef.unflatten([
         o if by_layer[leaf] else o[0] for leaf, o in enumerate(out)
     ])
-    # Per-layer trees, not stacked leaves: see models.transformer.layer_params.
+    # The serving layout: see models.transformer.per_layer.
     params["layers"] = [
         jax.tree.map(lambda per: per[i], params["layers"],
                      is_leaf=lambda x: isinstance(x, list))

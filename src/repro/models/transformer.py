@@ -150,20 +150,23 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.float32):
     return treedef.unflatten(leaves)
 
 
-def layer_params(params, i: int):
-    """Layer ``i``'s parameters.
+def per_layer(params):
+    """``params`` in the serving layout: ``params["layers"]`` a list of
+    per-layer trees.
 
-    ``params["layers"]`` is either one tree of stacked ``[L, ...]`` leaves
-    (training, ``lax.scan``) or a list of per-layer trees — the serving
-    layout of :func:`repro.launch.weights.load_quantized`. The list costs
-    nothing to index, where slicing a stacked leaf that feeds a Pallas call
-    copies the slice first (a custom call cannot read a view of a larger
-    buffer), doubling the weight traffic of every decode step.
+    Training keeps one tree of stacked ``[L, ...]`` leaves (``forward``'s
+    ``lax.scan``). The decode and prefill functions take the per-layer
+    list: slicing a stacked leaf that feeds a Pallas call copies the slice
+    first (a custom call cannot read a view of a larger buffer), which
+    doubles the weight traffic of every decode step. Called once where a
+    tree enters serving (``ServingEngine``); a list passes through as is.
     """
     layers = params["layers"]
-    if isinstance(layers, (list, tuple)):
-        return layers[i]
-    return jax.tree.map(lambda a: a[i], layers)
+    if isinstance(layers, list):
+        return params
+    n = jax.tree.leaves(layers)[0].shape[0]
+    return {**params,
+            "layers": [jax.tree.map(lambda a: a[i], layers) for i in range(n)]}
 
 
 # ---------------------------------------------------------------------------
@@ -291,8 +294,6 @@ def forward(
     positions = _positions(cfg, b, s + n_meta)
 
     flags = _hymba_flags(cfg) if cfg.block == "hymba" else None
-    if isinstance(params["layers"], (list, tuple)):
-        scan = False  # per-layer trees: nothing to scan over
     if scan and flags is not None:
         # Segmented scan: contiguous runs of same-kind layers (the 3 global
         # layers become their own segments) so the window/global choice is
@@ -315,7 +316,7 @@ def forward(
         x, _ = jax.lax.scan(body, x, params["layers"])
     else:
         for i in range(cfg.n_layers):
-            p_i = layer_params(params, i)
+            p_i = jax.tree.map(lambda a: a[i], params["layers"])
             f_i = bool(flags[i]) if flags is not None else None
             x = _block(cfg, p_i, x, positions, f_i)
 
@@ -460,8 +461,8 @@ def decode_tokens(
     ``<= pos + j`` — equal to Q sequential one-token steps, in ONE call.
 
     The layer loop is unrolled (see ``init_cache``): per-layer cache tensors
-    are donated and updated in place; stacked params are sliced per layer
-    (cheap relative to the cache traffic that dominates decode).
+    are donated and updated in place. ``params`` is in the serving layout
+    (:func:`per_layer`), as for every prefill function below.
 
     Paged caches (``"table"`` present, see ``serving.kv_cache``): per-layer
     leaves are page pools and reads/writes go through the shared block table;
@@ -496,7 +497,7 @@ def decode_tokens(
         if i >= n_run:
             new_layers.append(caches["layers"][i])  # drafter skips the tail
             continue
-        p_i = layer_params(params, i)
+        p_i = params["layers"][i]
         if cfg.block == "hymba":
             window = 0 if bool(flags[i]) else cfg.hymba.swa_window
             x, nc = _decode_block(cfg, p_i, x, caches["layers"][i], pos, window)
@@ -630,7 +631,7 @@ def prefill_with_cache(
     caches = init_cache(cfg, b, max_len, dtype=cache_dtype)
 
     for i in range(cfg.n_layers):
-        p = layer_params(params, i)
+        p = params["layers"][i]
         # The exact forward body (_block) — chunked prefill cannot drift
         # from forward/decode_step structure.
         x, (k, v) = _block(cfg, p, x, positions, return_kv=True)
@@ -696,7 +697,7 @@ def prefill_chunk_with_cache(
 
     new_layers = []
     for i in range(cfg.n_layers):
-        p = layer_params(params, i)
+        p = params["layers"][i]
         cache = caches["layers"][i]["attn"]
         kv_prefix = None
         if prefix_pad:
@@ -802,7 +803,7 @@ def prefill_into_pages(
 
     new_pools = []
     for i in range(cfg.n_layers):
-        p = layer_params(params, i)
+        p = params["layers"][i]
         kv_prefix = _kvc.gather_prefix(pools[i], prefix_ids) if n_hit else None
         if kv_prefix is not None and p_len is not None:
             # Trash-page pad rows may hold arbitrary stale K/V (even NaN from
@@ -847,10 +848,10 @@ class TransformerLM:
         return init_cache(self.cfg, batch, max_len, dtype)
 
     def decode_step(self, params, token, caches):
-        return decode_step(params, token, caches, self.cfg)
+        return decode_step(per_layer(params), token, caches, self.cfg)
 
     def verify_step(self, params, tokens, caches):
-        return verify_step(params, tokens, caches, self.cfg)
+        return verify_step(per_layer(params), tokens, caches, self.cfg)
 
     def prefill(self, params, tokens, max_len: int):
         return prefill(params, tokens, self.cfg, max_len)

@@ -13,7 +13,9 @@ float model in low precision. This engine is that provider's serving loop:
   ``DeprecationWarning``;
 * **weights** — the OCS+clip+int8 parameter tree from
   :func:`repro.core.apply.quantize_params` (float trees also accepted: the
-  model layer dispatches on leaf type);
+  model layer dispatches on leaf type), held in the serving layout of one
+  tree per layer (``models.transformer.per_layer``, applied at
+  construction; ``launch.weights.load_quantized`` builds it directly);
 * **request lifecycle** — ``submit(Request)`` queues; per-request
   :class:`SamplingParams` select greedy (default — the mode every
   bit-exactness contract is stated over) or temperature/top-k/top-p
@@ -477,6 +479,9 @@ class ServingEngine:
                 is_leaf=lambda x: isinstance(x, (OCSQuantLinear, W4A8Linear)),
             )
             params = self.params
+        # The serving layout, once: a per-layer tree per layer, so no decode
+        # step slices a stacked weight (see models.transformer.per_layer).
+        self.params = T.per_layer(self.params)
         # Observability (PR 8, docs/serving.md §Observability). The metrics
         # registry always exists — every legacy counter attribute below is a
         # registry-backed property (see _COUNTER_METRICS), so booking costs
@@ -2259,7 +2264,7 @@ class ServingEngine:
         if not self.attn_probe:
             return 0.0
         if self._attn_probe_fn is None:
-            p0 = T.layer_params(self.params, 0)["attn"]
+            p0 = self.params["layers"][0]["attn"]
 
             def impl(p, pool, table, pos, x):
                 with layers.serving_mode(

@@ -24,6 +24,7 @@ import pytest
 from repro.configs import smoke_config
 from repro.core import histogram, ocs, quantizer
 from repro.core.apply import quantize_params
+from repro.launch import weights
 from repro.launch.weights import SERVE_RECIPE, init_params_host, load_quantized
 
 
@@ -156,20 +157,24 @@ def test_quantize_tensor_compiled_matches_eager(bits, channel_axis, clip):
     np.testing.assert_array_equal(np.asarray(got.values), np.asarray(vals))
 
 
-@pytest.mark.parametrize("arch,layers,host_bytes", [
-    ("glm4-9b", 0, None),
-    ("glm4-9b", 1, 1),  # one layer; a budget that runs one slice at a time
-    ("deepseek-moe-16b", 0, None),
+@pytest.mark.parametrize("arch,layers,serial", [
+    ("glm4-9b", 0, False),
+    ("glm4-9b", 1, True),  # one layer; no memory for a second slice at once
+    ("deepseek-moe-16b", 0, False),
 ])
-def test_load_quantized_matches_whole_tree_quantize_params(arch, layers, host_bytes):
+def test_load_quantized_matches_whole_tree_quantize_params(
+        arch, layers, serial, monkeypatch):
     cfg = smoke_config(arch)
     if arch == "glm4-9b":  # odd widths exercise the 128-lane padding
         cfg = dataclasses.replace(cfg, d_ff=200)
     if layers:
         cfg = dataclasses.replace(cfg, n_layers=layers)
     want = quantize_params(init_params_host(cfg, seed=11), SERVE_RECIPE)
-    kw = {"host_bytes": host_bytes} if host_bytes else {}
-    got = load_quantized(cfg, seed=11, workers=3, **kw)
+    monkeypatch.setattr(weights, "MAX_WORKERS", 3)
+    if serial:
+        monkeypatch.setattr(weights, "HOST_RESERVE_BYTES", 2**62)
+        monkeypatch.setattr(weights, "_host_available", lambda: 2**40)
+    got = load_quantized(cfg, seed=11)
     assert isinstance(got["layers"], list) and len(got["layers"]) == cfg.n_layers
     got["layers"] = jax.tree.map(lambda *xs: jnp.stack(xs), *got["layers"])
     got_l, got_t = jax.tree_util.tree_flatten(got)

@@ -15,7 +15,7 @@ from repro.models import transformer as T
 def test_int8_cache_matches_float_decode(arch):
     cfg = smoke_config(arch)
     cfg8 = dataclasses.replace(cfg, kv_bits=8)
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    params = T.per_layer(T.init_params(cfg, jax.random.PRNGKey(0)))
     rng = np.random.RandomState(0)
     tokens = rng.randint(0, cfg.vocab, (2, 12))
 
@@ -49,7 +49,7 @@ def test_int8_paged_cache_matches_float_decode(arch):
 
     cfg = smoke_config(arch)
     cfg8 = dataclasses.replace(cfg, kv_bits=8)
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    params = T.per_layer(T.init_params(cfg, jax.random.PRNGKey(0)))
     rng = np.random.RandomState(0)
     tokens = rng.randint(0, cfg.vocab, (2, 12))
     B, L, ps = 2, 32, 8

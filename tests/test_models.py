@@ -93,7 +93,8 @@ def test_smoke_decode_step(arch):
         pytest.skip("encoder-only: no decode step (per assignment)")
     params = T.init_params(cfg, jax.random.key(0))
     caches = T.init_cache(cfg, 2, 64)
-    logits, caches = T.decode_step(params, jnp.zeros((2, 1), jnp.int32), caches, cfg)
+    logits, caches = T.decode_step(
+        T.per_layer(params), jnp.zeros((2, 1), jnp.int32), caches, cfg)
     assert logits.shape == (2, cfg.vocab)
     assert not bool(jnp.any(jnp.isnan(logits.astype(jnp.float32))))
     assert caches["pos"].shape == (2,)  # per-slot positions
@@ -111,9 +112,10 @@ def test_decode_matches_forward(arch):
     full = T.forward(params, toks, cfg).astype(jnp.float32)
 
     caches = T.init_cache(cfg, 1, 32, dtype=jnp.float32)
+    served = T.per_layer(params)
     outs = []
     for t in range(s):
-        lg, caches = T.decode_step(params, toks[:, t : t + 1], caches, cfg)
+        lg, caches = T.decode_step(served, toks[:, t : t + 1], caches, cfg)
         outs.append(np.asarray(lg.astype(jnp.float32)))
     dec = np.stack(outs, axis=1)  # [1, s, V]
     # bf16 compute: tolerances are loose but trends must match exactly.

@@ -227,7 +227,7 @@ def test_legacy_decode_path_survives_poisoned_trash_page():
     """End to end through attention_decode's *gather* path: an active lane
     decodes next to a retired (all-trash) lane whose page 0 holds NaN."""
     cfg = smoke_config("deepseek-7b")
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    params = T.per_layer(T.init_params(cfg, jax.random.PRNGKey(0)))
     B, L, ps = 2, 32, 8
     t = L // ps
     caches = kvc.init_paged_cache(cfg, B, B * t + 1, ps, t, dtype=jnp.float32)

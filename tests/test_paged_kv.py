@@ -102,7 +102,7 @@ def test_paged_decode_bitexact_vs_contiguous(kv_bits):
     cache, so paged decode logits equal contiguous-cache logits *bitwise* —
     float pages and int8 pages alike (same quant grid, same values)."""
     cfg = dataclasses.replace(smoke_config("deepseek-7b"), kv_bits=kv_bits)
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    params = T.per_layer(T.init_params(cfg, jax.random.PRNGKey(0)))
     rng = np.random.RandomState(0)
     tokens = rng.randint(0, cfg.vocab, (2, 12))
     B, L, ps = 2, 32, 8

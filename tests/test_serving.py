@@ -35,6 +35,7 @@ def test_prefill_with_cache_matches_replay(dense_setup):
     """One-shot prefill == token-by-token replay: same pos, same first token,
     K/V rows equal to bf16 accumulation noise (layer 0 exactly)."""
     cfg, params = dense_setup
+    params = T.per_layer(params)
     rng = np.random.default_rng(3)
     prompt = rng.integers(0, cfg.vocab, 9).tolist()
 

@@ -65,7 +65,7 @@ def test_verify_step_equals_sequential_decode(paged):
     decode steps — the primitive the exactness contract rests on (float
     caches; the paged run addresses the same positions through a table)."""
     cfg = smoke_config("deepseek-7b")
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    params = T.per_layer(T.init_params(cfg, jax.random.PRNGKey(0)))
     rng = np.random.RandomState(0)
     tokens = rng.randint(0, cfg.vocab, (2, 5))
     B, L, ps = 2, 32, 8
@@ -100,7 +100,7 @@ def test_truncated_draft_runs_prefix_only():
     """layers_limit: the drafter runs the first L layers (different logits)
     and leaves the skipped layers' caches untouched."""
     cfg = smoke_config("glm4-9b")
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    params = T.per_layer(T.init_params(cfg, jax.random.PRNGKey(0)))
     caches = T.init_cache(cfg, 1, 32, dtype=jnp.float32)
     tok = jnp.asarray([[37]], jnp.int32)
     lg_full, _ = T.decode_step(params, tok, caches, cfg)
@@ -115,7 +115,7 @@ def test_truncated_draft_runs_prefix_only():
 
 def test_multi_token_decode_rejects_ssm():
     cfg = smoke_config("mamba2-1.3b")
-    params = T.init_params(cfg, jax.random.PRNGKey(1))
+    params = T.per_layer(T.init_params(cfg, jax.random.PRNGKey(1)))
     caches = T.init_cache(cfg, 1, 16, dtype=jnp.float32)
     with pytest.raises(NotImplementedError):
         T.verify_step(params, jnp.zeros((1, 3), jnp.int32), caches, cfg)
