@@ -487,10 +487,9 @@ def attention_decode(
             from repro.kernels import ops as kops
 
             force = {"pallas": None, "xla": "ref", "gather": "gather"}[kernel]
-            with jax.named_scope(f"paged_attention_{kernel}"):
-                out, new_cache = kops.paged_attention(
-                    cache, table, pos, q, k, v, force=force,
-                )
+            out, new_cache = kops.paged_attention(
+                cache, table, pos, q, k, v, force=force,
+            )
             new_cache = _kvc._shard_pool(new_cache)
             out = out.astype(x.dtype).reshape(b, qn, h * hd)
             return dense(params["wo"], out, name="attn_o"), new_cache

@@ -2,9 +2,11 @@
 
 Three host-side subsystems, all off-by-default-cheap and bounded-memory:
 
-* :mod:`repro.obs.trace`   — typed span events in a bounded ring buffer,
-  exported as Chrome trace-event JSON (loadable in Perfetto / chrome://tracing)
-  plus a per-request timeline (``trace_request``).
+* :mod:`repro.obs.trace`   — the engine's phase spans (``span``: a
+  ``jax.profiler`` annotation, and with a ring attached a typed event in a
+  bounded ring buffer), exported as Chrome trace-event JSON (loadable in
+  Perfetto / chrome://tracing) plus a per-request timeline
+  (``trace_request``).
 * :mod:`repro.obs.metrics` — Counter/Gauge/Histogram primitives with a central
   registry, Prometheus text exposition, and JSONL snapshots. The engine's
   stats-v8 dict view is derived from this registry.
@@ -16,7 +18,7 @@ Three host-side subsystems, all off-by-default-cheap and bounded-memory:
 """
 from .log import get_logger, setup_logging
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
-from .trace import SpanEvent, TraceRing
+from .trace import SpanEvent, TraceRing, span
 
 __all__ = [
     "Counter",
@@ -27,4 +29,5 @@ __all__ = [
     "TraceRing",
     "get_logger",
     "setup_logging",
+    "span",
 ]

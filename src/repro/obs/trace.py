@@ -44,6 +44,12 @@ duration, ``i`` = instant. :meth:`TraceRing.chrome_trace` renders the ring
 as a Perfetto-loadable ``{"traceEvents": [...]}`` document with one track
 (pid/tid pair) per request plus an engine lane; :meth:`TraceRing.
 trace_request` gives a single request's timeline.
+
+The ``X`` kinds are timed by :class:`span`, the one span mechanism of the
+engine: every span it opens is a ``jax.profiler.TraceAnnotation`` on the
+profiler's clock, and a span given a ring ``kind`` also lands in the ring,
+from the same call. Ring events and profiler host events join on their
+``step`` (and, per request, ``uid``) args.
 """
 from __future__ import annotations
 
@@ -53,7 +59,9 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
-__all__ = ["SpanEvent", "TraceRing", "ENGINE_TRACK"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["SpanEvent", "TraceRing", "ENGINE_TRACK", "span"]
 
 # track id for engine-wide (non-request) events; request tracks use the
 # request uid (a non-negative int)
@@ -185,6 +193,61 @@ class TraceRing:
         for e in self._ring:
             out[e.kind] = out.get(e.kind, 0) + 1
         return out
+
+
+class span:
+    """One phase of engine work, on the profiler's clock and in the ring.
+
+    ``with span("engine.dispatch", ring, kind="decode_step", step=n):``
+    always opens a ``jax.profiler.TraceAnnotation`` named ``name`` with the
+    args ``step``, ``uid`` (when given) and ``args``; with no profiler
+    running that costs a few microseconds of host time. When ``ring`` is a
+    :class:`TraceRing` and ``kind`` names a ring span kind, the same span is
+    emitted into the ring on exit (``track`` = ``uid``, or the engine lane).
+    Args known only at the end go through :meth:`set`.
+    """
+
+    __slots__ = ("_ann", "_ring", "_kind", "_uid", "_step", "_args", "_t0")
+
+    def __init__(self, name: str, ring: Optional[TraceRing] = None, *,
+                 kind: Optional[str] = None, step: int = 0, uid=None,
+                 **args):
+        self._ring = ring if kind is not None else None
+        self._kind = kind
+        self._uid = uid
+        self._step = step
+        self._args = args
+        if uid is None:
+            self._ann = TraceAnnotation(name, step=step, **args)
+        else:
+            self._ann = TraceAnnotation(name, step=step, uid=uid, **args)
+
+    @property
+    def on(self) -> bool:
+        """True when someone records this span: a ring, or the profiler."""
+        return self._ring is not None or TraceAnnotation.is_enabled()
+
+    def set(self, **args) -> None:
+        """Add args to the span (both clocks)."""
+        self._args.update(args)
+        if TraceAnnotation.is_enabled():
+            self._ann.set_metadata(**args)
+
+    def __enter__(self) -> "span":
+        if self._ring is not None:
+            self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        if self._ring is not None:
+            self._ring.emit(
+                self._kind,
+                track=ENGINE_TRACK if self._uid is None else self._uid,
+                ts=self._t0, dur=time.perf_counter() - self._t0,
+                step=self._step, **self._args,
+            )
 
 
 def validate_chrome_trace(doc: dict) -> Optional[str]:

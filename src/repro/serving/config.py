@@ -372,8 +372,8 @@ class EngineConfig:
         default="",
         metadata={
             "help": "jax.profiler trace output directory ('' = off); run() "
-            "wraps the serving loop in a profiler window, with named_scope "
-            "labels on the prefill/decode/verify/attention dispatches",
+            "wraps the serving loop in a profiler window, which records the "
+            "engine's phase spans and the jit_serving_* programs",
         },
     )
     drift_every: int = dataclasses.field(

@@ -82,11 +82,15 @@ float model in low precision. This engine is that provider's serving loop:
   ``EngineConfig.drift_every`` samples a
   :class:`~repro.obs.drift.QuantDriftMonitor` eager forward every N steps,
   tracking live activation saturation against the calibrated OCS/clip
-  grid; ``EngineConfig.profile_dir`` wraps :meth:`ServingEngine.run` in a
-  ``jax.profiler`` trace window (``jax.named_scope`` labels the jitted
-  prefill/decode/verify dispatches);
-* **stats** — a typed :class:`EngineStats` (schema v10: v8 plus the
-  precision-tier fields), *derived from the metrics registry* —
+  grid; each phase of a step (``engine.step`` / ``admit`` / ``prefill`` /
+  ``grow`` / ``inputs`` / ``dispatch`` / ``wait`` / ``book``) is a
+  :class:`~repro.obs.trace.span` on the ``jax.profiler`` clock, and the
+  jitted programs carry stable names (XLA modules ``jit_serving_decode_step``,
+  ``jit_serving_prefill``, ``jit_serving_prefill_chunk``,
+  ``jit_serving_replay``); ``EngineConfig.profile_dir`` wraps
+  :meth:`ServingEngine.run` in a ``jax.profiler`` trace window;
+* **stats** — a typed :class:`EngineStats` (schema v11: v8 plus the
+  precision-tier fields, less four unread ones), *derived from the metrics registry* —
   percentiles come from registry histograms, counts from registry
   counters; ``stats()`` keeps returning the flat dict view and
   :meth:`ServingEngine.metrics_text` renders the same registry as
@@ -115,7 +119,7 @@ from repro.models import transformer as T
 from repro.obs.drift import QuantDriftMonitor, clips_from_params
 from repro.obs.log import get_logger
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import TraceRing
+from repro.obs.trace import TraceRing, span
 from repro.runtime.health import HeartbeatMonitor, StepTimer
 from . import kv_cache as kvc
 from . import sampling as sampling_mod
@@ -219,12 +223,16 @@ class TokenEvent:
 
 @dataclasses.dataclass
 class EngineStats:
-    """Typed serving counters (stats schema v10, frozen).
+    """Typed serving counters (stats schema v11).
 
     The dict view (:meth:`as_dict`, what ``ServingEngine.stats()`` returns)
     is the stable cross-PR schema consumed by benchmarks — append fields,
-    never rename. v10 additions over v8 (v9 was the router schema rev —
-    ``serving.router`` — no engine fields changed): the precision-tier
+    never rename. v11 removed four fields no consumer read:
+    ``prefill_time_s`` and ``decode_time_s`` (the warm wall times stay
+    engine attributes, behind ``prefill_tok_per_s`` / ``decode_tok_per_s``),
+    ``spec_enabled`` and ``spec_accepted``. v10 additions over v8 (v9 was
+    the router schema rev — ``serving.router`` — no engine fields
+    changed): the precision-tier
     fields ``kv_bits`` (0 = float KV, 8 / 4 = quantized page tiers; pairs
     with ``matmul_mode``, which gains the ``"w4a8"`` vocabulary) and the
     capacity gauges ``kv_bytes_per_token`` (per-token KV footprint across
@@ -270,10 +278,8 @@ class EngineStats:
     itl_p50_s: float = 0.0
     itl_p95_s: float = 0.0
     prefill_tokens: int = 0
-    prefill_time_s: float = 0.0
     prefill_compile_s: float = 0.0
     prefill_tok_per_s: float = 0.0
-    decode_time_s: float = 0.0
     decode_compile_s: float = 0.0
     decode_tok_per_s: float = 0.0
     prefill_calls: int = 0
@@ -297,11 +303,9 @@ class EngineStats:
     kv_bytes_per_token: float = 0.0
     kv_pool_capacity_tokens: float = 0.0
     attn_step_ms: float = 0.0
-    spec_enabled: float = 0.0
     spec_rounds: float = 0.0
     spec_k: float = 0.0
     spec_proposed: float = 0.0
-    spec_accepted: float = 0.0
     spec_acceptance_rate: float = 0.0
     spec_tokens_per_target_step: float = 0.0
     spec_draft_time_s: float = 0.0
@@ -663,7 +667,7 @@ class ServingEngine:
         self.prefill_traces = 0  # distinct prefill compilations (buckets)
         self.decode_traces = 0
 
-        self._decode = jax.jit(self._decode_impl, static_argnames=("sampled",))
+        self._decode = self._decode_fn()
         # Prefill jits per shape key: prompt-length bucket (pow2 padding
         # bounds recompiles) + the sampled flag, plus the prefix-hit page
         # count when paged.
@@ -683,11 +687,25 @@ class ServingEngine:
         paged-attention dispatch, i.e. ``kernels.attn`` is pallas/xla)."""
         return self.paged and self.attn_kernel in ("pallas", "xla")
 
+    def _span(self, name: str, kind: Optional[str] = None, **args) -> span:
+        """A phase span (:class:`~repro.obs.trace.span`) on this engine's
+        ring, stamped with the step in progress (``steps`` at its start)."""
+        return span(name, self.trace, kind=kind, step=self.steps, **args)
+
+    def _decode_fn(self) -> Callable:
+        """The jitted decode step. Its XLA module reads
+        ``jit_serving_decode_step(...)``: profiler traces find decode
+        programs by that name."""
+        impl = self._decode_impl
+
+        def serving_decode_step(params, caches, token, samp, fault, *, sampled):
+            return impl(params, caches, token, samp, fault, sampled=sampled)
+
+        return jax.jit(serving_decode_step, static_argnames=("sampled",))
+
     def _decode_impl(self, params, caches, token, samp, fault, *, sampled: bool):
         self.decode_traces += 1  # python side effect: runs only while tracing
-        with jax.named_scope("serving_decode_step"), layers.serving_mode(
-            self.matmul_mode, kernel=self.matmul_kernel
-        ):
+        with layers.serving_mode(self.matmul_mode, kernel=self.matmul_kernel):
             logits, new_caches = T.decode_step(
                 params, token, caches, self.cfg, attn_kernel=self.attn_kernel
             )
@@ -741,10 +759,10 @@ class ServingEngine:
         sampled = key[-1]
         if self.paged:
 
-            def impl(params, tokens, length, page_ids, prefix_ids, pools,
-                     samp, samp_pos):
+            def serving_prefill(params, tokens, length, page_ids, prefix_ids,
+                                pools, samp, samp_pos):
                 self.prefill_traces += 1
-                with jax.named_scope("serving_prefill"), layers.serving_mode(
+                with layers.serving_mode(
                     self.matmul_mode, kernel=self.matmul_kernel
                 ):
                     logits, new_pools = T.prefill_into_pages(
@@ -760,9 +778,9 @@ class ServingEngine:
 
         else:
 
-            def impl(params, tokens, length, samp):
+            def serving_prefill(params, tokens, length, samp):
                 self.prefill_traces += 1
-                with jax.named_scope("serving_prefill"), layers.serving_mode(
+                with layers.serving_mode(
                     self.matmul_mode, kernel=self.matmul_kernel
                 ):
                     logits, scratch = T.prefill_with_cache(
@@ -776,7 +794,7 @@ class ServingEngine:
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return nxt, finite, scratch
 
-        fn = jax.jit(impl)
+        fn = jax.jit(serving_prefill)  # XLA module jit_serving_prefill(...)
         self._prefill_cache[key] = fn
         return fn
 
@@ -793,12 +811,11 @@ class ServingEngine:
         sampled = key[-1]
         if self.paged:
 
-            def impl(params, tokens, length, page_ids, prefix_ids, prefix_len,
-                     pools, samp, samp_pos):
+            def serving_prefill_chunk(params, tokens, length, page_ids,
+                                      prefix_ids, prefix_len, pools, samp,
+                                      samp_pos):
                 self.prefill_traces += 1
-                with jax.named_scope(
-                    "serving_prefill_chunk"
-                ), layers.serving_mode(
+                with layers.serving_mode(
                     self.matmul_mode, kernel=self.matmul_kernel
                 ):
                     logits, new_pools = T.prefill_into_pages(
@@ -816,11 +833,10 @@ class ServingEngine:
         else:
             prefix_pad = key[1]
 
-            def impl(params, tokens, length, start, scratch, samp, samp_pos):
+            def serving_prefill_chunk(params, tokens, length, start, scratch,
+                                      samp, samp_pos):
                 self.prefill_traces += 1
-                with jax.named_scope(
-                    "serving_prefill_chunk"
-                ), layers.serving_mode(
+                with layers.serving_mode(
                     self.matmul_mode, kernel=self.matmul_kernel
                 ):
                     logits, new_scratch = T.prefill_chunk_with_cache(
@@ -834,7 +850,7 @@ class ServingEngine:
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return nxt, finite, new_scratch
 
-        fn = jax.jit(impl)
+        fn = jax.jit(serving_prefill_chunk)  # jit_serving_prefill_chunk(...)
         self._chunk_cache[key] = fn
         return fn
 
@@ -863,37 +879,49 @@ class ServingEngine:
         """
         n = len(prompt)
         self._validate_prompt_len(n)  # backstop; submit() already rejected
-        traces0 = self.prefill_traces + self.decode_traces
-        t0 = time.perf_counter()
-        if self.cfg.block in ("dense", "moe"):
-            bucket = self._prefill_bucket(n)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :n] = prompt
-            nxt, finite, scratch = self._prefill_fn((bucket, not sp.greedy))(
-                self.params, jnp.asarray(toks), jnp.asarray([n], jnp.int32),
-                self._samp_one(sp),
-            )
-            self.prefill_calls += 1
-            first = int(nxt[0])
-        else:
-            scratch = T.init_cache(self.cfg, 1, self.max_len, dtype=jnp.float32)
-            tok = jnp.asarray(prompt, jnp.int32)[None, :]
-            samp1 = self._samp_one(sp)
-            zero_fault = jnp.zeros((1,), jnp.float32)
-            nxt = finite = None
-            for i in range(tok.shape[1]):
-                nxt, finite, scratch = self._decode(
-                    self.params, scratch, tok[:, i : i + 1], samp1, zero_fault,
-                    sampled=not sp.greedy,
-                )
+        with self._span("engine.prefill", "prefill", uid=uid, tokens=n) as pre:
+            traces0 = self.prefill_traces + self.decode_traces
+            t0 = time.perf_counter()
+            if self.cfg.block in ("dense", "moe"):
+                with self._span("engine.inputs", uid=uid):
+                    bucket = self._prefill_bucket(n)
+                    toks = np.zeros((1, bucket), np.int32)
+                    toks[0, :n] = prompt
+                    args = (self.params, jnp.asarray(toks),
+                            jnp.asarray([n], jnp.int32), self._samp_one(sp))
+                with self._span("engine.dispatch", uid=uid,
+                                program="prefill") as d:
+                    nxt, finite, scratch = self._prefill_fn(
+                        (bucket, not sp.greedy))(*args)
+                    d.set(traced=self.prefill_traces + self.decode_traces
+                          > traces0)
                 self.prefill_calls += 1
-            first = int(nxt[0, 0])
-        elapsed = time.perf_counter() - t0
-        traced = self.prefill_traces + self.decode_traces > traces0
-        self._book_prefill(n, elapsed, traced)
-        if self.trace is not None:
-            self.trace.emit("prefill", track=uid, ts=t0, dur=elapsed,
-                            step=self.steps, tokens=n, traced=traced)
+                with self._span("engine.wait", uid=uid):
+                    first = int(nxt[0])
+            else:
+                with self._span("engine.inputs", uid=uid):
+                    scratch = T.init_cache(self.cfg, 1, self.max_len,
+                                           dtype=jnp.float32)
+                    tok = jnp.asarray(prompt, jnp.int32)[None, :]
+                    samp1 = self._samp_one(sp)
+                    zero_fault = jnp.zeros((1,), jnp.float32)
+                nxt = finite = None
+                with self._span("engine.dispatch", uid=uid, program="prefill",
+                                calls=n) as d:
+                    for i in range(tok.shape[1]):
+                        nxt, finite, scratch = self._decode(
+                            self.params, scratch, tok[:, i : i + 1], samp1,
+                            zero_fault, sampled=not sp.greedy,
+                        )
+                        self.prefill_calls += 1
+                    d.set(traced=self.prefill_traces + self.decode_traces
+                          > traces0)
+                with self._span("engine.wait", uid=uid):
+                    first = int(nxt[0, 0])
+            elapsed = time.perf_counter() - t0
+            traced = self.prefill_traces + self.decode_traces > traces0
+            self._book_prefill(n, elapsed, traced)
+            pre.set(traced=traced)
         return first, bool(finite[0]), scratch
 
     def _run_prefill_paged(
@@ -910,38 +938,42 @@ class ServingEngine:
         finite flag)``.
         """
         m = len(suffix)  # >= 1: admission caps prefix hits at (n-1)//page_size
-        bucket = self._prefill_bucket(m)
-        nb = bucket // self.page_size
-        ids = np.full((nb,), kvc.TRASH_PAGE, np.int32)
-        k = min(nb, len(new_ids))
-        ids[:k] = new_ids[:k]
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :m] = suffix
-        pools = [layer["attn"] for layer in self.caches["layers"]]
-        traces0 = self.prefill_traces
-        t0 = time.perf_counter()
-        nxt, finite, new_pools = self._prefill_fn(
-            (bucket, len(hit_ids), not sp.greedy)
-        )(
-            self.params,
-            jnp.asarray(toks),
-            jnp.asarray([m], jnp.int32),
-            jnp.asarray(ids),
-            jnp.asarray(hit_ids, jnp.int32),
-            pools,
-            self._samp_one(sp),
-            jnp.asarray([n_total - 1], jnp.int32),
-        )
-        self.prefill_calls += 1
-        first = int(nxt[0])
-        self.caches["layers"] = [{"attn": p} for p in new_pools]
-        elapsed = time.perf_counter() - t0
-        traced = self.prefill_traces > traces0
-        self._book_prefill(m, elapsed, traced)
-        if self.trace is not None:
-            self.trace.emit("prefill", track=uid, ts=t0, dur=elapsed,
-                            step=self.steps, tokens=m, traced=traced)
-        return first, bool(finite[0])
+        with self._span("engine.prefill", "prefill", uid=uid, tokens=m) as pre:
+            t0 = time.perf_counter()
+            with self._span("engine.inputs", uid=uid):
+                bucket = self._prefill_bucket(m)
+                nb = bucket // self.page_size
+                ids = np.full((nb,), kvc.TRASH_PAGE, np.int32)
+                k = min(nb, len(new_ids))
+                ids[:k] = new_ids[:k]
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :m] = suffix
+                args = (
+                    self.params,
+                    jnp.asarray(toks),
+                    jnp.asarray([m], jnp.int32),
+                    jnp.asarray(ids),
+                    jnp.asarray(hit_ids, jnp.int32),
+                    [layer["attn"] for layer in self.caches["layers"]],
+                    self._samp_one(sp),
+                    jnp.asarray([n_total - 1], jnp.int32),
+                )
+            traces0 = self.prefill_traces
+            with self._span("engine.dispatch", uid=uid, program="prefill") as d:
+                nxt, finite, new_pools = self._prefill_fn(
+                    (bucket, len(hit_ids), not sp.greedy)
+                )(*args)
+                d.set(traced=self.prefill_traces > traces0)
+            self.prefill_calls += 1
+            with self._span("engine.wait", uid=uid):
+                first = int(nxt[0])
+                ok = bool(finite[0])
+            self.caches["layers"] = [{"attn": p} for p in new_pools]
+            elapsed = time.perf_counter() - t0
+            traced = self.prefill_traces > traces0
+            self._book_prefill(m, elapsed, traced)
+            pre.set(traced=traced)
+        return first, ok
 
     def _replay_fn(self, bucket: int) -> Callable:
         """b=1 multi-token decode over the page pool: the preemption-resume
@@ -955,14 +987,14 @@ class ServingEngine:
         if fn is not None:
             return fn
 
-        def impl(params, pools, table1, pos1, tokens):
+        def serving_replay(params, pools, table1, pos1, tokens):
             self.decode_traces += 1  # python side effect: bumps only tracing
             caches = {
                 "layers": [{"attn": p} for p in pools],
                 "table": table1,
                 "pos": pos1,
             }
-            with jax.named_scope("serving_replay"), layers.serving_mode(
+            with layers.serving_mode(
                 self.matmul_mode, kernel=self.matmul_kernel
             ):
                 _, new_caches = T.decode_tokens(
@@ -971,7 +1003,7 @@ class ServingEngine:
                 )
             return [layer["attn"] for layer in new_caches["layers"]]
 
-        fn = jax.jit(impl)
+        fn = jax.jit(serving_replay)  # XLA module jit_serving_replay(...)
         self._replay_cache[bucket] = fn
         return fn
 
@@ -983,21 +1015,28 @@ class ServingEngine:
         speculative window."""
         if len(tokens) == 0:
             return
-        bucket = 8
-        while bucket < len(tokens):
-            bucket *= 2
-        bucket = min(bucket, self.max_len)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, : len(tokens)] = tokens
-        table1 = self.caches["table"][slot_idx : slot_idx + 1]
-        pos1 = jnp.asarray([start], jnp.int32)
-        pools = [layer["attn"] for layer in self.caches["layers"]]
-        traces0 = self.decode_traces
         t0 = time.perf_counter()
-        new_pools = self._replay_fn(bucket)(
-            self.params, pools, table1, pos1, jnp.asarray(toks)
-        )
-        jax.block_until_ready(new_pools)
+        with self._span("engine.inputs"):
+            bucket = 8
+            while bucket < len(tokens):
+                bucket *= 2
+            bucket = min(bucket, self.max_len)
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, : len(tokens)] = tokens
+            args = (
+                self.params,
+                [layer["attn"] for layer in self.caches["layers"]],
+                self.caches["table"][slot_idx : slot_idx + 1],
+                jnp.asarray([start], jnp.int32),
+                jnp.asarray(toks),
+            )
+        traces0 = self.decode_traces
+        with self._span("engine.dispatch", program="replay",
+                        tokens=len(tokens)) as d:
+            new_pools = self._replay_fn(bucket)(*args)
+            d.set(traced=self.decode_traces > traces0)
+        with self._span("engine.wait"):
+            jax.block_until_ready(new_pools)
         elapsed = time.perf_counter() - t0
         self.caches["layers"] = [{"attn": p} for p in new_pools]
         if self.decode_traces > traces0:
@@ -1308,14 +1347,19 @@ class ServingEngine:
         if not lanes:
             return
         for slot_idx, grant in self._sched.plan_chunks(lanes):
-            if not self.slots[slot_idx].prefilling:
+            slot = self.slots[slot_idx]
+            if not slot.prefilling:
                 continue  # quarantined by an earlier chunk this step
-            if self.paged:
-                self._run_chunk_paged(slot_idx, grant)
-            elif self.cfg.block in ("dense", "moe"):
-                self._run_chunk_unpaged(slot_idx, grant)
-            else:
-                self._run_chunk_replay(slot_idx, grant)
+            start = slot.prefill_pos
+            with self._span("engine.prefill", "prefill_chunk", uid=slot.req.uid,
+                            start=start, grant=grant,
+                            final=start + grant >= len(slot.req.prompt)):
+                if self.paged:
+                    self._run_chunk_paged(slot_idx, grant)
+                elif self.cfg.block in ("dense", "moe"):
+                    self._run_chunk_unpaged(slot_idx, grant)
+                else:
+                    self._run_chunk_replay(slot_idx, grant)
 
     def _run_chunk_paged(self, slot_idx: int, grant: int) -> None:
         """One chunk of lane ``slot_idx``'s prompt straight into its pages.
@@ -1335,48 +1379,49 @@ class ServingEngine:
         final = end >= n
         sp = req.sampling or _GREEDY
         ps = self.page_size
-        bucket = self._prefill_bucket(grant)
-        nb = bucket // ps
-        p0 = start // ps
-        ids = np.full((nb,), kvc.TRASH_PAGE, np.int32)
-        have = slot.pages[p0 : p0 + nb]
-        ids[: len(have)] = have  # bucket pads past the need write to trash
-        pp = 0
-        if p0:
-            pp = 1
-            while pp < p0:
-                pp *= 2
-        pref = np.full((pp,), kvc.TRASH_PAGE, np.int32)
-        pref[:p0] = slot.pages[:p0]
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :grant] = prompt[start:end]
-        pools = [layer["attn"] for layer in self.caches["layers"]]
-        traces0 = self.prefill_traces
         t0 = time.perf_counter()
-        nxt, finite, new_pools = self._prefill_chunk_fn(
-            (bucket, pp, not sp.greedy)
-        )(
-            self.params,
-            jnp.asarray(toks),
-            jnp.asarray([grant], jnp.int32),
-            jnp.asarray(ids),
-            jnp.asarray(pref),
-            jnp.asarray(start, jnp.int32),
-            pools,
-            self._samp_one(sp),
-            jnp.asarray([n - 1], jnp.int32),
-        )
+        p0 = start // ps
+        with self._span("engine.inputs", uid=req.uid):
+            bucket = self._prefill_bucket(grant)
+            nb = bucket // ps
+            ids = np.full((nb,), kvc.TRASH_PAGE, np.int32)
+            have = slot.pages[p0 : p0 + nb]
+            ids[: len(have)] = have  # bucket pads past the need write to trash
+            pp = 0
+            if p0:
+                pp = 1
+                while pp < p0:
+                    pp *= 2
+            pref = np.full((pp,), kvc.TRASH_PAGE, np.int32)
+            pref[:p0] = slot.pages[:p0]
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :grant] = prompt[start:end]
+            args = (
+                self.params,
+                jnp.asarray(toks),
+                jnp.asarray([grant], jnp.int32),
+                jnp.asarray(ids),
+                jnp.asarray(pref),
+                jnp.asarray(start, jnp.int32),
+                [layer["attn"] for layer in self.caches["layers"]],
+                self._samp_one(sp),
+                jnp.asarray([n - 1], jnp.int32),
+            )
+        traces0 = self.prefill_traces
+        with self._span("engine.dispatch", uid=req.uid,
+                        program="prefill_chunk") as d:
+            nxt, finite, new_pools = self._prefill_chunk_fn(
+                (bucket, pp, not sp.greedy)
+            )(*args)
+            d.set(traced=self.prefill_traces > traces0)
         self.prefill_calls += 1
-        finite = bool(finite[0])
+        with self._span("engine.wait", uid=req.uid):
+            finite = bool(finite[0])
         self.caches["layers"] = [{"attn": p} for p in new_pools]
         elapsed = time.perf_counter() - t0
         self._book_prefill(
             grant, elapsed, self.prefill_traces > traces0, new_request=False
         )
-        if self.trace is not None:
-            self.trace.emit("prefill_chunk", track=req.uid, ts=t0,
-                            dur=elapsed, step=self.steps, start=start,
-                            grant=grant, final=final)
         if not finite:
             self.allocator.release(slot.pages)
             self.slots[slot_idx] = _Slot()
@@ -1417,41 +1462,44 @@ class ServingEngine:
         start = slot.prefill_pos
         end = start + grant
         sp = req.sampling or _GREEDY
-        bucket = self._prefill_bucket(grant)
-        prefix_pad = 0
-        if start:
-            prefix_pad = 8
-            while prefix_pad < start:
-                prefix_pad *= 2
-            prefix_pad = min(prefix_pad, self.max_len)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :grant] = prompt[start:end]
-        traces0 = self.prefill_traces
         t0 = time.perf_counter()
-        nxt, finite, slot.scratch = self._prefill_chunk_fn(
-            (bucket, prefix_pad, not sp.greedy)
-        )(
-            self.params,
-            jnp.asarray(toks),
-            jnp.asarray([grant], jnp.int32),
-            jnp.asarray(start, jnp.int32),
-            slot.scratch,
-            self._samp_one(sp),
-            jnp.asarray([n - 1], jnp.int32),
-        )
+        with self._span("engine.inputs", uid=req.uid):
+            bucket = self._prefill_bucket(grant)
+            prefix_pad = 0
+            if start:
+                prefix_pad = 8
+                while prefix_pad < start:
+                    prefix_pad *= 2
+                prefix_pad = min(prefix_pad, self.max_len)
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :grant] = prompt[start:end]
+            args = (
+                self.params,
+                jnp.asarray(toks),
+                jnp.asarray([grant], jnp.int32),
+                jnp.asarray(start, jnp.int32),
+                slot.scratch,
+                self._samp_one(sp),
+                jnp.asarray([n - 1], jnp.int32),
+            )
+        traces0 = self.prefill_traces
+        with self._span("engine.dispatch", uid=req.uid,
+                        program="prefill_chunk") as d:
+            nxt, finite, slot.scratch = self._prefill_chunk_fn(
+                (bucket, prefix_pad, not sp.greedy)
+            )(*args)
+            d.set(traced=self.prefill_traces > traces0)
         self.prefill_calls += 1
+        with self._span("engine.wait", uid=req.uid):
+            first, finite = int(nxt[0]), bool(finite[0])
         elapsed = time.perf_counter() - t0
         self._book_prefill(
             grant, elapsed, self.prefill_traces > traces0, new_request=False
         )
-        if self.trace is not None:
-            self.trace.emit("prefill_chunk", track=req.uid, ts=t0,
-                            dur=elapsed, step=self.steps, start=start,
-                            grant=grant, final=end >= n)
         if end >= n:
-            self._finalize_unpaged(slot_idx, int(nxt[0]), bool(finite[0]))
+            self._finalize_unpaged(slot_idx, first, finite)
         else:
-            if not bool(finite[0]):
+            if not finite:
                 self.slots[slot_idx] = _Slot()
                 self._quarantine(req)
                 return
@@ -1469,29 +1517,31 @@ class ServingEngine:
         start = slot.prefill_pos
         end = start + grant
         sp = req.sampling or _GREEDY
-        samp1 = self._samp_one(sp)
-        zero_fault = jnp.zeros((1,), jnp.float32)
-        tok = jnp.asarray(prompt[start:end], jnp.int32)[None, :]
-        traces0 = self.prefill_traces + self.decode_traces
         t0 = time.perf_counter()
+        with self._span("engine.inputs", uid=req.uid):
+            samp1 = self._samp_one(sp)
+            zero_fault = jnp.zeros((1,), jnp.float32)
+            tok = jnp.asarray(prompt[start:end], jnp.int32)[None, :]
+        traces0 = self.prefill_traces + self.decode_traces
         nxt = finite = None
-        for i in range(grant):
-            nxt, finite, slot.scratch = self._decode(
-                self.params, slot.scratch, tok[:, i : i + 1], samp1,
-                zero_fault, sampled=not sp.greedy,
-            )
-            self.prefill_calls += 1
+        with self._span("engine.dispatch", uid=req.uid,
+                        program="prefill_chunk", calls=grant) as d:
+            for i in range(grant):
+                nxt, finite, slot.scratch = self._decode(
+                    self.params, slot.scratch, tok[:, i : i + 1], samp1,
+                    zero_fault, sampled=not sp.greedy,
+                )
+                self.prefill_calls += 1
+            d.set(traced=self.prefill_traces + self.decode_traces > traces0)
         elapsed = time.perf_counter() - t0
         traced = self.prefill_traces + self.decode_traces > traces0
         self._book_prefill(grant, elapsed, traced, new_request=False)
-        if self.trace is not None:
-            self.trace.emit("prefill_chunk", track=req.uid, ts=t0,
-                            dur=elapsed, step=self.steps, start=start,
-                            grant=grant, final=end >= n)
         if end >= n:
             # The monolithic replay checks the final step only (an SSM NaN
             # propagates through the state) — keep that contract.
-            self._finalize_unpaged(slot_idx, int(nxt[0, 0]), bool(finite[0]))
+            with self._span("engine.wait", uid=req.uid):
+                first, finite = int(nxt[0, 0]), bool(finite[0])
+            self._finalize_unpaged(slot_idx, first, finite)
         else:
             slot.prefill_pos = end
 
@@ -1677,7 +1727,7 @@ class ServingEngine:
         self._fault_streak = 0
         if self.trace is not None:
             self.trace.emit("kernel_fallback", step=self.steps, kernel="xla")
-        self._decode = jax.jit(self._decode_impl, static_argnames=("sampled",))
+        self._decode = self._decode_fn()
         self._prefill_cache.clear()
         self._replay_cache.clear()
         self._chunk_cache.clear()
@@ -1971,27 +2021,48 @@ class ServingEngine:
         # preemption during growth rewrites lane state the snapshots must
         # already reflect (a stale snapshot would "rewind" a preempted lane
         # back to life at round end).
-        self._ensure_capacity(dec.controller.k + 1)
+        with self._span("engine.grow"):
+            self._ensure_capacity(dec.controller.k + 1)
         if not any(s.req for s in self.slots):
             return True  # growth preempted every lane; re-admit next step
-        pos0 = np.asarray(self.caches["pos"])
-        tok0 = np.asarray(self.tokens)[:, 0]
+        with self._span("engine.wait"):
+            pos0 = np.asarray(self.caches["pos"])
+            tok0 = np.asarray(self.tokens)[:, 0]
         warm0 = dec.draft_time_s + dec.verify_time_s
         compile0 = dec.compile_s
-        # Clamp the window to the largest remaining lane budget: drafts past
-        # every budget can never commit (k == 0 degenerates to a plain decode
-        # step through the verify jit when every lane needs exactly 1 token).
-        k_want = min(
-            dec.controller.k,
-            max(0, max(s.remaining for s in self.slots if s.req) - 1),
-        )
-        fault = self._fault_row(window=k_want + 1)
+        with self._span("engine.inputs"):
+            # Clamp the window to the largest remaining lane budget: drafts
+            # past every budget can never commit (k == 0 degenerates to a
+            # plain decode step through the verify jit when every lane needs
+            # exactly 1 token).
+            k_want = min(
+                dec.controller.k,
+                max(0, max(s.remaining for s in self.slots if s.req) - 1),
+            )
+            fault = jnp.asarray(self._fault_row(window=k_want + 1))
         dec.trace_step = self.steps  # spec spans land on the engine lane
         greedy, drafts, finite, self.caches, k = dec.propose_and_verify(
-            self.params, self.caches, self.tokens, k_want,
-            fault=jnp.asarray(fault),
+            self.params, self.caches, self.tokens, k_want, fault=fault,
         )
+        with self._span("engine.book"):
+            committed = self._book_spec_round(dec, greedy, drafts, finite, k,
+                                              pos0, tok0)
+        # Mirror into the engine's warm decode counters so decode_tok_per_s
+        # stays the end-to-end generation throughput under speculation.
+        warm_delta = (dec.draft_time_s + dec.verify_time_s) - warm0
+        if warm_delta > 0:
+            self.decode_time_s += warm_delta
+            self.decode_tokens_warm += committed
+        else:
+            self.decode_compile_s += dec.compile_s - compile0
         self.steps += 1
+        return True
+
+    def _book_spec_round(self, dec, greedy, drafts, finite, k, pos0,
+                         tok0) -> int:
+        """Commit each lane's accepted prefix of one speculation round, roll
+        back the rest, retire finished lanes, quarantine faulted ones.
+        Returns the tokens committed."""
         now = time.perf_counter()
         new_pos = pos0.copy()
         next_tok = tok0.copy()
@@ -2050,15 +2121,7 @@ class ServingEngine:
             self._retire(i)
         for r in faulted:
             self._note_fault(r)  # after retirement: may rebuild the decoder
-        # Mirror into the engine's warm decode counters so decode_tok_per_s
-        # stays the end-to-end generation throughput under speculation.
-        warm_delta = (dec.draft_time_s + dec.verify_time_s) - warm0
-        if warm_delta > 0:
-            self.decode_time_s += warm_delta
-            self.decode_tokens_warm += round_committed
-        else:
-            self.decode_compile_s += dec.compile_s - compile0
-        return True
+        return round_committed
 
     def step(self):
         """One engine iteration: shed expired deadlines, admit from queue,
@@ -2068,36 +2131,37 @@ class ServingEngine:
         the step-time percentiles (and the ``engine_step_seconds``
         histogram) and heartbeats ``heartbeat_path`` (throttled by
         ``heartbeat_interval_s``; the drain's final beat always lands).
-        With tracing on, the whole iteration lands as a ``step`` span on
-        the engine lane; with ``drift_every`` set, every Nth productive
-        step samples the quant-drift monitor *after* the timed window."""
-        t0 = time.perf_counter()
-        self._step_timer.start()
-        try:
-            out = self._step_impl()
-        finally:
-            self._hist_step.observe(self._step_timer.stop())
-        if self.trace is not None:
-            self.trace.emit(
-                "step", ts=t0, dur=time.perf_counter() - t0, step=self.steps,
-                active=sum(1 for s in self.slots if s.req is not None),
-                queued=len(self.queue),
-            )
-        if self._heartbeat is not None:
-            self._heartbeat.beat(
-                self.steps,
-                {"active": sum(1 for s in self.slots if s.req is not None),
-                 "queued": len(self.queue)},
-                force=not out and not self.queue,
-            )
-        if (
-            self._drift is not None
-            and out
-            and self.steps != self._drift_last_step
-            and self.steps % self.config.drift_every == 0
-        ):
-            self._drift_last_step = self.steps
-            self._drift_sample()
+        The whole iteration is an ``engine.step`` span (on the ring, a
+        ``step`` span on the engine lane), its phases ``engine.admit`` /
+        ``prefill`` / ``grow`` / ``inputs`` / ``dispatch`` / ``wait`` /
+        ``book`` spans inside it, every one stamped with ``step`` = ``steps``
+        at the iteration's start; with ``drift_every`` set, every Nth
+        productive step samples the quant-drift monitor *after* the timed
+        window."""
+        with self._span("engine.step", "step") as sp:
+            self._step_timer.start()
+            try:
+                out = self._step_impl()
+            finally:
+                self._hist_step.observe(self._step_timer.stop())
+            if self._heartbeat is not None:
+                self._heartbeat.beat(
+                    self.steps,
+                    {"active": sum(1 for s in self.slots if s.req is not None),
+                     "queued": len(self.queue)},
+                    force=not out and not self.queue,
+                )
+            if (
+                self._drift is not None
+                and out
+                and self.steps != self._drift_last_step
+                and self.steps % self.config.drift_every == 0
+            ):
+                self._drift_last_step = self.steps
+                self._drift_sample()
+            if sp.on:
+                sp.set(active=sum(1 for s in self.slots if s.req is not None),
+                       queued=len(self.queue))
         return out
 
     def _drift_sample(self) -> None:
@@ -2131,8 +2195,9 @@ class ServingEngine:
             _LOG.warning("quant-drift monitor disabled: %s", e)
 
     def _step_impl(self):
-        self._shed_expired()
-        self._admit()
+        with self._span("engine.admit"):
+            self._shed_expired()
+            self._admit()
         if self.chunked:
             # Budgeted prefill work first: decode lanes then step below in
             # the same iteration — one chunk's worth of prefill latency is
@@ -2157,35 +2222,46 @@ class ServingEngine:
         ):
             return self._spec_step()
         # Optimistic growth: the next decode writes one position per lane.
-        self._ensure_capacity(1)
+        with self._span("engine.grow"):
+            self._ensure_capacity(1)
         if not any(s.req is not None and not s.prefilling for s in self.slots):
             return True  # growth preempted every lane; re-admit next step
         n_active = sum(1 for s in self.slots if s.req and not s.prefilling)
-        traces0 = self.decode_traces
         t0 = time.perf_counter()
-        # Static per-round flag: greedy-only rounds skip the sampling branch
-        # entirely (no sort/softmax over [B, V] per step). Both variants
-        # compile at most once, so mixed workloads cannot retrace-thrash.
-        nxt, finite, self.caches = self._decode(
-            self.params, self.caches, self.tokens, self._samp_device(),
-            jnp.asarray(self._fault_row()),
-            sampled=self._active_sampled(),
-        )
-        self.steps += 1
-        nxt_np = np.asarray(nxt)  # sync point: decode step fully retired
-        finite_np = np.asarray(finite)
+        with self._span("engine.inputs"):
+            # Static per-round flag: greedy-only rounds skip the sampling
+            # branch entirely (no sort/softmax over [B, V] per step). Both
+            # variants compile at most once, so mixed workloads cannot
+            # retrace-thrash.
+            sampled = self._active_sampled()
+            samp = self._samp_device()
+            fault = jnp.asarray(self._fault_row())
+        traces0 = self.decode_traces
+        with self._span("engine.dispatch", "decode_step", program="decode",
+                        lanes=n_active) as d:
+            nxt, finite, self.caches = self._decode(
+                self.params, self.caches, self.tokens, samp, fault,
+                sampled=sampled,
+            )
+            d.set(traced=self.decode_traces > traces0)
+        with self._span("engine.wait"):
+            nxt_np = np.asarray(nxt)  # sync point: decode step fully retired
+            finite_np = np.asarray(finite)
         elapsed = time.perf_counter() - t0
-        now = time.perf_counter()
         if self.decode_traces > traces0:
             self.decode_compile_s += elapsed
         else:
             self.decode_time_s += elapsed
             self.decode_tokens_warm += n_active
-        if self.trace is not None:
-            self.trace.emit(
-                "decode_step", ts=t0, dur=elapsed, step=self.steps,
-                lanes=n_active, traced=self.decode_traces > traces0,
-            )
+        with self._span("engine.book"):
+            self._book_decode(nxt, nxt_np, finite_np)
+        self.steps += 1
+        return True
+
+    def _book_decode(self, nxt, nxt_np: np.ndarray, finite_np: np.ndarray):
+        """Book one decode step's tokens: ITL observations, eos/length
+        retirement, quarantine of lanes whose logits went nonfinite."""
+        now = time.perf_counter()
         faulted: List[Request] = []
         for i, slot in enumerate(self.slots):
             if slot.req is None or slot.prefilling:
@@ -2214,13 +2290,12 @@ class ServingEngine:
         self.tokens = nxt
         for r in faulted:
             self._note_fault(r)
-        return True
 
     def run(self, max_steps: int = 10_000) -> List[Request]:
         """Drive until queue and slots drain (or the step budget ends).
         ``EngineConfig.profile_dir`` wraps the whole drive in a
-        ``jax.profiler`` trace window (the ``jax.named_scope`` labels on
-        the prefill/decode/verify dispatches show up there)."""
+        ``jax.profiler`` trace window (the engine's phase spans and the
+        programs' ``jit_serving_*`` module names show up there)."""
         self.start_profile()
         try:
             for _ in range(max_steps):
@@ -2266,7 +2341,7 @@ class ServingEngine:
         if self._attn_probe_fn is None:
             p0 = self.params["layers"][0]["attn"]
 
-            def impl(p, pool, table, pos, x):
+            def attn_probe(p, pool, table, pos, x):
                 with layers.serving_mode(
                     self.matmul_mode, kernel=self.matmul_kernel
                 ):
@@ -2276,7 +2351,7 @@ class ServingEngine:
                     )
                 return y
 
-            self._attn_probe_fn = (jax.jit(impl), p0)
+            self._attn_probe_fn = (jax.jit(attn_probe), p0)
         fn, p0 = self._attn_probe_fn
         pool = self.caches["layers"][0]["attn"]
         table = self.caches["table"]
@@ -2407,7 +2482,7 @@ class ServingEngine:
         return self._drift.report() if self._drift is not None else {}
 
     def engine_stats(self) -> EngineStats:
-        """The typed v10 stats record (``stats()`` is its flat dict view),
+        """The typed v11 stats record (``stats()`` is its flat dict view),
         derived from the metrics registry: counts read registry counters
         (through the legacy attribute facade), percentiles read the
         bounded-reservoir registry histograms booked live at the event
@@ -2434,7 +2509,6 @@ class ServingEngine:
             itl_p50_s=self._hist_itl.percentile(50),
             itl_p95_s=self._hist_itl.percentile(95),
             prefill_tokens=self.prefill_tokens,
-            prefill_time_s=self.prefill_time_s,
             prefill_compile_s=self.prefill_compile_s,
             # Warm-only throughput: compile calls are excluded so the number
             # tracks kernels across PRs, not jit noise. 0.0 when every call
@@ -2444,7 +2518,6 @@ class ServingEngine:
                 if self.prefill_time_s > 0
                 else 0.0
             ),
-            decode_time_s=self.decode_time_s,
             decode_compile_s=self.decode_compile_s,
             decode_tok_per_s=(
                 self.decode_tokens_warm / self.decode_time_s
@@ -2477,7 +2550,6 @@ class ServingEngine:
             kv_bytes_per_token=gv("kv_bytes_per_token"),
             kv_pool_capacity_tokens=gv("kv_pool_capacity_tokens"),
             attn_step_ms=self._attn_step_ms(),
-            spec_enabled=1.0 if self._spec is not None else 0.0,
             queue_wait_p50_s=self._hist_qwait.percentile(50),
             queue_wait_p95_s=self._hist_qwait.percentile(95),
             sched_policy=self.config.sched_policy,
@@ -2504,7 +2576,7 @@ class ServingEngine:
         return s
 
     def stats(self) -> Dict:
-        """The flat dict view of :meth:`engine_stats` (stats schema v10)."""
+        """The flat dict view of :meth:`engine_stats` (stats schema v11)."""
         return self.engine_stats().as_dict()
 
 

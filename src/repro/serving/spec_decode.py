@@ -49,6 +49,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import layers
 from repro.models import transformer as T
+from repro.obs.trace import span
 
 __all__ = ["SpecConfig", "AdaptiveK", "SpecDecoder", "committed_tokens"]
 
@@ -164,9 +165,10 @@ class SpecDecoder:
         self.compile_s = 0.0  # draft+verify calls that triggered a trace
         self.draft_traces = 0
         self.verify_traces = 0
-        # Span tracing (PR 8): the engine attaches its TraceRing (or None)
-        # and stamps trace_step before each round, so draft/verify spans
-        # land on the engine lane with the right step index.
+        # Span tracing: the engine attaches its TraceRing (or None) and
+        # stamps trace_step before each round, so the round's
+        # engine.dispatch / engine.wait spans (ring kinds spec_draft /
+        # spec_verify) carry the engine's step.
         self.trace = None
         self.trace_step = 0
 
@@ -174,12 +176,11 @@ class SpecDecoder:
         # plain decode (``attn_kernel`` / ``matmul_kernel`` from the
         # resolved ``EngineConfig.kernels``): the exactness contract
         # compares verify logits against that path's own decode steps, so
-        # the two must go through one attention implementation.
-        def draft_impl(params, caches, token):
+        # the two must go through one attention implementation. XLA module
+        # names: jit_serving_spec_draft, jit_serving_spec_verify.
+        def serving_spec_draft(params, caches, token):
             self.draft_traces += 1  # python side effect: bumps only tracing
-            with jax.named_scope("spec_draft"), layers.serving_mode(
-                spec.draft_mode, kernel=matmul_kernel
-            ):
+            with layers.serving_mode(spec.draft_mode, kernel=matmul_kernel):
                 logits, new_caches = T.decode_step(
                     params, token, caches, cfg, layers_limit=spec.draft_layers,
                     attn_kernel=attn_kernel,
@@ -187,11 +188,9 @@ class SpecDecoder:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
             return nxt, new_caches
 
-        def verify_impl(params, caches, tokens, fault):
+        def serving_spec_verify(params, caches, tokens, fault):
             self.verify_traces += 1
-            with jax.named_scope("spec_verify"), layers.serving_mode(
-                matmul_mode, kernel=matmul_kernel
-            ):
+            with layers.serving_mode(matmul_mode, kernel=matmul_kernel):
                 logits, new_caches = T.verify_step(
                     params, tokens, caches, cfg, attn_kernel=attn_kernel
                 )
@@ -203,8 +202,8 @@ class SpecDecoder:
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, Q]
             return greedy, finite, new_caches
 
-        self._draft = jax.jit(draft_impl)
-        self._verify = jax.jit(verify_impl)  # one compile per distinct k
+        self._draft = jax.jit(serving_spec_draft)
+        self._verify = jax.jit(serving_spec_verify)  # one compile per k
 
     # ------------------------------------------------------------------ round
 
@@ -231,26 +230,37 @@ class SpecDecoder:
         if fault is None:
             fault = jnp.zeros((tokens.shape[0],), jnp.float32)
         pos0 = caches["pos"]
+        step = self.trace_step
         traces0 = self.draft_traces + self.verify_traces
         t0 = time.perf_counter()
-        cur, drafts = tokens, []
-        for _ in range(k):
-            cur, caches = self._draft(params, caches, cur)
-            drafts.append(cur)
-        if drafts:
-            draft_toks = jnp.concatenate(drafts, axis=1)  # [B, k]
-        else:
-            draft_toks = jnp.zeros((tokens.shape[0], 0), jnp.int32)
-        np_drafts = np.asarray(draft_toks)  # sync: draft chain fully retired
+        with span("engine.dispatch", self.trace, kind="spec_draft", step=step,
+                  program="spec_draft", k=k) as d:
+            cur, drafts = tokens, []
+            for _ in range(k):
+                cur, caches = self._draft(params, caches, cur)
+                drafts.append(cur)
+            if drafts:
+                draft_toks = jnp.concatenate(drafts, axis=1)  # [B, k]
+            else:
+                draft_toks = jnp.zeros((tokens.shape[0], 0), jnp.int32)
+            d.set(traced=self.draft_traces + self.verify_traces > traces0)
+        with span("engine.wait", step=step):
+            np_drafts = np.asarray(draft_toks)  # sync: draft chain retired
         t1 = time.perf_counter()
         # Rewind to the round start: verify re-scores (and re-writes, at
         # target precision) every drafted position.
         caches["pos"] = pos0
-        greedy, finite, caches = self._verify(
-            params, caches, jnp.concatenate([tokens, draft_toks], axis=1), fault
-        )
-        np_greedy = np.asarray(greedy)  # sync: verify step fully retired
-        np_finite = np.asarray(finite)
+        verify0 = self.verify_traces
+        with span("engine.dispatch", self.trace, kind="spec_verify", step=step,
+                  program="spec_verify", lanes=int(tokens.shape[0])) as d:
+            greedy, finite, caches = self._verify(
+                params, caches, jnp.concatenate([tokens, draft_toks], axis=1),
+                fault,
+            )
+            d.set(traced=self.verify_traces > verify0)
+        with span("engine.wait", step=step):
+            np_greedy = np.asarray(greedy)  # sync: verify step fully retired
+            np_finite = np.asarray(finite)
         t2 = time.perf_counter()
         if self.draft_traces + self.verify_traces > traces0:
             self.compile_s += t2 - t0
@@ -258,12 +268,6 @@ class SpecDecoder:
             self.draft_time_s += t1 - t0
             self.verify_time_s += t2 - t1
         self.rounds += 1
-        if self.trace is not None:
-            self.trace.emit("spec_draft", ts=t0, dur=t1 - t0,
-                            step=self.trace_step, k=k)
-            self.trace.emit("spec_verify", ts=t1, dur=t2 - t1,
-                            step=self.trace_step,
-                            lanes=int(tokens.shape[0]))
         return np_greedy, np_drafts, np_finite, caches, k
 
     def book_lane(self, n_accepted: int, n_committed: int, n_proposed: int) -> None:
@@ -292,7 +296,6 @@ class SpecDecoder:
             "spec_rounds": float(self.rounds),
             "spec_k": float(self.controller.k),
             "spec_proposed": float(self.proposed),
-            "spec_accepted": float(self.accepted),
             "spec_acceptance_rate": self.acceptance_rate(),
             "spec_tokens_per_target_step": self.tokens_per_target_step(),
             "spec_draft_time_s": self.draft_time_s,

@@ -18,8 +18,9 @@ from repro.configs import smoke_config
 from repro.models import transformer as T
 from repro.obs.drift import QuantDriftMonitor, clips_from_params
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.trace import ENGINE_TRACK, TraceRing, validate_chrome_trace
+from repro.obs.trace import ENGINE_TRACK, TraceRing, span, validate_chrome_trace
 from repro.serving import EngineConfig, Request, ServingEngine
+from repro.serving.spec_decode import SpecConfig
 
 
 # ---------------------------------------------------------------------------
@@ -170,6 +171,34 @@ def test_chrome_trace_non_int_uids():
     assert len(tids) == 2  # engine lane + the string-uid track
 
 
+def test_span_emits_its_ring_kind_with_late_args():
+    """A span with a ring kind lands in the ring once, on exit, with the
+    args given at entry and through ``set``; the request's uid is its track."""
+    tr = TraceRing()
+    with span("engine.prefill", tr, kind="prefill", step=4, uid=7,
+              tokens=9) as sp:
+        assert sp.on
+        sp.set(traced=True)
+    (e,) = tr.events()
+    assert (e.kind, e.ph, e.track, e.step) == ("prefill", "X", 7, 4)
+    assert e.args == {"tokens": 9, "traced": True} and e.dur > 0
+    with span("engine.step", tr, kind="step", step=5):
+        pass
+    assert tr.events()[-1].track == ENGINE_TRACK
+
+
+@pytest.mark.parametrize("ring,kind", [(None, "step"), ("ring", None)],
+                         ids=["no_ring", "no_kind"])
+def test_span_without_ring_or_kind_emits_nothing(ring, kind):
+    """Phases that are not ring kinds, and every span of an engine without
+    a ring, reach the profiler only."""
+    tr = TraceRing()
+    with span("engine.wait", tr if ring else None, kind=kind, step=1) as sp:
+        assert sp.on == (ring is not None and kind is not None)
+        sp.set(traced=False)
+    assert len(tr) == 0
+
+
 def test_trace_request_timeline():
     tr = TraceRing()
     tr.emit("admit", track=1, ts=1.0)
@@ -307,6 +336,80 @@ def test_engine_trace_preempt_resume(dense_setup):
     assert "preempt" in tl and "resume" in tl
     assert tl.index("preempt") < tl.index("resume")
     assert tl[-1] == "retire"
+
+
+def test_engine_without_trace_emits_into_no_ring(dense_setup, monkeypatch):
+    """``trace=False``: a full run, speculative rounds and chunked prefill
+    included, puts nothing into any ring."""
+    cfg, params = dense_setup
+    emitted = []
+    monkeypatch.setattr(TraceRing, "emit", lambda self, *a, **k: emitted.append(a))
+    eng = ServingEngine(cfg, params, EngineConfig(
+        max_batch=2, max_len=64, spec=SpecConfig(k=2), prefill_budget=16,
+        chunk_size=16))
+    for r in _reqs(cfg, [4, 20], max_new=6):
+        eng.submit(r)
+    eng.run()
+    assert eng.trace is None and eng.completed == 2
+    assert emitted == []
+
+
+@pytest.fixture(scope="module")
+def lowered_programs(dense_setup):
+    """Each serving program, lowered with the arguments of its first call:
+    a plain engine runs prefill and decode; a chunked engine on a pool small
+    enough to preempt runs chunks and the resume's replay."""
+    cfg, params = dense_setup
+    first = {}
+
+    def spy(eng, attr, name):
+        orig = getattr(eng, attr)
+
+        def make(key):
+            fn = orig(key)
+
+            def call(*a):
+                first.setdefault(name, (fn, a, {}))
+                return fn(*a)
+            return call
+        setattr(eng, attr, make)
+
+    plain = ServingEngine(cfg, params, EngineConfig(max_batch=2, max_len=64))
+    spy(plain, "_prefill_fn", "prefill")
+    decode = plain._decode
+
+    def decode_call(*a, **k):
+        first.setdefault("decode", (decode, a, k))
+        return decode(*a, **k)
+    plain._decode = decode_call
+    for r in _reqs(cfg, [4, 6]):
+        plain.submit(r)
+    plain.run()
+    chunked = ServingEngine(cfg, params, EngineConfig(
+        max_batch=2, max_len=96, page_size=8, n_pages=6,
+        admission="optimistic", admission_headroom=1, prefill_budget=8,
+        chunk_size=8))
+    spy(chunked, "_prefill_chunk_fn", "prefill_chunk")
+    spy(chunked, "_replay_fn", "replay")
+    for r in _reqs(cfg, [8, 8], max_new=30, seed=11):
+        chunked.submit(r)
+    chunked.run()
+    return {name: fn.lower(*a, **k).as_text().splitlines()[0]
+            for name, (fn, a, k) in first.items()}
+
+
+@pytest.mark.parametrize("program,module", [
+    ("decode", "jit_serving_decode_step"),
+    ("prefill", "jit_serving_prefill"),
+    ("prefill_chunk", "jit_serving_prefill_chunk"),
+    ("replay", "jit_serving_replay"),
+])
+def test_program_module_names(lowered_programs, program, module):
+    """The XLA module names a profiler trace shows: stable ``serving_*``
+    names, which the benchmark's reduction finds programs by."""
+    head = lowered_programs[program]
+    assert head.startswith(f"module @{module} "), head
+    assert "jit_impl" not in head and "_decode_impl" not in head
 
 
 def test_engine_stats_v8_from_registry(dense_setup):

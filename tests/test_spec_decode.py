@@ -335,12 +335,12 @@ def test_spec_stats_schema(dense_setup):
     )
     s = eng.stats()
     for key in (
-        "spec_enabled", "spec_rounds", "spec_k", "spec_proposed",
-        "spec_accepted", "spec_acceptance_rate", "spec_tokens_per_target_step",
+        "spec_rounds", "spec_k", "spec_proposed",
+        "spec_acceptance_rate", "spec_tokens_per_target_step",
         "spec_draft_time_s", "spec_verify_time_s", "spec_compile_s",
     ):
         assert key in s, key
-    assert s["spec_enabled"] == 1.0
+    assert s["spec_rounds"] > 0
     # 2 requests x (max_new - 1) decode-committed tokens (first from prefill)
     assert s["decoded_tokens"] == 8
     assert all(len(o) == 5 for o in done.values())
