@@ -189,6 +189,115 @@ def test_ocs_matmul_property(m, k, n, s):
     np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
+# The OCS matmul at decode (8 rows) and prefill (256 rows) shapes, with the
+# 128-wide tail of the serving recipe; tiles as planned, or 128-wide so the
+# contraction runs over several grid steps.
+OCS_SHAPES = [(8, 512, 128, 384), (256, 512, 128, 384)]
+OCS_TILES = {"planned": {}, "k-steps": {"bn": 128, "bk": 128}}
+
+
+@pytest.mark.parametrize("m,k,s,n", OCS_SHAPES)
+@pytest.mark.parametrize("mult", [(0.0, 1.0), (0.5, 1.0)])
+@pytest.mark.parametrize("tiles", list(OCS_TILES))
+def test_ocs_matmul_bf16_contraction(m, k, s, n, mult, tiles):
+    """bfloat16 activations against the float32 oracle: every product is
+    exact, so the two differ only by the order of float32 summation
+    (bounded by K+S roundings of the absolute sum), before any bf16 cast."""
+    rng = np.random.RandomState(m + n + int(10 * mult[0]))
+    x = jnp.asarray(rng.randn(m, k), jnp.bfloat16)
+    w8, src, ws = _split_setup(rng, m, k, n, s)
+    tm = jnp.asarray(rng.choice(mult, s), jnp.float32)
+    got = ocs_quant_matmul(x, w8, ws, src, tail_mult=tm, interpret=True,
+                           out_dtype=jnp.float32, **OCS_TILES[tiles])
+    want = ref.ocs_quant_matmul_ref(x, w8, ws, src, None, tm, jnp.float32)
+    mag = ref.ocs_quant_matmul_ref(jnp.abs(x), jnp.abs(w8), ws, src, None, tm,
+                                   jnp.float32)
+    err = np.abs(np.asarray(got) - np.asarray(want))
+    assert (err <= (k + s) * 2.0 ** -24 * np.asarray(mag)).all(), err.max()
+
+
+def _kernel_dots(fn, *args):
+    """(lhs, rhs, result) dtypes of every dot inside the traced kernels."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                found.append(tuple(jnp.dtype(v.aval.dtype).name
+                                   for v in (*eqn.invars, *eqn.outvars)))
+            for p in eqn.params.values():
+                inner = getattr(p, "jaxpr", p)
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("xdtype,dots", [
+    (jnp.int8, ("int8", "int8", "int32")),
+    (jnp.bfloat16, ("bfloat16", "bfloat16", "float32")),
+    (jnp.float32, ("float32", "float32", "float32")),
+])
+def test_ocs_matmul_contraction_dtype(xdtype, dots):
+    """The MXU takes the activations' dtype: W8A8 stays integer, bfloat16
+    activations meet a bfloat16 weight tile, float32 keeps float32."""
+    rng = np.random.RandomState(5)
+    w8, src, ws = _split_setup(rng, 8, 256, 128, 128)
+    x = jnp.asarray(rng.randint(-127, 128, (8, 256)), xdtype)
+    fn = lambda x: ocs_quant_matmul(x, w8, ws, src, interpret=True)
+    assert _kernel_dots(fn, x) == [dots]
+
+
+@pytest.mark.parametrize("m,k,s,n", OCS_SHAPES)
+def test_ocs_matmul_w8a8_bit_exact(m, k, s, n):
+    """The W8A8 path: exact int32 accumulation, then one float32 epilogue
+    ``acc * (x_scale * w_scale)``, bit for bit."""
+    rng = np.random.RandomState(m + k)
+    x8 = rng.randint(-127, 128, (m, k)).astype(np.int8)
+    w8, src, ws = _split_setup(rng, m, k, n, s)
+    xs = (rng.rand(m) + 0.05).astype(np.float32)
+    mask = rng.choice([0.0, 1.0], s).astype(np.float32)
+    got = ocs_quant_matmul(jnp.asarray(x8), w8, ws, src, jnp.asarray(xs),
+                           tail_mult=jnp.asarray(mask), interpret=True)
+    x_exp = np.concatenate([x8, x8[:, np.asarray(src)] * mask.astype(np.int8)], 1)
+    acc = (x_exp.astype(np.int64) @ np.asarray(w8, np.int64)).astype(np.float32)
+    want = acc * (xs[:, None] * np.asarray(ws)[None, :])
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+# glm4-9b's OCS linears (ratio 0.02, tails padded to 128) as (K, S, N, calls
+# per decode step): q and o, k and v, gate and up, down, each in 40 layers,
+# and the head.
+GLM4_LINEARS = [(4096, 128, 4096, 80), (4096, 128, 256, 80),
+                (4096, 128, 13696, 80), (13696, 384, 4096, 40),
+                (4096, 128, 151552, 1)]
+
+
+def test_ocs_matmul_decode_plan_glm4():
+    """One glm4-9b decode step (8 rows) plans a few thousand grid steps, not
+    the 552,112 of 128-wide tiles; every plan fits its VMEM budget, and its
+    tiles divide the stored weight."""
+    from repro.kernels import ocs_matmul as om
+
+    om.clear_tile_plans()
+    for k, s, n, _ in GLM4_LINEARS:
+        sds = jax.ShapeDtypeStruct
+        jax.eval_shape(
+            lambda *a: ocs_quant_matmul(*a, interpret=True),
+            sds((8, k), jnp.bfloat16), sds((k + s, n), jnp.int8),
+            sds((n,), jnp.float32), sds((s,), jnp.int32))
+    plans = om.tile_plans()
+    om.clear_tile_plans()
+    steps = sum(plans[(8, k, s, n, "bfloat16")].steps * calls
+                for k, s, n, calls in GLM4_LINEARS)
+    assert steps <= 20_000, steps
+    for (m, k, s, n, _), p in plans.items():
+        assert p.vmem_bytes <= om.PLAN_BUDGET_BYTES < om.VMEM_LIMIT_BYTES
+        assert (k + s) % p.bk == 0 and n % p.bn == 0 and p.bm == 16
+        assert p.grid == (1, n // p.bn, (k + s) // p.bk)
+
+
 # ---------------------------------------------------------------------------
 # ops dispatch
 

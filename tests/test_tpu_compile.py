@@ -87,6 +87,34 @@ def test_ocs_quant_matmul_compiles(sds):
     assert "tpu_custom_call" in _hlo(om.ocs_quant_matmul, *args)
 
 
+# glm4-9b's stored OCS weights (K, S, N): q and o, k and v, gate and up,
+# down, and the head.
+OCS_WEIGHTS = [(D, 128, D), (D, 128, KV * HD), (D, 128, FF), (FF, 384, D),
+               (D, 128, 151552)]
+
+
+@pytest.mark.parametrize("k,s,n", OCS_WEIGHTS)
+@pytest.mark.parametrize("m", [8, 1024])
+def test_ocs_quant_matmul_plan_compiles(sds, m, k, s, n):
+    """Every glm4-9b OCS matmul, at 8 decode rows and a 1024-token prefill,
+    compiles to the Mosaic kernel with its planned tiles: no reroute, and
+    tiles that divide the stored weight, so no int8 copy of it is padded or
+    concatenated."""
+    import re
+
+    budget.clear_reroutes()
+    om.clear_tile_plans()
+    args = (sds((m, k), jnp.bfloat16), sds((k + s, n), jnp.int8),
+            sds((n,), jnp.float32), sds((s,), jnp.int32))
+    hlo = _hlo(om.ocs_quant_matmul, *args, tail_mult=jnp.ones((s,), jnp.float32))
+    assert "tpu_custom_call" in hlo
+    assert budget.reroutes() == {}
+    plan = om.tile_plans()[(m, k, s, n, "bfloat16")]
+    assert (k + s) % plan.bk == 0 and n % plan.bn == 0
+    assert not re.search(r"= s8\[[\d,]+\]\S* (pad|concatenate|fusion)\(", hlo)
+    om.clear_tile_plans()
+
+
 def test_fused_w8a8_compiles(sds):
     args = (sds((8, D), jnp.bfloat16), sds((D + 128, D), jnp.int8),
             sds((D,), jnp.float32), sds((128,), jnp.int32))
