@@ -5,13 +5,15 @@ repository:
 
 1. :func:`load` reads the ``.xplane.pb`` that ``jax.profiler`` writes into
    plain lists: device operations with the program (XLA module) they ran
-   in, program runs, and the host spans the benchmark opened
-   (``jax.profiler.TraceAnnotation``), all on the profiler's clock in
-   nanoseconds.
+   in, program runs, and the host spans the benchmark and the engine opened
+   (``jax.profiler.TraceAnnotation``: ``bench_*`` and ``engine.*``), all on
+   the profiler's clock in nanoseconds.
 2. :func:`reduce` turns those lists into device seconds per program kind,
-   per kernel inside the decode programs, the device's busy time over the
-   measured window, and the breakdown of the longest operations and idle
-   gaps.
+   per kernel inside the decode programs, per ``program:kernel-or-op``
+   label (``op_s``: every label, so that a metric can read a kernel that
+   :data:`KERNELS` does not name), the device's busy time over the measured
+   window, and the breakdown of the longest operations and idle gaps, each
+   gap named by the innermost host span over it (``bench/spans.py``).
 
 Programs are told apart by their names: the engine's named scopes
 (``serving_decode_step``, ``serving_prefill``) where the trace keeps them,
@@ -31,12 +33,15 @@ import os
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
+from bench import spans as S
+
 # What marks each program kind and kernel, searched in lower case.
 PROGRAMS = {"decode": ("serving_decode_step", "jit__decode_impl("),
             "prefill": ("serving_prefill", "jit_impl(")}
 KERNELS = {"ocs_matmul": ("ocs_matmul", "ocs_quant_matmul"),
            "paged_attn": ("paged_attn", "paged_attention")}
 WINDOW_SPAN = "bench_window"  # the measured window; each step is a "bench_step"
+HOST_SPANS = ("bench_", "engine.")  # the host spans kept: the benchmark's, the engine's
 
 
 def _text(stats) -> str:
@@ -83,7 +88,7 @@ def load(path: str) -> Dict:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith("bench_"):
+                    if ev.name.startswith(HOST_SPANS):
                         host.append([ev.name, ev.start_ns, ev.duration_ns])
     return {"ops": ops, "modules": modules, "host": sorted(host, key=lambda h: h[1]),
             "devices": max(devices, 1)}
@@ -118,9 +123,10 @@ def window(trace: Dict) -> Tuple[int, int]:
 
 
 def reduce(trace: Dict, top: int = 10) -> Dict:
-    """Device seconds by program kind and kernel over the measured window,
-    busy seconds (each device's, averaged over the devices), and the
-    breakdown. An op without a device index ran on device 0."""
+    """Device seconds by program kind, by kernel in the decode programs and
+    by ``program:kernel-or-op`` label over the measured window, busy seconds
+    (each device's, averaged over the devices), and the breakdown. An op
+    without a device index ran on device 0."""
     w0, w1 = window(trace)
     clip = lambda s, d: (max(s, w0), min(s + d, w1))
     mod_kind = []
@@ -153,37 +159,19 @@ def reduce(trace: Dict, top: int = 10) -> Dict:
         label = f"{prog or 'other'}:{kern or name.split('.')[0]}"
         by_label[label] += (b - a) / 1e9
     busy_ns = sum(_union(iv) for iv in per_device.values())
-    gaps = _gaps(busy, w0, w1, trace["host"])
+    gaps = S.gaps({"spans": [[n, s, d, {}] for n, s, d in trace["host"]],
+                   "busy": [busy], "window": [w0, w1]}, top)
     return {
         "window_s": (w1 - w0) / 1e9,
         "busy_s": busy_ns / 1e9 / trace["devices"],
         "program_s": dict(program_s),
         "program_runs": dict(program_runs),
         "kernel_s": dict(kernel_s),
+        "op_s": dict(by_label),
         "device_ops": sorted(([k, v] for k, v in by_label.items()),
                              key=lambda kv: -kv[1])[:top],
-        "idle_gaps": gaps[:top],
+        "idle_gaps": gaps,
     }
-
-
-def _gaps(busy, w0, w1, host) -> List[List]:
-    """The longest stretches with no device operation, each named by the
-    host span (step or client work) that covered its midpoint."""
-    out, end = [], w0
-    for s, e in sorted(busy):
-        if s > end:
-            out.append((end, s))
-        end = max(end, e)
-    if w1 > end:
-        out.append((end, w1))
-    spans = [h for h in host if h[0] != WINDOW_SPAN]
-    named = []
-    for s, e in sorted(out, key=lambda g: g[0] - g[1])[:50]:
-        mid = (s + e) // 2
-        label = next((h[0] for h in spans if h[1] <= mid < h[1] + h[2]),
-                     "outside any bench span")
-        named.append([label, (e - s) / 1e9])
-    return sorted(named, key=lambda g: -g[1])
 
 
 def save(trace: Dict, path: str) -> None:

@@ -22,8 +22,10 @@ read by ``bench/loadgen.py``). One run:
    ``--trace 1`` the window's first ``TRACE_SECONDS`` run under the
    profiler, each ``step`` in a ``bench_step`` span;
 4. **checks** a sample, drawn from the seed, of the requests the window
-   finished (the longest among them) against the float32 reference of
-   ``bench/reference/``, after the engines and their weights are freed.
+   finished (the longest among them) against the float32 reference of the
+   configuration's block, ``bench/reference/<block>.py`` (looked up before
+   anything loads: a block without one ends the run at once), after the
+   engines and their weights are freed.
    With ``--control 1`` the int4 control's tokens stand in the program's
    place, and the same limits must fail them;
 5. prints, as the last line of standard output, one JSON object: the
@@ -82,6 +84,38 @@ def cell_metrics(spec: Dict, cell: str, kind: str) -> List[Dict]:
     """The ``end_to_end`` or ``per_layer`` metrics this cell reports."""
     return [m for m in spec[kind]
             if "workloads" not in m or cell in m["workloads"]]
+
+
+def model_config(d: Dict):
+    """The configuration file's ``model`` mapping as a ``ModelConfig``: its
+    ``moe``, ``ssm`` and ``hymba`` mappings as their sections, JSON lists as
+    tuples."""
+    from repro.configs.base import HymbaConfig, ModelConfig, MoEConfig, SSMConfig
+
+    def tup(v):
+        return tuple(tup(x) for x in v) if isinstance(v, list) else v
+
+    kw = {k: tup(v) for k, v in d.items()}
+    for key, section in (("moe", MoEConfig), ("ssm", SSMConfig),
+                         ("hymba", HymbaConfig)):
+        if kw.get(key) is not None:
+            kw[key] = section(**{k: tup(v) for k, v in kw[key].items()})
+    return ModelConfig(**kw)
+
+
+def reference(block: str):
+    """``bench/reference/<block>.py``, the plain reference a configuration of
+    this block is checked against; without one the run ends here."""
+    name = f"bench.reference.{block}"
+    try:
+        mod = importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        mod = None
+    if not hasattr(mod, "served_gaps"):
+        _fail(f"no reference for block {block!r}")
+    return mod
 
 
 def engine_config(d: Dict):
@@ -184,22 +218,22 @@ def run_cell(cell: Dict, config: Dict, mix: Dict, spec: Dict, seed: int,
 
 def _run(cell, config, mix, spec, seed, seconds, trace, devices, control,
          t_process, log, compiles) -> Dict:
+    served_gaps = reference(config["model"]["block"]).served_gaps
+
     import jax
     import numpy as np
 
-    from repro.configs.base import ModelConfig
     from repro.core.recipe import QuantRecipe
     from repro.kernels.budget import reroutes
     from repro.launch.weights import load_quantized
     from repro.serving import EngineOverloaded, Request
 
     from bench.loadgen import Traffic
-    from bench.reference.model import served_gaps
 
     dev = devices[0]
     rr0 = reroutes()  # the process may have rerouted kernels before this run
 
-    cfg = ModelConfig(**config["model"])
+    cfg = model_config(config["model"])
     recipe = QuantRecipe(**config["recipe"])
     t0 = time.perf_counter()
     params = jax.block_until_ready(load_quantized(cfg, seed, recipe, device=dev))
@@ -532,11 +566,12 @@ def main(argv=None) -> None:
     if not (ROOT / "src" / "repro").is_dir():
         _fail("no program (src/repro) in this checkout")
     config = _json(BENCH / "configs" / f"{cell['config']}.json")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
+    reference(config["model"]["block"])
     from bench.loadgen import load_mix
 
     mix = load_mix(BENCH / "traffic" / f"{cell['traffic']}.json")
 
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
     import jax
 
     devs = jax.devices()

@@ -152,3 +152,51 @@ def test_benchmark_alone_no_result(tmp_path):
          "--seed", "1", "--seconds", "1", "--trace", "0"],
         cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300)
     assert p.returncode != 0 and p.stdout.strip() == ""
+
+
+# A block kind no reference module will ever be named after.
+NO_REFERENCE = "no-such-block"
+
+
+def test_a_block_without_a_reference_ends_before_the_load(monkeypatch, capsys):
+    """A configuration whose block has no ``bench/reference/<block>.py``
+    exits 1 with the reason, and loads no weight."""
+    import repro.launch.weights as W
+
+    def load(*a, **kw):
+        raise AssertionError("weights loaded")
+
+    monkeypatch.setattr(W, "load_quantized", load)
+    config = dict(SMOKE, model=dict(SMOKE["model"], block=NO_REFERENCE))
+    with pytest.raises(SystemExit) as e:
+        _run("chat", config=config)
+    assert e.value.code == 1
+    assert f"bench: no reference for block '{NO_REFERENCE}'" in capsys.readouterr().err
+
+
+def test_a_block_without_a_reference_exits_1(tmp_path):
+    """From the command line, before the look for a chip: exit 1, the
+    reason on standard error, nothing on standard output."""
+    import os
+    import shutil
+    import subprocess
+    import sys
+
+    spec = dict(SPEC, configs=SPEC["configs"] + [dict(
+        SPEC["configs"][0], name="noref", file="bench/configs/noref.json")],
+        workloads=SPEC["workloads"] + [dict(
+            SPEC["workloads"][0], name="noref.chat", config="noref")])
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    shutil.copytree(HERE, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "src").symlink_to(HERE.parent / "src")
+    config = dict(SMOKE, model=dict(SMOKE["model"], block=NO_REFERENCE))
+    (tmp_path / "bench" / "configs" / "noref.json").write_text(json.dumps(config))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", "noref.chat",
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode == 1 and p.stdout.strip() == ""
+    assert f"bench: no reference for block '{NO_REFERENCE}'" in p.stderr
+    assert "no TPU" not in p.stderr

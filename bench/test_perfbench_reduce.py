@@ -77,3 +77,34 @@ def test_recorded_trace():
                                            "paged_attn": 0.052364094})
     assert r["device_ops"][0][0] == "decode:ocs_matmul"
     assert all(label == "bench_step" for label, _ in r["idle_gaps"][:3])
+
+
+def test_op_s_holds_every_label():
+    r = R.reduce(_hand_trace())
+    assert r["op_s"] == pytest.approx({
+        "decode:ocs_matmul": 40e-9, "decode:paged_attn": 20e-9,
+        "decode:fusion": 40e-9, "prefill:fusion": 100e-9})
+
+
+def test_recorded_trace_op_s():
+    """Every label of the recorded trace, with the seconds ``device_ops``
+    gives its ten longest; a named kernel's label reads its ``kernel_s``."""
+    r = R.reduce(R.read(str(RECORDED)))
+    assert len(r["op_s"]) > len(r["device_ops"]) == 10
+    for label, sec in r["device_ops"]:
+        assert r["op_s"][label] == sec
+    for kern, sec in r["kernel_s"].items():
+        assert r["op_s"]["decode:" + kern] == pytest.approx(sec)
+    assert sum(r["op_s"].values()) >= r["busy_s"]
+
+
+def test_gaps_are_named_by_the_innermost_host_span():
+    """An engine span inside the benchmark's step names the gap under it:
+    idle 250-300 lies in step 140-300, engine.step 140-290 and its
+    engine.book 260-285."""
+    t = _hand_trace()
+    t["host"] += [["engine.step", 140, 150], ["engine.book", 260, 25]]
+    t["host"].sort(key=lambda h: h[1])
+    assert R.reduce(t)["idle_gaps"] == [
+        ["outside any bench span", pytest.approx(50e-9)],
+        ["engine.book", pytest.approx(50e-9)]]

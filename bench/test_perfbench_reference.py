@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench.reference import model as RM
+from bench.reference import dense as RM
 from bench.reference import weights as RW
 
 SMOKE = json.loads((Path(__file__).parent / "testdata" / "smoke.json").read_text())
@@ -36,6 +36,35 @@ def test_weights_are_the_loaders_float_weights(seed):
                 RW.layer_matrix(MODEL, seed, layer, name))
     for leaf in ("final_norm",):
         assert np.all(np.asarray(p[leaf]["scale"]) == 1.0)
+
+
+@pytest.mark.parametrize("arch", ["glm4-9b", "deepseek-moe-16b"])
+def test_draw_is_the_loaders_rule_for_every_block(arch):
+    """Given its block's sorted leaf list, ``weights.draw`` gives each slice
+    the loader draws: a layer of a per-layer matrix, an expert of a layer
+    (row-major over the stack dims), or the whole leaf."""
+    from repro.configs import smoke_config
+    from repro.core.apply import path_str
+    from repro.launch.weights import init_params_host
+
+    cfg = smoke_config(arch)
+    seed = 2**31 + 11
+    flat = jax.tree_util.tree_flatten_with_path(init_params_host(cfg, seed))[0]
+    leaves = [path_str(p) for p, _ in flat]
+    assert leaves == sorted(leaves)
+    drawn = 0
+    for path, a in flat:
+        a = np.asarray(a)
+        name = path_str(path)
+        if "norm" in name or "scale" in name:
+            continue
+        std = 0.02 if name == "embed" else 1.0 / np.sqrt(a.shape[-2])
+        lead = a.shape[:-2]
+        for i, idx in enumerate(np.ndindex(*lead)):
+            np.testing.assert_array_equal(
+                a[idx], RW.draw(seed, leaves, name, i, a.shape[-2:], std))
+            drawn += 1
+    assert drawn > (20 if cfg.moe else 10)
 
 
 def _float_forward(seed, tokens):

@@ -116,6 +116,25 @@ def test_no_span_per_token(smoke, tmp_path):
     assert counts[0] == counts[1] == [6, 6, 6]
 
 
+def test_reduce_keeps_the_engine_spans(smoke, tmp_path):
+    """``bench/reduce.py`` reads the engine's spans beside the benchmark's
+    from a profiled window, and names its gaps as ``bench/spans.py`` does."""
+    eng = _engine(smoke)
+
+    def steps():
+        with jax.profiler.TraceAnnotation("bench_window"):
+            for _ in range(3):
+                with jax.profiler.TraceAnnotation("bench_step"):
+                    eng.step()
+
+    spans = _profiled(tmp_path, steps)
+    t = R.load(str(tmp_path))
+    names = {h[0] for h in t["host"]}
+    assert {"bench_window", "bench_step", "engine.step", "engine.dispatch",
+            "engine.wait"} <= names
+    assert R.reduce(t)["idle_gaps"] == S.gaps(spans)
+
+
 def _hand_trace():
     """Two decode steps in a 100 ms window (ms):
 
