@@ -13,7 +13,18 @@ class MoEConfig:
     top_k: int
     expert_ff: int
     n_shared: int = 0  # shared (always-on) experts, DeepSeek-MoE style
+    # Experts held here, ids [0, n_held): one chip's share of expert
+    # parallelism; the router still scores all n_experts. 0 holds them all.
+    n_held: int = 0
+    first_dense: int = 0  # leading layers with a dense SwiGLU of d_ff instead
+    norm_topk: bool = True  # renormalise the top-k gates to sum to 1
+    # Per-expert capacity of the shard_map training dispatch (the serving
+    # layer is dropless).
     capacity_factor: float = 1.25
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,13 +94,15 @@ class ModelConfig:
             mlp = 3 * d * f
         else:
             mlp = 2 * d * f
-        per_layer = 0
+        per_layer = dense = 0
         if self.block in ("dense",):
             per_layer = attn + mlp
         elif self.block == "moe":
             m = self.moe
             e_mlp = 3 * d * m.expert_ff
             per_layer = attn + (m.n_experts + m.n_shared) * e_mlp + d * m.n_experts
+            # Leading dense layers hold an MLP of d_ff in place of the experts.
+            dense = m.first_dense * (mlp - (per_layer - attn))
         elif self.block == "mamba2":
             di, s = self.d_inner, self.ssm
             conv_dim = di + 2 * s.n_groups * s.d_state
@@ -108,7 +121,7 @@ class ModelConfig:
             )
             per_layer = attn + ssm_p + mlp
         emb = self.vocab * d * (1 if self.tie_embeddings else 2)
-        return self.n_layers * per_layer + emb
+        return self.n_layers * per_layer + dense + emb
 
     def active_param_count(self) -> int:
         """Active params per token (= param_count for dense; MoE counts top-k)."""
@@ -123,7 +136,8 @@ class ModelConfig:
         e_mlp = 3 * d * m.expert_ff
         per_layer = attn + (m.top_k + m.n_shared) * e_mlp + d * m.n_experts
         emb = self.vocab * d * (1 if self.tie_embeddings else 2)
-        return self.n_layers * per_layer + emb
+        dense = m.first_dense * (3 * d * self.d_ff - (per_layer - attn))
+        return self.n_layers * per_layer + dense + emb
 
 
 @dataclasses.dataclass(frozen=True)

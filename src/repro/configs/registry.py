@@ -4,7 +4,7 @@ from __future__ import annotations
 import dataclasses
 from typing import List
 
-from .base import HymbaConfig, ModelConfig, MoEConfig, SSMConfig
+from .base import HymbaConfig, ModelConfig, SSMConfig
 
 from . import (
     deepseek_7b,
@@ -64,7 +64,8 @@ def smoke_config(name: str) -> ModelConfig:
         remat=False,
     )
     if cfg.moe is not None:
-        kw["moe"] = MoEConfig(
+        kw["moe"] = dataclasses.replace(
+            cfg.moe,
             n_experts=8,
             top_k=min(cfg.moe.top_k, 3),
             expert_ff=32,

@@ -19,6 +19,7 @@ from . import paged_attention as _pa
 from .dynamic_quant import dynamic_quant as _dynamic_quant_pallas
 from .fused_qmatmul import fused_quant_matmul as _fused_qmatmul_pallas
 from .fused_qmatmul import w4a8_quant_matmul as _w4a8_qmatmul_pallas
+from .moe_gmm import moe_gmm as _moe_gmm_pallas
 from .ocs_matmul import ocs_quant_matmul as _ocs_matmul_pallas
 from .quant_matmul import quant_matmul as _quant_matmul_pallas
 
@@ -29,6 +30,7 @@ __all__ = [
     "fused_quant_matmul",
     "w4a8_matmul",
     "paged_attention",
+    "moe_gmm",
     "backend_mode",
 ]
 
@@ -174,3 +176,17 @@ def ocs_quant_matmul(
         tail_is_mask=tail_is_mask, out_dtype=out_dtype,
         interpret=(mode == "interpret"),
     )
+
+
+@functools.partial(jax.jit, static_argnames=("force",))
+def moe_gmm(x, w8, w_scale, src_tail, mult_tail, group, n_used, *,
+            force: Optional[str] = None):
+    """Grouped OCS matmul over held experts (moe_gmm.py): row tile ``t`` of
+    ``x`` times expert ``group[t]``'s expanded int8 weight; tiles from
+    ``n_used`` on are padding."""
+    mode = backend_mode(force)
+    if mode == "ref":
+        return ref.moe_gmm_ref(x, w8, w_scale, src_tail, mult_tail, group,
+                               n_used)
+    return _moe_gmm_pallas(x, w8, w_scale, src_tail, mult_tail, group, n_used,
+                           interpret=(mode == "interpret"))

@@ -12,14 +12,21 @@ replaces that with one flash-decode-style dispatch that consumes the pool
 * **fused append** — the current Q tokens' K/V rows are quantized (int8
   pools) and DMA'd into their pages inside the kernel, so decode is one
   dispatch instead of scatter + gather + attention;
-* **block-table page loads** — each grid program ``(lane b, kv head g)``
-  walks its lane's block-table row and DMAs one ``[page_size, hd]`` page
-  tile at a time into VMEM; nothing per-lane is ever materialized in HBM;
-* **in-VMEM dequant** — int8 page rows are dequantized with their per-token
-  scales right after the load (``x * scale``, the paper's linear grid);
+* **block-table page loads** — each grid program (one lane) walks its
+  lane's block-table row and DMAs each page for all its KV heads at once
+  (one ``[KV, page_size, hd]`` tile of K, one of V, one scale row each),
+  the next page in flight while this one computes, so 16 heads cost a
+  lane one DMA per tile and page, not 16; nothing per-lane is ever
+  materialized in HBM;
+* **in-VMEM dequant** — int8 pages are converted in VMEM and their
+  per-token scales (the paper's linear grid) act on the scores and on the
+  softmax weights, lane by lane; packed int4 tiles are dequantized row by
+  row right after the load;
 * **online softmax** — the flash recurrence accumulates across pages, so
   the loop stops after ``(pos + Q - 1) // page_size + 1`` pages: work scales
-  with the tokens attended, not the table extent;
+  with the tokens attended, not the table extent. All heads
+  attend a page in one pair of matmuls, each query row masked to its own
+  head's keys;
 * **position masking** — per-lane causal masks (query ``j`` sees positions
   ``<= pos + j``) *and* an explicit trash-page mask: page-0 loads are
   select-zeroed before the dots, so a poisoned (even NaN) trash page can
@@ -497,10 +504,10 @@ def _paged_attn_kernel(
     table_ref,  # [B, T] int32
     pos_ref,  # [B] int32
     # inputs
-    q_ref,  # [1, 1, QR, hd] f32 block for (b, g)
-    kn_ref,  # [1, 1, Q, hd] f32 block
+    q_ref,  # [1, KV*QR, hd] f32 block for lane b: head-major rows
+    kn_ref,  # [1, KV, Q, hd] f32 block
     vn_ref,
-    k_in,  # [P, KV, ps, hd] ANY (aliased; unused — reads go through k_out)
+    k_in,  # [P, KV, rows, hd] ANY (aliased; unused — reads go through k_out)
     v_in,
     *rest,  # (ks_in, vs_in,) out refs, (scale out refs,) scratch, sems
     ps: int,
@@ -516,79 +523,67 @@ def _paged_attn_kernel(
     else:
         (out_ref, k_out, v_out, k_scr, v_scr, sems) = rest
     b = pl.program_id(0)
-    g = pl.program_id(1)
     pos_b = pos_ref[b]
     qr = qn * rep
     half = ps // 2
+    kvh, hd = kn_ref.shape[1], q_ref.shape[-1]
+    n_rows, n_keys = kvh * qr, kvh * ps
 
-    def copy_pages(pid, to_vmem: bool):
-        # Page tiles of (page pid, head g), plus the page's scale row. Reads
-        # go through the *output* refs (the aliased buffers), so every
-        # append is visible to the loads after it.
-        pairs = [(k_out.at[pid, g], k_scr), (v_out.at[pid, g], v_scr)]
+    def copies(pid, slot, to_vmem: bool):
+        # One page for all the lane's heads: a [KV, rows, hd] tile of K and
+        # of V and the lane-dense scale rows, one DMA each. Reads go
+        # through the *output* refs (the aliased buffers), so every append
+        # is visible to the loads after it.
+        pairs = [(k_out.at[pid], k_scr.at[slot]),
+                 (v_out.at[pid], v_scr.at[slot])]
         if scaled:
-            pairs += [(ks_out.at[pl.ds(pid, 1)], ks_scr),
-                      (vs_out.at[pl.ds(pid, 1)], vs_scr)]
-        dmas = [
-            pltpu.make_async_copy(*((h, v) if to_vmem else (v, h)), sems.at[i])
+            pairs += [(ks_out.at[pid], ks_scr.at[slot]),
+                      (vs_out.at[pid], vs_scr.at[slot])]
+        return [
+            pltpu.make_async_copy(*((h, v) if to_vmem else (v, h)),
+                                  sems.at[slot, i])
             for i, (h, v) in enumerate(pairs)
         ]
+
+    def run(dmas):
         for d in dmas:
             d.start()
         for d in dmas:
             d.wait()
 
-    def scale_col(s_scr):
-        # This head's per-token scales as a [ps, 1] column, picked out of the
-        # page's lane-dense scale row by a select + lane sum (exact: one
-        # nonzero term per row; scales are positive and finite on real pages).
-        lanes = s_scr.shape[-1]
-        pick = (jax.lax.broadcasted_iota(jnp.int32, (ps, lanes), 1)
-                == g * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, lanes), 0))
-        return jnp.sum(jnp.where(pick, s_scr[...], 0.0), axis=1, keepdims=True)
-
-    def page_values(scr):
-        # Page tile -> [ps, hd] f32. Packed int4 tiles hold token r in the
-        # low nibble and token r + ps/2 in the high nibble of row r; nibbles
-        # are sign-extended by 32-bit shifts (see unpack_nibbles).
-        if kind == "int4":
-            lo, hi = unpack_nibbles(scr[...])
-            return jnp.concatenate([lo, hi], axis=0).astype(jnp.float32)
-        return scr[...].astype(jnp.float32)
-
-    # ---- fused append: this program owns (lane b, head g)'s Q rows. Pages
-    # past the prompt are never shared across lanes, so the only rows this
-    # program ever reads back below are its own writes (waited on here).
-    # Each row is a read-modify-write of the whole page tile, in a 32-bit
+    # ---- fused append: this program owns lane b's Q rows.
+    # Pages past the prompt are never shared across lanes, so the only rows
+    # this program ever reads back below are its own writes (waited on
+    # here). Each row is a read-modify-write of the page tile, in a 32-bit
     # type: Mosaic can neither DMA one row of an 8-bit page (it is not
     # aligned to the tiling) nor select on 8-bit vectors.
     for j in range(qn):
         lin = jnp.minimum(jnp.maximum(pos_b + j, 0), t * ps - 1)
         pid = table_ref[b, lin // ps]
         slot = lin % ps
-        kr = kn_ref[0, 0, j : j + 1, :].astype(jnp.float32)  # [1, hd]
-        vr = vn_ref[0, 0, j : j + 1, :].astype(jnp.float32)
-        copy_pages(pid, to_vmem=True)
-        if not scaled:
-            hit = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) == slot
-            for row, scr in ((kr, k_scr), (vr, v_scr)):
-                scr[...] = jnp.where(
-                    hit, row, scr[...].astype(jnp.float32)
-                ).astype(scr.dtype)
-        else:
+        run(copies(pid, 0, to_vmem=True))
+        for h in range(kvh):
+            kr = kn_ref[0, h, j : j + 1, :].astype(jnp.float32)  # [1, hd]
+            vr = vn_ref[0, h, j : j + 1, :].astype(jnp.float32)
+            if not scaled:
+                hit = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) == slot
+                for row, scr in ((kr, k_scr), (vr, v_scr)):
+                    scr[0, h] = jnp.where(
+                        hit, row, scr[0, h].astype(jnp.float32)
+                    ).astype(scr.dtype)
+                continue
             # quant_rows, inlined: same grid as every other pool writer
             # (qmax 127 for int8 pages, KV4_QMAX for packed int4 pages).
             qm = 127.0 if kind == "int8" else KV4_QMAX
-            lanes = ks_scr.shape[-1]
-            s_hit = (jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
-                     == g * ps + slot)
+            s_hit = (jax.lax.broadcasted_iota(jnp.int32, ks_scr.shape[1:], 1)
+                     == h * ps + slot)
             for row, scr, s_scr in ((kr, k_scr, ks_scr), (vr, v_scr, vs_scr)):
                 amax = jnp.max(jnp.abs(row), axis=-1, keepdims=True)
                 sc = jnp.maximum(amax, 1e-30) * (1.0 / qm)
                 qrow = jnp.clip(
                     jnp.floor(row * (1.0 / sc) + 0.5), -qm, qm
                 ).astype(jnp.int32)
-                cur = scr[...].astype(jnp.int32)
+                cur = scr[0, h].astype(jnp.int32)
                 if kind == "int4":
                     hi = slot >= half
                     nib = qrow & 0xF
@@ -599,76 +594,137 @@ def _paged_attn_kernel(
                 else:
                     new = qrow
                     hit = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) == slot
-                scr[...] = jnp.where(hit, new, cur).astype(scr.dtype)
-                s_scr[...] = jnp.where(s_hit, sc, s_scr[...])
-        copy_pages(pid, to_vmem=False)
+                scr[0, h] = jnp.where(hit, new, cur).astype(scr.dtype)
+                s_scr[0] = jnp.where(s_hit, sc, s_scr[0])
+        run(copies(pid, 0, to_vmem=False))
 
-    # ---- flash loop over this lane's active pages only.
-    qv = q_ref[0, 0]  # [QR, hd] f32, pre-scaled
-    bound = pos_b + jax.lax.broadcasted_iota(jnp.int32, (qr, 1), 0) // rep
-    n_active = jnp.minimum(t, (pos_b + qn - 1) // ps + 1)
+    # ---- flash loop over this lane's active pages only, the next page's
+    # DMAs in flight while this one computes (two scratch slots).
+    n_active = jnp.minimum(t, (pos_b + qn - 1) // ps + 1)  # >= 1
+    for d in copies(table_ref[b, 0], 0, to_vmem=True):
+        d.start()
 
-    def load_tiles(ti):
-        pid = table_ref[b, ti]
-        copy_pages(pid, to_vmem=True)
-        readable = pid != TRASH_PAGE
-        kf = page_values(k_scr)
-        vf = page_values(v_scr)
-        if scaled:  # in-VMEM dequant with the per-token scales ([ps, 1])
-            kf = kf * scale_col(ks_scr)
-            vf = vf * scale_col(vs_scr)
-        kf = jnp.where(readable, kf, 0.0)  # select: NaN poison dies here
-        vf = jnp.where(readable, vf, 0.0)
-        gpos = ti * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        return kf, vf, (gpos <= bound) & readable
+    def page(ti):
+        """Wait for page ``ti``, start page ``ti + 1``; its slot and
+        whether it is readable."""
+        slot = ti % 2
+
+        @pl.when(ti + 1 < n_active)
+        def _prefetch():
+            for d in copies(table_ref[b, ti + 1], 1 - slot, to_vmem=True):
+                d.start()
+
+        for d in copies(table_ref[b, ti], slot, to_vmem=True):
+            d.wait()
+        return slot, table_ref[b, ti] != TRASH_PAGE
 
     if kind == "int4":
-        # int4 tier: the shared _int4_flash_step recurrence on 2-D per-(b, g)
-        # slices — the same op sequence the XLA fallback and the gather
-        # oracle run batched, so the three paths agree bitwise (the tier's
-        # exactness contract).
-        def body(ti, carry):
-            kf, vf, vis = load_tiles(ti)
-            return _int4_flash_step(qv, kf, vf, vis, carry)
+        # int4 tier: the shared _int4_flash_step recurrence on 2-D per-head
+        # slices against the dequantized tiles — the same op sequence the
+        # XLA fallback and the gather oracle run batched, so the three paths
+        # agree bitwise (the tier's exactness contract).
+        bound = pos_b + jax.lax.broadcasted_iota(jnp.int32, (qr, 1), 0) // rep
+        lanes = ks_scr.shape[-1]
+        pick = lambda h: (jax.lax.broadcasted_iota(jnp.int32, (ps, lanes), 1)
+                          == h * ps
+                          + jax.lax.broadcasted_iota(jnp.int32, (ps, lanes), 0))
 
-        m0 = jnp.full((qr,), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((qr,), jnp.float32)
-        acc0 = jnp.zeros((qr, q_ref.shape[-1]), jnp.float32)
-        m, l, acc = jax.lax.fori_loop(0, n_active, body, (m0, l0, acc0))
-        out_ref[0, 0] = _int4_finish(m, l, acc)
+        def tile(scr, s_scr, slot, h):
+            # Head h's page tile -> [ps, hd] f32: token r in the low nibble
+            # and token r + ps/2 in the high nibble of row r, sign-extended
+            # by 32-bit shifts (see unpack_nibbles); its per-token scales
+            # picked out of the lane-dense row as a [ps, 1] column by a
+            # select + lane sum (exact: one nonzero term per row).
+            lo, hi = unpack_nibbles(scr[slot, h])
+            col = jnp.sum(jnp.where(pick(h), s_scr[slot], 0.0), axis=1,
+                          keepdims=True)
+            return jnp.concatenate([lo, hi], axis=0).astype(jnp.float32) * col
+
+        def body(ti, carry):
+            slot, readable = page(ti)
+            gpos = ti * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+            vis = (gpos <= bound) & readable
+            out = []
+            for h in range(kvh):
+                kf = jnp.where(readable, tile(k_scr, ks_scr, slot, h), 0.0)
+                vf = jnp.where(readable, tile(v_scr, vs_scr, slot, h), 0.0)
+                q = q_ref[0, h * qr:(h + 1) * qr, :]
+                out.append(_int4_flash_step(q, kf, vf, vis, carry[h]))
+            return tuple(out)
+
+        init = tuple((jnp.full((qr,), NEG_INF, jnp.float32),
+                      jnp.zeros((qr,), jnp.float32),
+                      jnp.zeros((qr, hd), jnp.float32)) for _ in range(kvh))
+        res = jax.lax.fori_loop(0, n_active, body, init)
+        out_ref[0] = jnp.concatenate([_int4_finish(*c) for c in res], axis=0)
         return
+
+    # Every head in one pair of matmuls: query rows (head-major) against
+    # the page's keys of all the lane's heads, each row then masked
+    # to its own head's tokens. Row r is head r // QR, query (r % QR) // rep;
+    # key column c is head c // ps, token c % ps of the page.
+    row = jax.lax.broadcasted_iota(jnp.int32, (n_rows, n_keys), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n_rows, n_keys), 1)
+    own = row // qr == col // ps
+    bound = pos_b + (row % qr) // rep
+    qv = q_ref[0]  # [KV*QR, hd] f32, pre-scaled
 
     def body(ti, carry):
         m, l, acc = carry
-        kf, vf, vis = load_tiles(ti)
-        s = jax.lax.dot_general(  # [QR, ps]
+        slot, readable = page(ti)
+        kf = k_scr[slot].astype(jnp.float32).reshape(n_keys, hd)
+        vf = v_scr[slot].astype(jnp.float32).reshape(n_keys, hd)
+        if not scaled:  # select: NaN poison dies here
+            kf = jnp.where(readable, kf, 0.0)
+            vf = jnp.where(readable, vf, 0.0)
+        s = jax.lax.dot_general(  # [KV*QR, KV*ps]
             qv, kf, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if scaled:
+            # int8 pages: the per-token scales act on the scores and on the
+            # softmax weights, lane by lane; a trash page's are zeroed.
+            s = s * jnp.where(readable, ks_scr[slot][:, :n_keys], 0.0)
+        vis = own & (ti * ps + col % ps <= bound) & readable
         s = s + jnp.where(vis, 0.0, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if scaled:
+            p = p * jnp.where(readable, vs_scr[slot][:, :n_keys], 0.0)
         acc_new = acc * alpha + jax.lax.dot_general(
             p, vf, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((qr, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((qr, 1), jnp.float32)
-    acc0 = jnp.zeros((qr, q_ref.shape[-1]), jnp.float32)
+    m0 = jnp.full((n_rows, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((n_rows, 1), jnp.float32)
+    acc0 = jnp.zeros((n_rows, hd), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, n_active, body, (m0, l0, acc0))
-    out_ref[0, 0] = acc / jnp.maximum(l, 1e-30)
+    out_ref[0] = acc / jnp.maximum(l, 1e-30)
 
 
 def _scale_rows(scale: jnp.ndarray) -> jnp.ndarray:
-    """[P, KV, ps] page scales -> [P, L] lane-dense rows (L = KV*ps padded
-    to 128 lanes), so one page's scales move as one aligned row DMA."""
+    """[P, KV, ps] page scales -> [P, 1, L] lane-dense rows (L = KV*ps padded
+    to 128 lanes), so one page's scales move as one DMA of a leading-dim
+    slice, aligned in any memory space."""
     p, kvh, ps = scale.shape
-    rows = scale.reshape(p, kvh * ps)
-    return jnp.pad(rows, ((0, 0), (0, (-kvh * ps) % 128)))
+    rows = scale.reshape(p, 1, kvh * ps)
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, (-kvh * ps) % 128)))
+
+
+def _vmem_bytes(kvh: int, rows: int, hd: int, itemsize: int, ps: int,
+                qr: int, qn: int, scaled: bool) -> int:
+    """Resident VMEM of one program: two page slots of K and V tiles and
+    scale rows (8 sublanes, 128-lane padded), their float32 forms and the
+    scores, and the double-buffered q, out and new-row blocks."""
+    lanes = -(-kvh * ps // 128) * 128
+    page = 2 * kvh * rows * hd * itemsize + (2 * 8 * lanes * 4 if scaled else 0)
+    r8 = lambda r: -(-r // 8) * 8
+    work = 2 * kvh * ps * hd * 4 + r8(kvh * qr) * lanes * 4 * 2
+    return 2 * page + work + 2 * (2 * r8(kvh * qr) * hd * 4 + 2 * kvh * r8(qn) * hd * 4)
 
 
 def paged_attention_kernel(
@@ -677,7 +733,11 @@ def paged_attention_kernel(
     """Raw pallas_call. q: [B, Q, H, hd] float (post-RoPE, unscaled);
     k_new/v_new: [B, Q, KV, hd]; table: [B, T] int32; pos: [B] int32.
     Returns (out [B, Q, H, hd] f32, new pool — appended in place via
-    input/output aliasing)."""
+    input/output aliasing).
+
+    One program a lane: it walks the lane's pages, loads each for all KV
+    heads at once, the next page in flight while this one computes, and
+    attends all heads in one pair of matmuls a page."""
     b, qn, h, hd = q.shape
     p_pages, kvh, tile_rows, _ = pool["k"].shape  # ps/2 rows when int4
     ps = page_size(pool)
@@ -687,27 +747,28 @@ def paged_attention_kernel(
     kind = pool_kind(pool)
     scaled = kind in ("int8", "int4")
 
-    q2 = _q_rows(q, kvh)  # [B, KV, QR, hd] f32 pre-scaled
+    # [B, KV*QR, hd] f32 pre-scaled, rows head-major
+    q2 = _q_rows(q, kvh).reshape(b, kvh * qr, hd)
     kn2 = jnp.moveaxis(k_new.astype(jnp.float32), 1, 2)  # [B, KV, Q, hd]
     vn2 = jnp.moveaxis(v_new.astype(jnp.float32), 1, 2)
     pdt = pool["k"].dtype
 
-    blk = lambda shape: pl.BlockSpec(shape, lambda i, j, *_: (i, j, 0, 0))
+    rows = pl.BlockSpec((1, kvh * qr, hd), lambda i, *_: (i, 0, 0))
+    new = pl.BlockSpec((1, kvh, qn, hd), lambda i, *_: (i, 0, 0, 0))
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [blk((1, 1, qr, hd)), blk((1, 1, qn, hd)), blk((1, 1, qn, hd)),
-                any_spec, any_spec]
+    in_specs = [rows, new, new, any_spec, any_spec]
     inputs = [q2, kn2, vn2, pool["k"], pool["v"]]
-    out_specs = [blk((1, 1, qr, hd)), any_spec, any_spec]
+    out_specs = [rows, any_spec, any_spec]
     out_shape = [
-        jax.ShapeDtypeStruct((b, kvh, qr, hd), jnp.float32),
+        jax.ShapeDtypeStruct((b, kvh * qr, hd), jnp.float32),
         jax.ShapeDtypeStruct(pool["k"].shape, pdt),
         jax.ShapeDtypeStruct(pool["v"].shape, pdt),
     ]
     # Input indices include the 2 scalar-prefetch args (table, pos).
     aliases = {5: 1, 6: 2}
     scratch = [
-        pltpu.VMEM((tile_rows, hd), pdt),  # k page tile
-        pltpu.VMEM((tile_rows, hd), pdt),  # v page tile
+        pltpu.VMEM((2, kvh, tile_rows, hd), pdt),  # k page tiles, two slots
+        pltpu.VMEM((2, kvh, tile_rows, hd), pdt),  # v page tiles
     ]
     if scaled:
         ks2 = _scale_rows(pool["k_scale"])
@@ -717,31 +778,29 @@ def paged_attention_kernel(
         out_specs += [any_spec, any_spec]
         out_shape += [jax.ShapeDtypeStruct(ks2.shape, jnp.float32)] * 2
         aliases.update({7: 3, 8: 4})
-        scratch += [pltpu.VMEM((1, ks2.shape[1]), jnp.float32)] * 2
-    scratch += [pltpu.SemaphoreType.DMA((4,))]
+        scratch += [pltpu.VMEM((2, 1, ks2.shape[-1]), jnp.float32)] * 2
+    scratch += [pltpu.SemaphoreType.DMA((2, 4))]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh),
+        grid=(b,),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
     res = pl.pallas_call(
-        functools.partial(
-            _paged_attn_kernel, ps=ps, qn=qn, rep=rep, t=t, kind=kind
-        ),
+        functools.partial(_paged_attn_kernel, ps=ps, qn=qn, rep=rep, t=t,
+                          kind=kind),
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
     )(table, jnp.broadcast_to(pos, (b,)).astype(jnp.int32), *inputs)
-    out = res[0]
     new_pool = {"k": res[1], "v": res[2]}
     if scaled:
-        new_pool["k_scale"] = res[3][:, : kvh * ps].reshape(p_pages, kvh, ps)
-        new_pool["v_scale"] = res[4][:, : kvh * ps].reshape(p_pages, kvh, ps)
-    return _rows_out(out, qn), new_pool
+        new_pool["k_scale"] = res[3][:, 0, : kvh * ps].reshape(p_pages, kvh, ps)
+        new_pool["v_scale"] = res[4][:, 0, : kvh * ps].reshape(p_pages, kvh, ps)
+    return _rows_out(res[0].reshape(b, kvh, qr, hd), qn), new_pool
 
 
 def paged_attention(
@@ -758,21 +817,19 @@ def paged_attention(
 ) -> Tuple:
     """Shape-safe wrapper: fused append + paged flash-decode attention.
 
-    Falls back to the gather-free XLA formulation when the per-program page
-    tiles would not fit the VMEM budget (double-buffered k/v page tiles plus
-    the q/out row blocks). Dispatching between this and the XLA/gather paths
-    lives in :func:`repro.kernels.ops.paged_attention`.
+    Falls back to the gather-free XLA formulation when one program's
+    resident tiles (:func:`_vmem_bytes`, all KV heads of a lane) would not
+    fit the VMEM budget. Dispatching between this and the
+    XLA/gather paths lives in :func:`repro.kernels.ops.paged_attention`.
     """
     b, qn, h, hd = q.shape
     _, kvh, rows, _ = pool["k"].shape  # rows: ps/2 for packed int4
     itemsize = jnp.dtype(pool["k"].dtype).itemsize
-    qr = qn * (h // kvh)
-    # k/v page tiles, the two scale rows, and the q/out row blocks.
-    scale_row = 4 * (kvh * page_size(pool) + (-kvh * page_size(pool)) % 128)
-    tile_bytes = 2 * (2 * rows * hd * itemsize + 2 * scale_row) + 2 * qr * hd * 4
-    if tile_bytes > vmem_budget_bytes:
-        reroute("paged_attention", pool["k"].shape, tile_bytes,
-                vmem_budget_bytes)
+    scaled = pool_kind(pool) in ("int8", "int4")
+    need = _vmem_bytes(kvh, rows, hd, itemsize, page_size(pool),
+                       qn * (h // kvh), qn, scaled)
+    if need > vmem_budget_bytes:
+        reroute("paged_attention", pool["k"].shape, need, vmem_budget_bytes)
         return paged_attention_xla(
             pool, table, pos, q, k_new, v_new, block_tokens=block_tokens
         )

@@ -10,6 +10,7 @@ __all__ = [
     "ocs_gather_ref",
     "fused_quant_matmul_ref",
     "w4a8_matmul_ref",
+    "moe_gmm_ref",
 ]
 
 
@@ -175,3 +176,20 @@ def ocs_quant_matmul_ref(
         acc = acc * jnp.asarray(x_scale, jnp.float32).reshape(-1, 1)
     acc = acc * jnp.asarray(w_scale, jnp.float32).reshape(1, -1)
     return acc.astype(out_dtype)
+
+
+def moe_gmm_ref(x, w8, w_scale, src_tail, mult_tail, group, n_used):
+    """XLA oracle of :func:`moe_gmm`: one ragged matmul of the expanded rows
+    over the int8 stack, each expert on its own tiles' rows, float32
+    accumulation, the expert's column scale after; rows of unused tiles
+    read 0."""
+    from .moe_gmm import column_scales, expand_rows, group_sizes
+
+    e, ke, n = w8.shape
+    bm = x.shape[0] // group.shape[0]
+    x_exp = expand_rows(x, src_tail, mult_tail, group).astype(jnp.float32)
+    y = jax.lax.ragged_dot(
+        x_exp, w8.astype(jnp.float32), group_sizes(group, n_used, e, bm),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+    scale = column_scales(w_scale, e, n)[jnp.repeat(group, bm), 0]
+    return (y * scale).astype(x.dtype)

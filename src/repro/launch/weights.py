@@ -116,12 +116,14 @@ def _lead(shape: Tuple[int, ...]) -> Tuple[int, ...]:
 
 
 def _float_slice(cfg, seed: int, leaf: int, path: str, shape, i: int):
-    """Slice ``i`` (row-major over the stack dims) of one leaf, float32."""
+    """Slice ``i`` (row-major over the stack dims) of one leaf, float32,
+    drawn as the uncut model's slice ``T.param_draw_index``."""
     kind, std = T.param_init_rule(cfg, path, shape)
     sl = shape[len(_lead(shape)):]
     if kind == "normal":
-        w = np.random.default_rng((seed, leaf, i)).standard_normal(
-            sl, dtype=np.float32)
+        w = np.random.default_rng(
+            (seed, leaf, T.param_draw_index(cfg, path, i))
+        ).standard_normal(sl, dtype=np.float32)
         w *= np.float32(std)  # in place: a vocab-sized slice is 2.3 GiB
         return w
     if kind == "a_log":
@@ -260,9 +262,10 @@ def load_quantized(
         o if by_layer[leaf] else o[0] for leaf, o in enumerate(out)
     ])
     # The serving layout: see models.transformer.per_layer.
-    params["layers"] = [
-        jax.tree.map(lambda per: per[i], params["layers"],
-                     is_leaf=lambda x: isinstance(x, list))
-        for i in range(cfg.n_layers)
-    ]
+    is_list = lambda x: isinstance(x, list)
+    params["layers"] = T.split_layers(
+        params["layers"],
+        take=lambda sub, j: jax.tree.map(lambda per: per[j], sub, is_leaf=is_list),
+        count=lambda sub: len(jax.tree.leaves(sub, is_leaf=is_list)[0]),
+    )
     return params

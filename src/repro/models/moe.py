@@ -1,14 +1,21 @@
 """Mixture-of-Experts block: top-k routing, shared experts, EP sharding.
 
-Dispatch is sort-based with per-expert capacity (no [N, E, C] one-hot tensor):
-tokens are ranked within their assigned expert via a stable argsort + segment
-offsets, dropped past capacity, gathered into an [E, C, d] buffer, processed
-by a vmapped expert MLP, and combined back with the routing gates.
+Two execution paths:
 
-Two execution paths share that algorithm:
-
+* **the held-expert layer** (:func:`_moe_held`) — serving and the
+  single-device path. The layer holds experts ``[0, moe.n_held)`` of the
+  router's ``n_experts`` (all of them when ``n_held`` is 0): one chip's
+  share of expert parallelism, run without the exchange. It routes over
+  all ``n_experts`` with the published router, sorts the assignments that
+  land on held experts by expert, pads each expert's rows to the row tile,
+  and computes each held expert's SwiGLU on exactly its rows — a grouped
+  matmul per projection (``kernels/moe_gmm.py`` for int8 OCS experts,
+  ``jax.lax.ragged_dot`` for float ones). It drops nothing,
+  and combines each token's rows in a fixed order: a token's output does
+  not depend on the other tokens of its batch. What absent experts would
+  add is left out.
 * **shard-local dispatch under shard_map** (:func:`_moe_sharded`) — the
-  production training path. Data-dependent scatter/gather cannot be
+  training path on a mesh. Data-dependent scatter/gather cannot be
   partitioned by GSPMD: left to the automatic partitioner it replicates the
   [E*C, d] buffers and all-reduces them (measured 10.4 TB/device/step on
   deepseek-moe-16b train_4k — 60x the model's own traffic). Under shard_map
@@ -16,15 +23,13 @@ Two execution paths share that algorithm:
   (expert axis on 'model'): routing, sorting and the capacity scatter are
   purely local, expert weights' FSDP dim is all-gathered explicitly, and the
   only cross-device traffic is one psum of the [N_local, d] output partials
-  over the expert axis. Capacity is enforced per (data-shard, expert) rather
-  than globally — the standard GShard-style approximation.
-* **single-device / GSPMD fallback** (:func:`_moe_local`) — identical math
-  on one shard; also the serving path for quantized (OCSQuantLinear) expert
-  weights, whose pytree leaves keep their own sharding story.
+  over the expert axis. Capacity (``moe.capacity_factor``) is enforced per
+  (data-shard, expert) — the standard GShard-style approximation.
 
-Supports DeepSeek-MoE fine-grained experts (64 routed, top-6, 2 shared) and
-Phi-3.5-MoE (16 routed, top-2). Shared experts are fused into one wide SwiGLU
-(mathematically identical to summing independent always-on experts).
+Supports DeepSeek-MoE fine-grained experts (64 routed, top-6, 2 shared,
+softmax gates used as they are) and Phi-3.5-MoE (16 routed, top-2, gates
+renormalised: ``moe.norm_topk``). Shared experts are fused into one wide
+SwiGLU (mathematically identical to summing independent always-on experts).
 
 The router stays in full precision and is excluded from PTQ (recipe skip
 pattern 'router') — it is tiny and routing decisions are brittle under
@@ -33,7 +38,8 @@ tables).
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+import contextlib
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,17 +50,20 @@ from repro.configs.base import ModelConfig
 from repro.sharding.specs import activation_rules, logical
 from .layers import dense
 
-__all__ = ["moe_params_shape", "moe"]
+__all__ = ["moe_params_shape", "moe", "counting"]
 
 
 def moe_params_shape(cfg: ModelConfig) -> Dict:
+    """The router scores all ``n_experts``; the expert stacks hold
+    ``moe.held`` of them."""
     d, m = cfg.d_model, cfg.moe
+    e = m.held
     shapes = {
         "router": (d, m.n_experts),
         "experts": {
-            "w_gate": (m.n_experts, d, m.expert_ff),
-            "w_up": (m.n_experts, d, m.expert_ff),
-            "w_down": (m.n_experts, m.expert_ff, d),
+            "w_gate": (e, d, m.expert_ff),
+            "w_up": (e, d, m.expert_ff),
+            "w_down": (e, m.expert_ff, d),
         },
     }
     if m.n_shared:
@@ -90,23 +99,26 @@ def _expert_mlp(w, x):
     return dense(_as_weight(w["w_down"]), jax.nn.silu(g) * u, name="moe_down")
 
 
-def _route(router_w, xf: jnp.ndarray, k: int):
-    """Top-k routing with renormalized gates (f32 softmax)."""
+def _route(router_w, xf: jnp.ndarray, k: int, norm: bool):
+    """Top-k routing over every expert (f32 softmax); the top-k gates
+    renormalised to sum to 1 where ``norm``, else used as they are."""
     logits = dense(router_w, xf.astype(jnp.float32), name="router")
     probs = jax.nn.softmax(logits, axis=-1)
     gate, top_idx = jax.lax.top_k(probs, k)  # [N, k]
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    if norm:
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
     return gate, top_idx
 
 
 def _dispatch_mlp_combine(experts, xf, gate, top_idx, *, n_experts: int,
                           e0, cap: int, dtype) -> jnp.ndarray:
-    """Shard-local sort-based dispatch -> expert MLP -> gated combine.
+    """Shard-local sort-based dispatch -> expert MLP -> gated combine, at
+    most ``cap`` rows per expert (the shard_map training path).
 
     xf: [N, d] tokens held by this shard; experts: stacked weights for the
     ``n_experts`` experts owned by this shard, whose global ids start at
-    ``e0`` (0 on the single-device path). Assignments to other shards'
-    experts fall into the drop slot. Returns this shard's output partial.
+    ``e0``. Assignments to other shards' experts fall into the drop slot.
+    Returns this shard's output partial.
     """
     n, d = xf.shape
     k = top_idx.shape[-1]
@@ -143,15 +155,115 @@ def _capacity(n_tokens: int, k: int, cf: float, e: int) -> int:
     return max(8, -(-cap // 8) * 8)  # pad to a multiple of 8 lanes
 
 
-def _moe_local(params, xf, cfg: ModelConfig) -> jnp.ndarray:
-    """Single-shard path (also GSPMD fallback for quantized expert trees)."""
+# Per held-expert layer traced inside :func:`counting`: int32 [rows, experts
+# touched, dropped].
+_COUNTS: Optional[List[jnp.ndarray]] = None
+
+
+@contextlib.contextmanager
+def counting():
+    """Collect the held-expert layers' counters while a program traces:
+    yields a list that each :func:`_moe_held` call traced inside appends
+    its int32 ``[rows, experts touched, dropped]`` to — the routed rows its
+    held experts computed, the held experts with at least one row, and the
+    held assignments left without a row (0: the layer is dropless)."""
+    global _COUNTS
+    prev, _COUNTS = _COUNTS, []
+    try:
+        yield _COUNTS
+    finally:
+        _COUNTS = prev
+
+
+def _grouped_mlp(experts, rows, group, n_used, bm: int):
+    """Each row tile's expert SwiGLU. rows: [T*bm, d], tile ``t`` holding
+    rows of expert ``group[t]``; tiles from ``n_used`` on hold none.
+
+    One grouped matmul per projection over the expert stacks: int8 OCS
+    experts in dequant mode run ``ops.moe_gmm`` (the Pallas kernel on the
+    Pallas path, its XLA oracle otherwise), float experts
+    ``jax.lax.ragged_dot``. The other matmul modes (w8a8, w4a8) keep
+    :func:`dense`'s own dispatch: :func:`_expert_mlp` batched over the
+    tiles, each with its expert's weights gathered."""
+    from repro.core.ocs import OCSQuantLinear, W4A8Linear
+    from repro.kernels import ops as kops
+    from repro.kernels.moe_gmm import group_sizes
+    from . import layers
+
+    w_gate = experts["w_gate"]
+    if isinstance(w_gate, OCSQuantLinear) and layers.SERVING_MODE == "dequant":
+        force = None if layers.SERVING_KERNEL == "pallas" else "ref"
+
+        def proj(w, x):
+            k = w.n_orig
+            return kops.moe_gmm(x, w.weight.values, w.weight.scale,
+                                w.spec.src[:, k:], w.spec.mult[:, k:],
+                                group, n_used, force=force)
+    elif not isinstance(w_gate, (OCSQuantLinear, W4A8Linear)):
+        sizes = group_sizes(group, n_used, w_gate.shape[0], bm)
+
+        def proj(w, x):
+            return jax.lax.ragged_dot(x, w.astype(x.dtype), sizes)
+    else:
+        w = jax.tree.map(lambda a: a[group], experts)
+        y = jax.vmap(_expert_mlp)(w, rows.reshape(group.shape[0], bm, -1))
+        return y.reshape(rows.shape[0], -1)
+    g = proj(experts["w_gate"], rows)
+    u = proj(experts["w_up"], rows)
+    return proj(experts["w_down"], jax.nn.silu(g) * u)
+
+
+def _moe_held(params, xf, cfg: ModelConfig) -> jnp.ndarray:
+    """The held-expert layer (see the module doc): xf [N, d] -> the routed
+    output of experts ``[0, moe.held)``, dropless."""
+    from repro.kernels.moe_gmm import max_tiles, row_tile
+
     m = cfg.moe
-    gate, top_idx = _route(params["router"], xf, m.top_k)
-    cap = _capacity(xf.shape[0], m.top_k, m.capacity_factor, m.n_experts)
-    return _dispatch_mlp_combine(
-        params["experts"], xf, gate, top_idx,
-        n_experts=m.n_experts, e0=0, cap=cap, dtype=xf.dtype,
-    )
+    e, k = m.held, m.top_k
+    n, d = xf.shape
+    gate, top_idx = _route(params["router"], xf, k, m.norm_topk)
+    # Row tiles sized to the rows an expert expects, from static shapes.
+    bm = row_tile(n * k / m.n_experts, xf.dtype)
+    n_tiles = max_tiles(n, k, e, bm)
+    rows_max = n_tiles * bm
+
+    flat_e = top_idx.reshape(-1)
+    key = jnp.where(flat_e < e, flat_e, e)  # not held -> sorts last
+    order = jnp.argsort(key, stable=True)
+    sorted_e = key[order]
+    held = sorted_e < e
+    counts = jnp.bincount(key, length=e + 1)[:e]  # rows per held expert
+    tiles = (counts + bm - 1) // bm
+    tile_end = jnp.cumsum(tiles)
+    first = jnp.cumsum(counts) - counts  # first sorted assignment per expert
+    ec = jnp.minimum(sorted_e, e - 1)
+    dest = jnp.where(
+        held, (tile_end[ec] - tiles[ec]) * bm + jnp.arange(n * k) - first[ec],
+        rows_max)
+    rows = jnp.zeros((rows_max + 1, d), xf.dtype).at[dest].set(
+        xf[order // k])[:rows_max]
+    # The expert of each tile; tiles past the last used one repeat its
+    # expert, so they start no weight DMA.
+    n_used = tile_end[-1]
+    t = jnp.arange(n_tiles)
+    group = jnp.searchsorted(tile_end, jnp.minimum(t, jnp.maximum(n_used - 1, 0)),
+                             side="right")
+    group = jnp.minimum(group, e - 1).astype(jnp.int32)
+
+    y_rows = _grouped_mlp(params["experts"], rows, group,
+                          n_used.reshape(1).astype(jnp.int32), bm)
+    # Combine: each assignment reads its own row (rows_max: the zero row of
+    # an expert not held), summed over a token's k slots in slot order.
+    at = jnp.zeros((n * k,), jnp.int32).at[order].set(dest)
+    y_rows = jnp.concatenate([y_rows, jnp.zeros((1, d), y_rows.dtype)])
+    y = y_rows[at].astype(jnp.float32).reshape(n, k, d)
+    y = jnp.sum(y * gate[..., None].astype(jnp.float32), axis=1)
+
+    if _COUNTS is not None:
+        placed = jnp.sum(held & (dest < rows_max))
+        _COUNTS.append(jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
+                                  jnp.sum(held) - placed]).astype(jnp.int32))
+    return y.astype(xf.dtype)
 
 
 def _shardmap_axes(mesh, rules) -> Optional[Tuple[Tuple[str, ...], str]]:
@@ -204,7 +316,7 @@ def _moe_sharded(params, xf, cfg: ModelConfig, mesh, batch_axes, model_ax,
     n_local = xf.shape[0] // dsize
     cap = _capacity(n_local, m.top_k, m.capacity_factor, e)
 
-    gate_full, idx_full = _route(params["router"], xf, m.top_k)
+    gate_full, idx_full = _route(params["router"], xf, m.top_k, m.norm_topk)
 
     fsdp_size = mesh.shape[fsdp_ax] if fsdp_ax else 1
     pack = _pack_experts(params["experts"])
@@ -279,7 +391,7 @@ def moe(params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     active = activation_rules()
     use_shardmap = False
     w_gate_leaf = params["experts"]["w_gate"]
-    if active is not None and isinstance(
+    if active is not None and not m.n_held and isinstance(
         w_gate_leaf, (jnp.ndarray, OCSQuantLinear)
     ):
         mesh, rules = active
@@ -301,7 +413,7 @@ def moe(params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
             fsdp_ax = None
         y = _moe_sharded(params, xf, cfg, mesh, batch_axes, model_ax, fsdp_ax)
     else:
-        y = _moe_local(params, xf, cfg)
+        y = _moe_held(params, xf, cfg)
 
     # --- Shared (always-on) experts (dense GSPMD tensor-parallel matmuls).
     if "shared" in params:

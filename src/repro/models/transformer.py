@@ -84,15 +84,23 @@ def layer_params_shape(cfg: ModelConfig) -> Dict:
 
 
 def model_params_shape(cfg: ModelConfig) -> Dict:
+    """The parameter tree's shapes, layers stacked: every leaf of
+    ``layers`` leads with its layer count. A moe block with leading dense
+    layers (``moe.first_dense``) stacks ``layers/mlp`` over those and
+    ``layers/moe`` over the rest; attention and norms over all."""
     d = cfg.d_model
+    stack = lambda tree, n: jax.tree.map(
+        lambda s: (n,) + s, tree, is_leaf=lambda s: isinstance(s, tuple))
+    per_layer = layer_params_shape(cfg)
+    n_dense = cfg.moe.first_dense if cfg.block == "moe" else 0
+    layers = {k: stack(v, cfg.n_layers - (n_dense if k == "moe" else 0))
+              for k, v in per_layer.items()}
+    if n_dense:
+        layers["mlp"] = stack(mlp_params_shape(cfg), n_dense)
     shapes: Dict[str, Any] = {
         "embed": (cfg.vocab, d),
         "final_norm": _norm_shape(cfg, d),
-        "layers": jax.tree.map(
-            lambda s: (cfg.n_layers,) + s,
-            layer_params_shape(cfg),
-            is_leaf=lambda s: isinstance(s, tuple),
-        ),
+        "layers": layers,
     }
     if not cfg.tie_embeddings:
         shapes["lm_head"] = (d, cfg.vocab)
@@ -128,6 +136,19 @@ def param_init_rule(cfg: ModelConfig, path: str, shape) -> Tuple[str, float]:
     return "normal", 1.0 / math.sqrt(shape[-2])
 
 
+def param_draw_index(cfg: ModelConfig, path: str, i: int) -> int:
+    """The slice of the uncut model that slice ``i`` (row-major over the
+    stack dims) of leaf ``path`` stands for. An expert stack that holds
+    ``moe.n_held`` of the router's ``n_experts`` holds experts ``[0,
+    n_held)`` of each layer: its slice ``(l, e)`` is the uncut stack's
+    ``l * n_experts + e``, so the experts held are the draws an uncut model
+    would have."""
+    m = cfg.moe
+    if m is not None and m.n_held and "/experts/" in path:
+        return (i // m.n_held) * m.n_experts + i % m.n_held
+    return i
+
+
 def init_params(cfg: ModelConfig, key, dtype=jnp.float32):
     from repro.core.apply import path_str
 
@@ -150,9 +171,30 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.float32):
     return treedef.unflatten(leaves)
 
 
+def split_layers(layers, take=None, count=None):
+    """Stacked ``layers`` -> a list of per-layer trees. ``take(sub, j)``
+    gives slice ``j`` of a stacked subtree and ``count(sub)`` its length
+    (default: leading array dims). A subtree stacked over fewer layers
+    than the norms covers the leading ones (``mlp``: leading dense layers)
+    or the trailing ones (``moe``)."""
+    take = take or (lambda sub, j: jax.tree.map(lambda a: a[j], sub))
+    count = count or (lambda sub: jax.tree.leaves(sub)[0].shape[0])
+    n = count(layers["norm1"])
+    out = []
+    for i in range(n):
+        tree = {}
+        for k, sub in layers.items():
+            m = count(sub)
+            j = i if m == n or k != "moe" else i - (n - m)
+            if 0 <= j < m:
+                tree[k] = take(sub, j)
+        out.append(tree)
+    return out
+
+
 def per_layer(params):
     """``params`` in the serving layout: ``params["layers"]`` a list of
-    per-layer trees.
+    per-layer trees (:func:`split_layers`).
 
     Training keeps one tree of stacked ``[L, ...]`` leaves (``forward``'s
     ``lax.scan``). The decode and prefill functions take the per-layer
@@ -164,13 +206,29 @@ def per_layer(params):
     layers = params["layers"]
     if isinstance(layers, list):
         return params
-    n = jax.tree.leaves(layers)[0].shape[0]
-    return {**params,
-            "layers": [jax.tree.map(lambda a: a[i], layers) for i in range(n)]}
+    return {**params, "layers": split_layers(layers)}
+
+
+def _stacked_runs(cfg: ModelConfig, layers):
+    """The stacked layer tree as runs of layers of one kind, each scanned
+    on its own: a moe block's leading dense layers, then its moe layers."""
+    if "mlp" not in layers or "moe" not in layers:
+        return [layers]
+    fd = cfg.moe.first_dense
+    common = {k: v for k, v in layers.items() if k not in ("mlp", "moe")}
+    cut = lambda lo, hi: jax.tree.map(lambda a: a[lo:hi], common)
+    return [{**cut(0, fd), "mlp": layers["mlp"]},
+            {**cut(fd, cfg.n_layers), "moe": layers["moe"]}]
 
 
 # ---------------------------------------------------------------------------
 # Block forward (full sequence)
+
+
+def _ffn(cfg: ModelConfig, p, h):
+    """The layer's feed-forward, chosen by its own tree: experts where it
+    holds ``moe``, else the dense MLP (a moe block's leading dense layers)."""
+    return moe(p["moe"], h, cfg) if "moe" in p else mlp(p["mlp"], h, cfg)
 
 
 def _block(cfg: ModelConfig, p, x, positions, layer_flag=None, *, return_kv=False,
@@ -200,9 +258,7 @@ def _block(cfg: ModelConfig, p, x, positions, layer_flag=None, *, return_kv=Fals
             a, kv = a
         x = x + a
         h = _norm(cfg, p["norm2"], x)
-        x = x + (
-            moe(p["moe"], h, cfg) if cfg.block == "moe" else mlp(p["mlp"], h, cfg)
-        )
+        x = x + _ffn(cfg, p, h)
         return (x, kv) if return_kv else x
     if return_kv or kv_prefix is not None:
         raise NotImplementedError(
@@ -313,10 +369,10 @@ def forward(
         body = lambda carry, p: (_block(cfg, p, carry, positions, None), None)
         if cfg.remat:
             body = jax.checkpoint(body)
-        x, _ = jax.lax.scan(body, x, params["layers"])
+        for run in _stacked_runs(cfg, params["layers"]):
+            x, _ = jax.lax.scan(body, x, run)
     else:
-        for i in range(cfg.n_layers):
-            p_i = jax.tree.map(lambda a: a[i], params["layers"])
+        for i, p_i in enumerate(split_layers(params["layers"])):
             f_i = bool(flags[i]) if flags is not None else None
             x = _block(cfg, p_i, x, positions, f_i)
 
@@ -408,8 +464,7 @@ def _decode_block(cfg: ModelConfig, p, x, cache, pos, window: int = 0, table=Non
                                        attn_kernel=attn_kernel)
         x = x + a
         h = _norm(cfg, p["norm2"], x)
-        x = x + (moe(p["moe"], h, cfg) if cfg.block == "moe" else mlp(p["mlp"], h, cfg))
-        return x, new_attn
+        return x + _ffn(cfg, p, h), new_attn
     if cfg.block == "mamba2":
         h = _norm(cfg, p["norm1"], x)
         s, new_ssm = mamba2_decode(p["ssm"], h, cache, cfg)

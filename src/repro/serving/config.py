@@ -392,6 +392,17 @@ class EngineConfig:
             "the calibrated outlier mass marks a site as drifted (> 1)",
         },
     )
+    decode_ahead: bool = dataclasses.field(
+        default=False,
+        metadata={
+            "help": "keep one decode step in flight: dispatch the next step "
+            "on the device-resident tokens and caches of the one running, "
+            "then book that one, so the host's booking, admission and "
+            "dispatch overlap the device (paged, reserve admission, "
+            "whole-prompt prefill, no spec or drift sampling)",
+            "store_true": True,
+        },
+    )
 
     def __post_init__(self):
         if self.max_batch < 1:
@@ -496,6 +507,20 @@ class EngineConfig:
                 "drift_threshold must be > 1 (a site at its calibrated "
                 f"outlier mass is not drifted), got {self.drift_threshold}"
             )
+        if self.decode_ahead:
+            clash = [name for name, on in (
+                ("paged=False", self.paged is False),
+                ("admission='optimistic'", self.admission == "optimistic"),
+                ("prefill_budget > 0", self.prefill_budget > 0),
+                ("spec", self.spec is not None),
+                ("drift_every > 0", self.drift_every > 0),
+            ) if on]
+            if clash:
+                raise ConfigError(
+                    "decode_ahead books a step after the next one is "
+                    "dispatched; it needs lanes that never grow, chunk or "
+                    f"roll back mid-decode, not {', '.join(clash)}"
+                )
         if self.spec is not None and not isinstance(self.spec, SpecConfig):
             raise TypeError(f"spec must be a SpecConfig, got {type(self.spec)}")
         if not isinstance(self.kernels, KernelConfig):

@@ -89,8 +89,9 @@ float model in low precision. This engine is that provider's serving loop:
   ``jit_serving_prefill``, ``jit_serving_prefill_chunk``,
   ``jit_serving_replay``); ``EngineConfig.profile_dir`` wraps
   :meth:`ServingEngine.run` in a ``jax.profiler`` trace window;
-* **stats** — a typed :class:`EngineStats` (schema v11: v8 plus the
-  precision-tier fields, less four unread ones), *derived from the metrics registry* —
+* **stats** — a typed :class:`EngineStats` (schema v12: v8 plus the
+  precision-tier fields and the held-expert counters, less four unread
+  ones), *derived from the metrics registry* —
   percentiles come from registry histograms, counts from registry
   counters; ``stats()`` keeps returning the flat dict view and
   :meth:`ServingEngine.metrics_text` renders the same registry as
@@ -115,6 +116,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn_mod
 from repro.models import layers
+from repro.models import moe as moe_mod
 from repro.models import transformer as T
 from repro.obs.drift import QuantDriftMonitor, clips_from_params
 from repro.obs.log import get_logger
@@ -159,6 +161,29 @@ FINISH_REASONS = ("eos", "length", "cancelled", "timeout", "error", "shed")
 # hang on a request that silently left the queue. "cancelled" is excluded
 # (the documented v5 contract: a cancel simply ends the stream).
 _SENTINEL_REASONS = ("timeout", "error", "shed")
+
+
+# The held-expert counters in stats(), in the order each program tallies
+# them (``models.moe.counting``), each split by program kind.
+MOE_COUNTERS = ("moe_rows", "moe_experts_touched", "moe_dropped")
+
+
+def _committed(tree):
+    """``tree``'s arrays committed to the device each already lives on. A
+    program's outputs come back committed, so state the engine feeds back
+    into its programs starts committed too: a program's first call then has
+    the signature of every later one and is not traced again mid-serving."""
+    return jax.tree.map(
+        lambda a: jax.device_put(a, a.sharding) if isinstance(a, jax.Array)
+        else a, tree)
+
+
+def _tallied(out: Tuple, acc, counts: List) -> Tuple:
+    """A program's outputs; given a counter accumulator ``acc``, also
+    ``acc`` plus the held-expert counters its trace collected."""
+    if acc is None:
+        return out
+    return out + (acc + sum(counts, jnp.zeros((3,), jnp.int32)),)
 
 
 class EngineOverloaded(RuntimeError):
@@ -223,14 +248,18 @@ class TokenEvent:
 
 @dataclasses.dataclass
 class EngineStats:
-    """Typed serving counters (stats schema v11).
+    """Typed serving counters (stats schema v12).
 
     The dict view (:meth:`as_dict`, what ``ServingEngine.stats()`` returns)
     is the stable cross-PR schema consumed by benchmarks — append fields,
-    never rename. v11 removed four fields no consumer read:
-    ``prefill_time_s`` and ``decode_time_s`` (the warm wall times stay
-    engine attributes, behind ``prefill_tok_per_s`` / ``decode_tok_per_s``),
-    ``spec_enabled`` and ``spec_accepted``. v10 additions over v8 (v9 was
+    never rename. v12 adds the held-expert counters of a moe model (0
+    elsewhere), each ``_decode`` and ``_prefill``: ``moe_rows`` (routed rows
+    its held experts computed), ``moe_experts_touched`` ((layer, call, held
+    expert) triples with a row) and ``moe_dropped`` (held assignments left
+    without a row: 0, the layer is dropless). v11 removed four fields no
+    consumer read: ``prefill_time_s`` and ``decode_time_s`` (the warm wall
+    times stay engine attributes, behind ``prefill_tok_per_s`` /
+    ``decode_tok_per_s``), ``spec_enabled`` and ``spec_accepted``. v10 additions over v8 (v9 was
     the router schema rev — ``serving.router`` — no engine fields
     changed): the precision-tier
     fields ``kv_bits`` (0 = float KV, 8 / 4 = quantized page tiers; pairs
@@ -327,6 +356,12 @@ class EngineStats:
     drift_sites: float = 0.0
     drift_flagged_sites: float = 0.0
     drift_max_ratio: float = 0.0
+    moe_rows_decode: float = 0.0
+    moe_rows_prefill: float = 0.0
+    moe_experts_touched_decode: float = 0.0
+    moe_experts_touched_prefill: float = 0.0
+    moe_dropped_decode: float = 0.0
+    moe_dropped_prefill: float = 0.0
 
     def as_dict(self) -> Dict:
         return {f.name: getattr(self, f.name) for f in dataclasses.fields(self)}
@@ -349,6 +384,18 @@ class _Slot:
     @property
     def prefilling(self) -> bool:
         return self.req is not None and self.prefill_pos >= 0
+
+
+@dataclasses.dataclass
+class _Ahead:
+    """A decode step in flight under ``EngineConfig.decode_ahead``: its
+    device outputs, the (lane, request) pairs it decodes for, when it was
+    dispatched and whether that call traced."""
+    nxt: jax.Array
+    finite: jax.Array
+    lanes: List[Tuple[int, "Request"]]
+    t0: float
+    traced: bool
 
 
 def _fold_legacy_kwargs(config: Optional[EngineConfig], legacy: Dict) -> EngineConfig:
@@ -583,6 +630,7 @@ class ServingEngine:
             self.allocator = None
             self.caches = T.init_cache(cfg, self.max_batch, self.max_len,
                                        dtype=jnp.float32)
+        self.caches = _committed(self.caches)
         # Paged decode-attention backend (KernelChoice vocabulary); unpaged
         # engines have no paged path and report "xla" (the dense einsums).
         self.attn_kernel = resolved.attn.value if self.paged else "xla"
@@ -666,8 +714,23 @@ class ServingEngine:
         self.decode_tokens_warm = 0
         self.prefill_traces = 0  # distinct prefill compilations (buckets)
         self.decode_traces = 0
+        # Held-expert counters, int32 [rows, experts touched, dropped] per
+        # program kind, summed on the device (``_counted``); read by stats().
+        self._moe_acc: Optional[Dict[str, jnp.ndarray]] = (
+            {k: _committed(jnp.zeros((3,), jnp.int32))
+             for k in ("decode", "prefill")}
+            if cfg.block == "moe" else None
+        )
 
         self._decode = self._decode_fn()
+        # EngineConfig.decode_ahead: the decode step in flight (dispatched,
+        # not yet booked) and when the last one was booked.
+        if config.decode_ahead and not self.paged:
+            raise ConfigError(
+                f"decode_ahead needs a paged engine; block {cfg.block!r} "
+                "resolved to an unpaged cache")
+        self._inflight: Optional[_Ahead] = None
+        self._t_booked = 0.0
         # Prefill jits per shape key: prompt-length bucket (pow2 padding
         # bounds recompiles) + the sampled flag, plus the prefix-hit page
         # count when paged.
@@ -698,10 +761,29 @@ class ServingEngine:
         programs by that name."""
         impl = self._decode_impl
 
-        def serving_decode_step(params, caches, token, samp, fault, *, sampled):
-            return impl(params, caches, token, samp, fault, sampled=sampled)
+        def serving_decode_step(params, caches, token, samp, fault, acc=None,
+                                *, sampled):
+            with moe_mod.counting() as counts:
+                out = impl(params, caches, token, samp, fault, sampled=sampled)
+            return _tallied(out, acc, counts)
 
-        return jax.jit(serving_decode_step, static_argnames=("sampled",))
+        return self._counted(
+            jax.jit(serving_decode_step, static_argnames=("sampled",)), "decode")
+
+    def _counted(self, fn: Callable, kind: str) -> Callable:
+        """``fn`` (a jitted program whose last argument is an optional
+        counter accumulator) with the held-expert counters of every call
+        added on the device into ``self._moe_acc[kind]``; ``fn`` itself
+        where the model has no expert layer. Nothing waits on them:
+        ``stats()`` reads them."""
+        if self._moe_acc is None:
+            return fn
+
+        def call(*args, **kw):
+            *out, self._moe_acc[kind] = fn(*args, self._moe_acc[kind], **kw)
+            return tuple(out)
+
+        return call
 
     def _decode_impl(self, params, caches, token, samp, fault, *, sampled: bool):
         self.decode_traces += 1  # python side effect: runs only while tracing
@@ -760,11 +842,11 @@ class ServingEngine:
         if self.paged:
 
             def serving_prefill(params, tokens, length, page_ids, prefix_ids,
-                                pools, samp, samp_pos):
+                                pools, samp, samp_pos, acc=None):
                 self.prefill_traces += 1
                 with layers.serving_mode(
                     self.matmul_mode, kernel=self.matmul_kernel
-                ):
+                ), moe_mod.counting() as counts:
                     logits, new_pools = T.prefill_into_pages(
                         params, tokens, self.cfg, pools, page_ids,
                         length=length, prefix_ids=prefix_ids,
@@ -774,15 +856,15 @@ class ServingEngine:
                     nxt = sampling_mod.sample_tokens(logits, samp, samp_pos)
                 else:
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return nxt, finite, new_pools
+                return _tallied((nxt, finite, new_pools), acc, counts)
 
         else:
 
-            def serving_prefill(params, tokens, length, samp):
+            def serving_prefill(params, tokens, length, samp, acc=None):
                 self.prefill_traces += 1
                 with layers.serving_mode(
                     self.matmul_mode, kernel=self.matmul_kernel
-                ):
+                ), moe_mod.counting() as counts:
                     logits, scratch = T.prefill_with_cache(
                         params, tokens, self.cfg, self.max_len,
                         length=length, cache_dtype=jnp.float32,
@@ -792,9 +874,10 @@ class ServingEngine:
                     nxt = sampling_mod.sample_tokens(logits, samp, length - 1)
                 else:
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return nxt, finite, scratch
+                return _tallied((nxt, finite, scratch), acc, counts)
 
-        fn = jax.jit(serving_prefill)  # XLA module jit_serving_prefill(...)
+        # XLA module jit_serving_prefill(...)
+        fn = self._counted(jax.jit(serving_prefill), "prefill")
         self._prefill_cache[key] = fn
         return fn
 
@@ -813,11 +896,11 @@ class ServingEngine:
 
             def serving_prefill_chunk(params, tokens, length, page_ids,
                                       prefix_ids, prefix_len, pools, samp,
-                                      samp_pos):
+                                      samp_pos, acc=None):
                 self.prefill_traces += 1
                 with layers.serving_mode(
                     self.matmul_mode, kernel=self.matmul_kernel
-                ):
+                ), moe_mod.counting() as counts:
                     logits, new_pools = T.prefill_into_pages(
                         params, tokens, self.cfg, pools, page_ids,
                         length=length, prefix_ids=prefix_ids,
@@ -828,17 +911,17 @@ class ServingEngine:
                     nxt = sampling_mod.sample_tokens(logits, samp, samp_pos)
                 else:
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return nxt, finite, new_pools
+                return _tallied((nxt, finite, new_pools), acc, counts)
 
         else:
             prefix_pad = key[1]
 
             def serving_prefill_chunk(params, tokens, length, start, scratch,
-                                      samp, samp_pos):
+                                      samp, samp_pos, acc=None):
                 self.prefill_traces += 1
                 with layers.serving_mode(
                     self.matmul_mode, kernel=self.matmul_kernel
-                ):
+                ), moe_mod.counting() as counts:
                     logits, new_scratch = T.prefill_chunk_with_cache(
                         params, tokens, self.cfg, scratch,
                         start=start, length=length, prefix_pad=prefix_pad,
@@ -848,9 +931,10 @@ class ServingEngine:
                     nxt = sampling_mod.sample_tokens(logits, samp, samp_pos)
                 else:
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return nxt, finite, new_scratch
+                return _tallied((nxt, finite, new_scratch), acc, counts)
 
-        fn = jax.jit(serving_prefill_chunk)  # jit_serving_prefill_chunk(...)
+        # jit_serving_prefill_chunk(...)
+        fn = self._counted(jax.jit(serving_prefill_chunk), "prefill")
         self._chunk_cache[key] = fn
         return fn
 
@@ -2137,7 +2221,8 @@ class ServingEngine:
         ``book`` spans inside it, every one stamped with ``step`` = ``steps``
         at the iteration's start; with ``drift_every`` set, every Nth
         productive step samples the quant-drift monitor *after* the timed
-        window."""
+        window. With ``decode_ahead`` the decode step a call dispatches is
+        booked by the next call (:meth:`_step_ahead`)."""
         with self._span("engine.step", "step") as sp:
             self._step_timer.start()
             try:
@@ -2198,6 +2283,12 @@ class ServingEngine:
         with self._span("engine.admit"):
             self._shed_expired()
             self._admit()
+        if self.config.decode_ahead:
+            if not self._fault_at:
+                return self._step_ahead()
+            # An injected fault names an output index: book the step in
+            # flight so the fault row below counts every token.
+            self._drain_ahead()
         if self.chunked:
             # Budgeted prefill work first: decode lanes then step below in
             # the same iteration — one chunk's worth of prefill latency is
@@ -2228,6 +2319,25 @@ class ServingEngine:
             return True  # growth preempted every lane; re-admit next step
         n_active = sum(1 for s in self.slots if s.req and not s.prefilling)
         t0 = time.perf_counter()
+        nxt, finite, traced = self._dispatch_decode(n_active)
+        with self._span("engine.wait"):
+            nxt_np = np.asarray(nxt)  # sync point: decode step fully retired
+            finite_np = np.asarray(finite)
+        elapsed = time.perf_counter() - t0
+        if traced:
+            self.decode_compile_s += elapsed
+        else:
+            self.decode_time_s += elapsed
+            self.decode_tokens_warm += n_active
+        with self._span("engine.book"):
+            self._book_decode(nxt, nxt_np, finite_np)
+        self.steps += 1
+        return True
+
+    def _dispatch_decode(self, n_lanes: int):
+        """Dispatch one decode step on the engine's tokens and caches (the
+        caches become its outputs): its next tokens, finite flags and
+        whether the call traced."""
         with self._span("engine.inputs"):
             # Static per-round flag: greedy-only rounds skip the sampling
             # branch entirely (no sort/softmax over [B, V] per step). Both
@@ -2238,34 +2348,34 @@ class ServingEngine:
             fault = jnp.asarray(self._fault_row())
         traces0 = self.decode_traces
         with self._span("engine.dispatch", "decode_step", program="decode",
-                        lanes=n_active) as d:
+                        lanes=n_lanes) as d:
             nxt, finite, self.caches = self._decode(
                 self.params, self.caches, self.tokens, samp, fault,
                 sampled=sampled,
             )
             d.set(traced=self.decode_traces > traces0)
-        with self._span("engine.wait"):
-            nxt_np = np.asarray(nxt)  # sync point: decode step fully retired
-            finite_np = np.asarray(finite)
-        elapsed = time.perf_counter() - t0
-        if self.decode_traces > traces0:
-            self.decode_compile_s += elapsed
-        else:
-            self.decode_time_s += elapsed
-            self.decode_tokens_warm += n_active
-        with self._span("engine.book"):
-            self._book_decode(nxt, nxt_np, finite_np)
-        self.steps += 1
-        return True
+        return nxt, finite, self.decode_traces > traces0
 
     def _book_decode(self, nxt, nxt_np: np.ndarray, finite_np: np.ndarray):
         """Book one decode step's tokens: ITL observations, eos/length
         retirement, quarantine of lanes whose logits went nonfinite."""
+        # Mid-prefill lanes decode into their trash rows: nothing to book.
+        self._book_lanes(self._decoding_lanes(), nxt_np, finite_np)
+        self.tokens = nxt
+
+    def _decoding_lanes(self) -> List[Tuple[int, Request]]:
+        return [(i, s.req) for i, s in enumerate(self.slots)
+                if s.req is not None and not s.prefilling]
+
+    def _book_lanes(self, lanes, nxt_np: np.ndarray, finite_np: np.ndarray):
+        """Book lane ``i``'s token for each ``(i, req)`` of ``lanes`` whose
+        lane still holds ``req``."""
         now = time.perf_counter()
         faulted: List[Request] = []
-        for i, slot in enumerate(self.slots):
-            if slot.req is None or slot.prefilling:
-                continue  # mid-prefill lanes decode into their trash rows
+        for i, req in lanes:
+            slot = self.slots[i]
+            if slot.req is not req:
+                continue  # retired since the step was dispatched
             if not bool(finite_np[i]):
                 # Nonfinite logits: the lane's "token" is garbage — book
                 # nothing, quarantine the request, free the lane. Neighbour
@@ -2287,9 +2397,60 @@ class ServingEngine:
             elif slot.remaining <= 0:
                 slot.req.finish_reason = "length"
                 self._retire(i)
-        self.tokens = nxt
         for r in faulted:
             self._note_fault(r)
+
+    def _step_ahead(self):
+        """One iteration under ``EngineConfig.decode_ahead``: dispatch the
+        next decode step on the tokens and caches of the step in flight
+        (device arrays, so the call queues behind it), then wait for and
+        book that step. The device runs a step while the host books the
+        last one, admits and dispatches. A lane whose length ends at the
+        step in flight books nothing from the next one; a lane that ends
+        there by eos or a nonfinite logit decodes one token more, which is
+        dropped. Either way the extra row writes only into the lane's own
+        pages, and every later program that reuses them queues behind it.
+        A lane freed at booking takes its next request one step later than
+        the plain path would."""
+        prev = self._inflight
+        pending = dict(prev.lanes) if prev is not None else {}
+        # A lane whose last token is in flight decodes no further.
+        lanes = [(i, r) for i, r in self._decoding_lanes()
+                 if self.slots[i].remaining > (1 if pending.get(i) is r else 0)]
+        self._inflight = None
+        if lanes:
+            t0 = time.perf_counter()
+            nxt, finite, traced = self._dispatch_decode(len(lanes))
+            self.tokens = nxt
+            self._inflight = _Ahead(nxt, finite, lanes, t0, traced)
+        if prev is not None:
+            self._book_ahead(prev)
+        return self._inflight is not None or any(s.req for s in self.slots)
+
+    def _book_ahead(self, a: _Ahead) -> None:
+        """Wait for the decode step ``a`` and book its tokens."""
+        with self._span("engine.wait"):
+            nxt_np = np.asarray(a.nxt)
+            finite_np = np.asarray(a.finite)
+        now = time.perf_counter()
+        # Steps overlap: each books the time since the later of its
+        # dispatch and the last booking.
+        elapsed = now - max(a.t0, self._t_booked)
+        self._t_booked = now
+        if a.traced:
+            self.decode_compile_s += elapsed
+        else:
+            self.decode_time_s += elapsed
+            self.decode_tokens_warm += len(a.lanes)
+        with self._span("engine.book"):
+            self._book_lanes(a.lanes, nxt_np, finite_np)
+        self.steps += 1
+
+    def _drain_ahead(self) -> None:
+        """Wait for and book the decode step in flight, if there is one."""
+        a, self._inflight = self._inflight, None
+        if a is not None:
+            self._book_ahead(a)
 
     def run(self, max_steps: int = 10_000) -> List[Request]:
         """Drive until queue and slots drain (or the step budget ends).
@@ -2459,6 +2620,13 @@ class ServingEngine:
                 "per-token KV cache footprint across all layers").set(
             float(kvc.kv_bytes_per_token(self.cfg)) if self.paged else 0.0
         )
+        for kind in ("decode", "prefill"):
+            acc = (np.asarray(self._moe_acc[kind]) if self._moe_acc
+                   else np.zeros(3))
+            for name, v in zip(MOE_COUNTERS, acc):
+                m.gauge(f"{name}_{kind}",
+                        f"held-expert {name[4:]} in {kind} programs").set(
+                    float(v))
         m.gauge("kv_pool_capacity_tokens",
                 "page-pool capacity expressed in tokens").set(
             float(alloc.capacity * self.page_size) if alloc else 0.0
@@ -2482,7 +2650,7 @@ class ServingEngine:
         return self._drift.report() if self._drift is not None else {}
 
     def engine_stats(self) -> EngineStats:
-        """The typed v11 stats record (``stats()`` is its flat dict view),
+        """The typed v12 stats record (``stats()`` is its flat dict view),
         derived from the metrics registry: counts read registry counters
         (through the legacy attribute facade), percentiles read the
         bounded-reservoir registry histograms booked live at the event
@@ -2566,6 +2734,8 @@ class ServingEngine:
                 float(self.trace.dropped) if self.trace is not None else 0.0
             ),
             drift_enabled=1.0 if self._drift is not None else 0.0,
+            **{f"{name}_{kind}": gv(f"{name}_{kind}")
+               for name in MOE_COUNTERS for kind in ("decode", "prefill")},
         )
         if self._spec is not None:
             for k, v in self._spec.stats().items():
@@ -2576,7 +2746,7 @@ class ServingEngine:
         return s
 
     def stats(self) -> Dict:
-        """The flat dict view of :meth:`engine_stats` (stats schema v11)."""
+        """The flat dict view of :meth:`engine_stats` (stats schema v12)."""
         return self.engine_stats().as_dict()
 
 

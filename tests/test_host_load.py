@@ -26,6 +26,7 @@ from repro.core import histogram, ocs, quantizer
 from repro.core.apply import quantize_params
 from repro.launch import weights
 from repro.launch.weights import SERVE_RECIPE, init_params_host, load_quantized
+from repro.models import transformer as T
 
 
 def _run_splits_oracle(w, n_splits, delta, qa):
@@ -176,7 +177,9 @@ def test_load_quantized_matches_whole_tree_quantize_params(
         monkeypatch.setattr(weights, "_host_available", lambda: 2**40)
     got = load_quantized(cfg, seed=11)
     assert isinstance(got["layers"], list) and len(got["layers"]) == cfg.n_layers
-    got["layers"] = jax.tree.map(lambda *xs: jnp.stack(xs), *got["layers"])
+    # The serving layout of the same tree (a moe model's leading dense
+    # layer and its expert layers hold different subtrees).
+    want = T.per_layer(want)
     got_l, got_t = jax.tree_util.tree_flatten(got)
     want_l, want_t = jax.tree_util.tree_flatten(want)
     assert got_t == want_t
@@ -200,3 +203,20 @@ def test_init_params_host_is_seeded_per_slice():
                                   c["final_norm"]["scale"])
     w = np.asarray(a["layers"]["mlp"]["w_up"])
     assert not np.array_equal(w[0], w[1])  # layers draw distinct slices
+
+
+def test_held_experts_are_the_uncut_models_draws():
+    """A model holding experts [0, n_held) of each layer draws them as the
+    uncut model does; everything outside the expert stacks is the same."""
+    cfg = smoke_config("deepseek-moe-16b")
+    cut = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, n_held=3))
+    whole, held = init_params_host(cfg, seed=5), init_params_host(cut, seed=5)
+    for name, w in whole["layers"]["moe"]["experts"].items():
+        np.testing.assert_array_equal(
+            np.asarray(held["layers"]["moe"]["experts"][name]),
+            np.asarray(w)[:, :3])
+    np.testing.assert_array_equal(np.asarray(held["layers"]["moe"]["router"]),
+                                  np.asarray(whole["layers"]["moe"]["router"]))
+    np.testing.assert_array_equal(np.asarray(held["layers"]["mlp"]["w_up"]),
+                                  np.asarray(whole["layers"]["mlp"]["w_up"]))
+

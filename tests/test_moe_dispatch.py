@@ -1,7 +1,7 @@
 """MoE shard-local dispatch: multi-device equivalence (subprocess).
 
 The shard_map dispatch path must produce the same outputs as the
-single-device reference on a real multi-device mesh (2 data x 2 model, with
+single-device held-expert layer on a real multi-device mesh (2 data x 2 model, with
 experts split across the model axis and tokens across the data axis).
 Runs in a subprocess because the 8-device XLA flag must be set before jax
 initializes.
@@ -27,15 +27,15 @@ SCRIPT = textwrap.dedent("""
     import dataclasses
     cfg = smoke_config("deepseek-moe-16b")  # 8 experts top-3, 1 shared
     # Capacity high enough that nothing drops: the sharded path enforces
-    # capacity per (data-shard, expert) while the reference is global, so
-    # only the drop-free regime is bit-comparable.
+    # capacity per (data-shard, expert) while the held-expert layer is
+    # dropless, so only the drop-free regime is comparable.
     cfg = dataclasses.replace(
         cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
     params = T.init_params(cfg, jax.random.PRNGKey(0))
     layer0 = jax.tree.map(lambda a: a[0], params["layers"])["moe"]
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model), jnp.float32)
 
-    ref = moe_mod._moe_local(layer0, x.reshape(-1, cfg.d_model), cfg)
+    ref = moe_mod._moe_held(layer0, x.reshape(-1, cfg.d_model), cfg)
     if "shared" in layer0:
         sh = layer0["shared"]
         xf = x.reshape(-1, cfg.d_model)
@@ -43,7 +43,8 @@ SCRIPT = textwrap.dedent("""
         ref = ref + (jax.nn.silu(g) * u) @ sh["w_down"]
     ref = ref.reshape(x.shape)
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh(2, 2)
     with use_rules(mesh, SINGLE_POD_RULES):
         out = moe_mod.moe(layer0, x, cfg)
     d = float(jnp.abs(out - ref).max())

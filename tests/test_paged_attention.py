@@ -109,6 +109,46 @@ def test_kernel_and_xla_match_gather_oracle(ps, qn, int8):
         assert (np.asarray(p_ref[key]) == np.asarray(p_krn[key])).all(), key
 
 
+@pytest.mark.parametrize("qn", [1, 4])
+@pytest.mark.parametrize("int8", [False, True])
+def test_kernel_matches_gather_oracle_with_one_query_head_per_kv_head(qn, int8):
+    """MHA (16 KV heads in deepseek-moe-16b, 4 here): one query row a head
+    at Q=1, four verify rows at Q=4, every head of the lane in one
+    program."""
+    rng = np.random.RandomState(17 + qn + 2 * int8)
+    args = _mk_case(rng, int8, qn, 16, KV=4, rep=1)
+    o_ref, p_ref = ops.paged_attention(*args, force="gather")
+    o_krn, p_krn = ops.paged_attention(*args, force="interpret")
+    ref = np.asarray(o_ref)
+    # Same f32 math as the oracle, online softmax and another summation
+    # order (the test above's tolerances).
+    tol = 0.02 * np.abs(ref).max() if int8 else 2e-6
+    np.testing.assert_allclose(np.asarray(o_krn), ref, atol=tol, rtol=0 if int8 else tol)
+    for key in p_ref:
+        assert (np.asarray(p_ref[key]) == np.asarray(p_krn[key])).all(), key
+
+
+@pytest.mark.parametrize("int8", [False, True])
+def test_past_the_vmem_budget_reroutes_to_xla(int8):
+    """Where one program's tiles for all 16 KV heads of a lane would not
+    fit the budget, the wrapper runs the XLA formulation and books the
+    reroute (the chip run refuses one), with the XLA path's result."""
+    from repro.kernels import budget
+    rng = np.random.RandomState(21)
+    args = _mk_case(rng, int8, 2, 16, KV=16, rep=1)
+    budget.clear_reroutes()
+    o_small, p_small = pa.paged_attention(*args, vmem_budget_bytes=1 << 16,
+                                          interpret=True)
+    assert list(budget.reroutes()) == [f"paged_attention{args[0]['k'].shape}"]
+    budget.clear_reroutes()
+    o_xla, p_xla = pa.paged_attention_xla(*args)
+    np.testing.assert_array_equal(np.asarray(o_small), np.asarray(o_xla))
+    for key in p_xla:
+        assert (np.asarray(p_small[key]) == np.asarray(p_xla[key])).all(), key
+    pa.paged_attention(*args, interpret=True)
+    assert budget.reroutes() == {}
+
+
 @pytest.mark.parametrize("int8", [False, True])
 def test_append_fusion_matches_append_tokens(int8):
     """The fused dispatch's pool == kv_cache.append_tokens' pool, bitwise:

@@ -170,6 +170,28 @@ def test_engine_w8a8_serving(dense_setup):
     assert agree >= 4  # half the tokens (random-weight smoke model: noisy)
 
 
+@pytest.mark.parametrize("arch", ["glm4-9b", "deepseek-moe-16b"])
+def test_each_program_traces_once_on_committed_params(arch):
+    """Weights committed to a device (as the loader places them): the state
+    the engine feeds back into its programs (page pools, the held-expert
+    counters) starts committed, as the programs' outputs come back, so a
+    program's first call has the signature of every later one and a bucket
+    met again after other programs ran is not traced again."""
+    cfg = smoke_config(arch)
+    params = jax.device_put(T.init_params(cfg, jax.random.PRNGKey(0)),
+                            jax.devices()[0])
+    eng = ServingEngine(cfg, params, EngineConfig(max_batch=2, max_len=64))
+    state = jax.tree.leaves((eng.caches, eng._moe_acc))
+    assert state and all(a.committed for a in state)
+    rng = np.random.default_rng(1)
+    for lengths in ([5], [20, 6], [7]):  # buckets 8, then 32 and 8, then 8
+        for r in _mk_requests(rng, cfg.vocab, lengths, max_new=3):
+            eng.submit(r)
+        eng.run()
+    assert eng.prefill_traces == 2
+    assert eng.decode_traces == 1
+
+
 def test_stats_schema(dense_setup):
     cfg, params = dense_setup
     eng = ServingEngine(cfg, params, EngineConfig(max_batch=1, max_len=32))

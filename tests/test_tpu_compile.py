@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import budget
 from repro.kernels.dynamic_quant import dynamic_quant
 from repro.kernels import fused_qmatmul as fq
+from repro.kernels import moe_gmm as mg
 from repro.kernels import ocs_matmul as om
 from repro.kernels import paged_attention as pa
 
@@ -77,6 +78,45 @@ def test_paged_attention_compiles(sds, kind, qn):
             sds((B, qn, H, HD), jnp.bfloat16), sds((B, qn, KV, HD), jnp.bfloat16),
             sds((B, qn, KV, HD), jnp.bfloat16))
     assert "tpu_custom_call" in _hlo(pa.paged_attention, *args)
+
+
+@pytest.mark.parametrize("qn", [1, 4])
+@pytest.mark.parametrize("kv,lanes", [(16, 32), (32, 8)],
+                         ids=["deepseek-moe-16b", "deepseek-7b"])
+def test_paged_attention_compiles_at_mha_head_counts(sds, kv, lanes, qn):
+    """int8 pages of 16 tokens at 16 KV heads of 128 (MHA, 32 lanes of 512
+    tokens) and at 32 (8 lanes): one program per lane takes all its heads
+    (no reroute), each page one DMA per tile."""
+    budget.clear_reroutes()
+    p = lanes * T + 1
+    pool = {"k": sds((p, kv, PS, HD), jnp.int8),
+            "v": sds((p, kv, PS, HD), jnp.int8),
+            "k_scale": sds((p, kv, PS), jnp.float32),
+            "v_scale": sds((p, kv, PS), jnp.float32)}
+    args = (pool, sds((lanes, T), jnp.int32), sds((lanes,), jnp.int32),
+            sds((lanes, qn, kv, HD), jnp.bfloat16),
+            sds((lanes, qn, kv, HD), jnp.bfloat16),
+            sds((lanes, qn, kv, HD), jnp.bfloat16))
+    assert "tpu_custom_call" in _hlo(pa.paged_attention, *args)
+    assert budget.reroutes() == {}
+
+
+@pytest.mark.parametrize("tokens", [32, 128, 512])
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_moe_gmm_compiles(sds, tokens, k, n):
+    """deepseek-moe-16b's expert projections over its 16 held experts: a
+    32-lane decode step (about 3 rows an expert) and prefills of 128 and
+    512 tokens, each at the layer's row tile and tile count."""
+    e, topk, n_experts = 16, 6, 64
+    bm = mg.row_tile(tokens * topk / n_experts, jnp.bfloat16)
+    t = mg.max_tiles(tokens, topk, e, bm)
+    ke = k + 128  # the serving recipe's OCS tail, padded to 128
+    args = (sds((t * bm, k), jnp.bfloat16), sds((e, ke, n), jnp.int8),
+            sds((e, 1, n), jnp.float32), sds((e, 128), jnp.int32),
+            sds((e, 128), jnp.float32), sds((t,), jnp.int32),
+            sds((1,), jnp.int32))
+    hlo = _hlo(mg.moe_gmm, *args)
+    assert "tpu_custom_call" in hlo and "%moe_gmm" in hlo
 
 
 def test_ocs_quant_matmul_compiles(sds):
