@@ -12,21 +12,25 @@ replaces that with one flash-decode-style dispatch that consumes the pool
 * **fused append** — the current Q tokens' K/V rows are quantized (int8
   pools) and DMA'd into their pages inside the kernel, so decode is one
   dispatch instead of scatter + gather + attention;
-* **block-table page loads** — each grid program (one lane) walks its
-  lane's block-table row and DMAs each page for all its KV heads at once
-  (one ``[KV, page_size, hd]`` tile of K, one of V, one scale row each),
-  the next page in flight while this one computes, so 16 heads cost a
-  lane one DMA per tile and page, not 16; nothing per-lane is ever
-  materialized in HBM;
+* **page blocks** — each grid program (one lane) walks its lane's
+  block-table row in blocks of ``G`` pages (:func:`plan_pages`, from the
+  pool's static shapes: at most :data:`BLOCK_TOKENS` tokens, within half
+  the VMEM budget; 16 pages at glm4-9b's 2 KV heads, 8 at 16). Each page
+  is one DMA per tile for all its KV heads (one ``[KV, page_size, hd]``
+  tile of K, one of V, one scale row each); every page of the next block
+  is in flight while this one computes (two block slots), and a full
+  block is waited on once per array. Pages past the lane's last are never
+  loaded; their stale slots, like the trash page, are select-zeroed and
+  masked. Nothing per-lane is ever materialized in HBM;
 * **in-VMEM dequant** — int8 pages are converted in VMEM and their
   per-token scales (the paper's linear grid) act on the scores and on the
   softmax weights, lane by lane; packed int4 tiles are dequantized row by
   row right after the load;
-* **online softmax** — the flash recurrence accumulates across pages, so
-  the loop stops after ``(pos + Q - 1) // page_size + 1`` pages: work scales
-  with the tokens attended, not the table extent. All heads
-  attend a page in one pair of matmuls, each query row masked to its own
-  head's keys;
+* **online softmax** — the flash recurrence accumulates across blocks,
+  so the loop stops after ``(pos + Q - 1) // page_size + 1`` pages: work
+  scales with the tokens attended, not the table extent. All heads and
+  pages of a block attend in one pair of matmuls and one softmax update,
+  each query row masked to its own head's keys;
 * **position masking** — per-lane causal masks (query ``j`` sees positions
   ``<= pos + j``) *and* an explicit trash-page mask: page-0 loads are
   select-zeroed before the dots, so a poisoned (even NaN) trash page can
@@ -34,7 +38,10 @@ replaces that with one flash-decode-style dispatch that consumes the pool
 
 ``Q > 1`` queries run the speculative ``verify_step`` through the same
 kernel: rows are laid out ``(query j, rep r)`` row-major, so row ``qr``
-masks against ``pos + qr // rep``.
+masks against ``pos + qr // rep``. ``G`` does not depend on ``Q``, so the
+decode and verify calls of one engine sum a row's keys in the same order.
+The int4 tier keeps one page a step: its per-page recurrence
+(``_int4_flash_step``) is what makes it bitwise equal to its fallbacks.
 
 Numerics: the kernel computes attention in f32 after dequant. Float pages
 match the gather oracle to float tolerance (online vs one-shot softmax);
@@ -78,12 +85,15 @@ __all__ = [
     "paged_attention_xla",
     "paged_attention_kernel",
     "paged_attention",
+    "plan_pages",
+    "BLOCK_TOKENS",
     "VMEM_BUDGET_BYTES",
 ]
 
 NEG_INF = -1e30  # finite: exp(NEG_INF - NEG_INF) == 1, never NaN
 TRASH_PAGE = 0  # reserved pool page (serving.kv_cache.TRASH_PAGE): never read
 KV4_QMAX = 7.0  # symmetric int4 grid: quantized values live in [-7, 7]
+BLOCK_TOKENS = 256  # most tokens one step of the kernel's flash loop takes
 
 
 def quant_rows(x: jnp.ndarray, qmax: float = 127.0):
@@ -514,6 +524,7 @@ def _paged_attn_kernel(
     qn: int,
     rep: int,
     t: int,
+    g: int,
     kind: str,
 ):
     scaled = kind in ("int8", "int4")
@@ -527,22 +538,23 @@ def _paged_attn_kernel(
     qr = qn * rep
     half = ps // 2
     kvh, hd = kn_ref.shape[1], q_ref.shape[-1]
-    n_rows, n_keys = kvh * qr, kvh * ps
+    n_rows, page_keys = kvh * qr, kvh * ps
+    n_keys = g * page_keys
 
-    def copies(pid, slot, to_vmem: bool):
-        # One page for all the lane's heads: a [KV, rows, hd] tile of K and
-        # of V and the lane-dense scale rows, one DMA each. Reads go
-        # through the *output* refs (the aliased buffers), so every append
-        # is visible to the loads after it.
-        pairs = [(k_out.at[pid], k_scr.at[slot]),
-                 (v_out.at[pid], v_scr.at[slot])]
-        if scaled:
-            pairs += [(ks_out.at[pid], ks_scr.at[slot]),
-                      (vs_out.at[pid], vs_scr.at[slot])]
+    hbm = [k_out, v_out] + ([ks_out, vs_out] if scaled else [])
+    vmem = [k_scr, v_scr] + ([ks_scr, vs_scr] if scaled else [])
+
+    def copies(pid, slot, j, to_vmem: bool):
+        # One page for all the lane's heads, into page j of a block slot: a
+        # [KV, rows, hd] tile of K and of V and the lane-dense scale rows,
+        # one DMA each. Reads go through the *output* refs (the aliased
+        # buffers), so every append is visible to the loads after it.
         return [
-            pltpu.make_async_copy(*((h, v) if to_vmem else (v, h)),
-                                  sems.at[slot, i])
-            for i, (h, v) in enumerate(pairs)
+            pltpu.make_async_copy(
+                *((h.at[pid], v.at[slot, j]) if to_vmem
+                  else (v.at[slot, j], h.at[pid])),
+                sems.at[slot, i])
+            for i, (h, v) in enumerate(zip(hbm, vmem))
         ]
 
     def run(dmas):
@@ -561,21 +573,21 @@ def _paged_attn_kernel(
         lin = jnp.minimum(jnp.maximum(pos_b + j, 0), t * ps - 1)
         pid = table_ref[b, lin // ps]
         slot = lin % ps
-        run(copies(pid, 0, to_vmem=True))
+        run(copies(pid, 0, 0, to_vmem=True))
         for h in range(kvh):
             kr = kn_ref[0, h, j : j + 1, :].astype(jnp.float32)  # [1, hd]
             vr = vn_ref[0, h, j : j + 1, :].astype(jnp.float32)
             if not scaled:
                 hit = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) == slot
                 for row, scr in ((kr, k_scr), (vr, v_scr)):
-                    scr[0, h] = jnp.where(
-                        hit, row, scr[0, h].astype(jnp.float32)
+                    scr[0, 0, h] = jnp.where(
+                        hit, row, scr[0, 0, h].astype(jnp.float32)
                     ).astype(scr.dtype)
                 continue
             # quant_rows, inlined: same grid as every other pool writer
             # (qmax 127 for int8 pages, KV4_QMAX for packed int4 pages).
             qm = 127.0 if kind == "int8" else KV4_QMAX
-            s_hit = (jax.lax.broadcasted_iota(jnp.int32, ks_scr.shape[1:], 1)
+            s_hit = (jax.lax.broadcasted_iota(jnp.int32, ks_scr.shape[2:], 1)
                      == h * ps + slot)
             for row, scr, s_scr in ((kr, k_scr, ks_scr), (vr, v_scr, vs_scr)):
                 amax = jnp.max(jnp.abs(row), axis=-1, keepdims=True)
@@ -583,7 +595,7 @@ def _paged_attn_kernel(
                 qrow = jnp.clip(
                     jnp.floor(row * (1.0 / sc) + 0.5), -qm, qm
                 ).astype(jnp.int32)
-                cur = scr[0, h].astype(jnp.int32)
+                cur = scr[0, 0, h].astype(jnp.int32)
                 if kind == "int4":
                     hi = slot >= half
                     nib = qrow & 0xF
@@ -594,35 +606,56 @@ def _paged_attn_kernel(
                 else:
                     new = qrow
                     hit = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) == slot
-                scr[0, h] = jnp.where(hit, new, cur).astype(scr.dtype)
-                s_scr[0] = jnp.where(s_hit, sc, s_scr[0])
-        run(copies(pid, 0, to_vmem=False))
+                scr[0, 0, h] = jnp.where(hit, new, cur).astype(scr.dtype)
+                s_scr[0, 0] = jnp.where(s_hit, sc, s_scr[0, 0])
+        run(copies(pid, 0, 0, to_vmem=False))
 
-    # ---- flash loop over this lane's active pages only, the next page's
-    # DMAs in flight while this one computes (two scratch slots).
+    # ---- flash loop over this lane's active pages only, in blocks of g
+    # pages: every page of the next block in flight while this one
+    # computes (two block slots). Pages past the lane's last are never
+    # loaded; their stale slots are masked below.
     n_active = jnp.minimum(t, (pos_b + qn - 1) // ps + 1)  # >= 1
-    for d in copies(table_ref[b, 0], 0, to_vmem=True):
-        d.start()
+    n_blocks = (n_active + g - 1) // g
 
-    def page(ti):
-        """Wait for page ``ti``, start page ``ti + 1``; its slot and
-        whether it is readable."""
-        slot = ti % 2
+    def start(blk, slot):
+        for j in range(g):
+            @pl.when(blk * g + j < n_active)
+            def _load():
+                for d in copies(table_ref[b, blk * g + j], slot, j, to_vmem=True):
+                    d.start()
 
-        @pl.when(ti + 1 < n_active)
-        def _prefetch():
-            for d in copies(table_ref[b, ti + 1], 1 - slot, to_vmem=True):
-                d.start()
+    def wait(blk, slot):
+        """Wait for block ``blk``; per page of it, whether it is readable
+        (loaded, and not the trash page)."""
+        full = (blk + 1) * g <= n_active
 
-        for d in copies(table_ref[b, ti], slot, to_vmem=True):
-            d.wait()
-        return slot, table_ref[b, ti] != TRASH_PAGE
+        # A full block's pages all signal one semaphore per array: one
+        # wait for the whole slot's bytes. The last block waits page by page.
+        @pl.when(full)
+        def _whole():
+            for i, (h, v) in enumerate(zip(hbm, vmem)):
+                pltpu.make_async_copy(h.at[pl.ds(0, g)], v.at[slot],
+                                      sems.at[slot, i]).wait()
+
+        ok = []
+        for j in range(g):
+            idx = blk * g + j
+            if g > 1:
+                @pl.when(jnp.logical_not(full) & (idx < n_active))
+                def _page():
+                    for d in copies(0, slot, j, to_vmem=True):
+                        d.wait()
+            ok.append((idx < n_active)
+                      & (table_ref[b, jnp.minimum(idx, t - 1)] != TRASH_PAGE))
+        return ok
+
+    start(0, 0)
 
     if kind == "int4":
-        # int4 tier: the shared _int4_flash_step recurrence on 2-D per-head
-        # slices against the dequantized tiles — the same op sequence the
-        # XLA fallback and the gather oracle run batched, so the three paths
-        # agree bitwise (the tier's exactness contract).
+        # int4 tier (g == 1): the shared _int4_flash_step recurrence on 2-D
+        # per-head slices against the dequantized tiles — the same op
+        # sequence the XLA fallback and the gather oracle run batched, so
+        # the three paths agree bitwise (the tier's exactness contract).
         bound = pos_b + jax.lax.broadcasted_iota(jnp.int32, (qr, 1), 0) // rep
         lanes = ks_scr.shape[-1]
         pick = lambda h: (jax.lax.broadcasted_iota(jnp.int32, (ps, lanes), 1)
@@ -635,13 +668,19 @@ def _paged_attn_kernel(
             # by 32-bit shifts (see unpack_nibbles); its per-token scales
             # picked out of the lane-dense row as a [ps, 1] column by a
             # select + lane sum (exact: one nonzero term per row).
-            lo, hi = unpack_nibbles(scr[slot, h])
-            col = jnp.sum(jnp.where(pick(h), s_scr[slot], 0.0), axis=1,
+            lo, hi = unpack_nibbles(scr[slot, 0, h])
+            col = jnp.sum(jnp.where(pick(h), s_scr[slot, 0], 0.0), axis=1,
                           keepdims=True)
             return jnp.concatenate([lo, hi], axis=0).astype(jnp.float32) * col
 
         def body(ti, carry):
-            slot, readable = page(ti)
+            slot = ti % 2
+
+            @pl.when(ti + 1 < n_active)
+            def _prefetch():
+                start(ti + 1, 1 - slot)
+
+            (readable,) = wait(ti, slot)
             gpos = ti * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
             vis = (gpos <= bound) & readable
             out = []
@@ -659,40 +698,62 @@ def _paged_attn_kernel(
         out_ref[0] = jnp.concatenate([_int4_finish(*c) for c in res], axis=0)
         return
 
-    # Every head in one pair of matmuls: query rows (head-major) against
-    # the page's keys of all the lane's heads, each row then masked
-    # to its own head's tokens. Row r is head r // QR, query (r % QR) // rep;
-    # key column c is head c // ps, token c % ps of the page.
+    # Every head and page of a block in one pair of matmuls: query rows
+    # (head-major) against the block's keys, each row then masked to its
+    # own head's tokens. Row r is head r // QR, query (r % QR) // rep; key
+    # column c is page c // (KV*ps) of the block, head (c // ps) % KV,
+    # token c % ps of the page.
     row = jax.lax.broadcasted_iota(jnp.int32, (n_rows, n_keys), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (n_rows, n_keys), 1)
-    own = row // qr == col // ps
+    own = row // qr == (col // ps) % kvh
     bound = pos_b + (row % qr) // rep
+    cpos = (col // page_keys) * ps + col % ps  # token offset in the block
+    cpage = jax.lax.broadcasted_iota(jnp.int32, (1, n_keys), 1) // page_keys
     qv = q_ref[0]  # [KV*QR, hd] f32, pre-scaled
 
-    def body(ti, carry):
+    def scale_row(scr, slot, ok_cols):
+        # The block's per-token scales as one [1, G*KV*ps] row, columns in
+        # key order; an unreadable page's are zeroed (a select: a stale or
+        # poisoned slot may hold NaN).
+        rows = [scr[slot, j][:, :page_keys] for j in range(g)]
+        return jnp.where(ok_cols, jnp.concatenate(rows, axis=1), 0.0)
+
+    def body(blk, carry):
         m, l, acc = carry
-        slot, readable = page(ti)
-        kf = k_scr[slot].astype(jnp.float32).reshape(n_keys, hd)
-        vf = v_scr[slot].astype(jnp.float32).reshape(n_keys, hd)
-        if not scaled:  # select: NaN poison dies here
-            kf = jnp.where(readable, kf, 0.0)
-            vf = jnp.where(readable, vf, 0.0)
-        s = jax.lax.dot_general(  # [KV*QR, KV*ps]
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _prefetch():
+            start(blk + 1, 1 - slot)
+
+        ok = wait(blk, slot)
+        ok_cols = cpage < 0
+        for j in range(g):
+            ok_cols = ok_cols | ((cpage == j) & ok[j])
+        if scaled:
+            kf = k_scr[slot].astype(jnp.float32).reshape(n_keys, hd)
+            vf = v_scr[slot].astype(jnp.float32).reshape(n_keys, hd)
+        else:  # select: NaN poison and stale slots die here
+            kf, vf = (jnp.concatenate([
+                jnp.where(ok[j], scr[slot, j].astype(jnp.float32), 0.0)
+                .reshape(page_keys, hd) for j in range(g)], axis=0)
+                for scr in (k_scr, v_scr))
+        s = jax.lax.dot_general(  # [KV*QR, G*KV*ps]
             qv, kf, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         if scaled:
             # int8 pages: the per-token scales act on the scores and on the
-            # softmax weights, lane by lane; a trash page's are zeroed.
-            s = s * jnp.where(readable, ks_scr[slot][:, :n_keys], 0.0)
-        vis = own & (ti * ps + col % ps <= bound) & readable
+            # softmax weights, lane by lane.
+            s = s * scale_row(ks_scr, slot, ok_cols)
+        vis = own & (blk * (g * ps) + cpos <= bound) & ok_cols
         s = s + jnp.where(vis, 0.0, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         if scaled:
-            p = p * jnp.where(readable, vs_scr[slot][:, :n_keys], 0.0)
+            p = p * scale_row(vs_scr, slot, ok_cols)
         acc_new = acc * alpha + jax.lax.dot_general(
             p, vf, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -702,7 +763,7 @@ def _paged_attn_kernel(
     m0 = jnp.full((n_rows, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((n_rows, 1), jnp.float32)
     acc0 = jnp.zeros((n_rows, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_active, body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
     out_ref[0] = acc / jnp.maximum(l, 1e-30)
 
 
@@ -715,29 +776,65 @@ def _scale_rows(scale: jnp.ndarray) -> jnp.ndarray:
     return jnp.pad(rows, ((0, 0), (0, 0), (0, (-kvh * ps) % 128)))
 
 
-def _vmem_bytes(kvh: int, rows: int, hd: int, itemsize: int, ps: int,
-                qr: int, qn: int, scaled: bool) -> int:
-    """Resident VMEM of one program: two page slots of K and V tiles and
-    scale rows (8 sublanes, 128-lane padded), their float32 forms and the
-    scores, and the double-buffered q, out and new-row blocks."""
+def _block_bytes(kvh: int, rows: int, hd: int, itemsize: int, ps: int,
+                 scaled: bool, g: int) -> int:
+    """The resident VMEM of a block of ``g`` pages, whatever the query
+    rows: two block slots of K and V tiles and scale rows (8 sublanes,
+    128-lane padded) and the block's float32 K and V."""
     lanes = -(-kvh * ps // 128) * 128
     page = 2 * kvh * rows * hd * itemsize + (2 * 8 * lanes * 4 if scaled else 0)
+    return 2 * g * page + 2 * g * kvh * ps * hd * 4
+
+
+def _vmem_bytes(kvh: int, rows: int, hd: int, itemsize: int, ps: int,
+                qr: int, qn: int, scaled: bool, g: int) -> int:
+    """Resident VMEM of one program: the block (:func:`_block_bytes`), the
+    scores and softmax weights of a block, and the double-buffered q, out
+    and new-row blocks."""
     r8 = lambda r: -(-r // 8) * 8
-    work = 2 * kvh * ps * hd * 4 + r8(kvh * qr) * lanes * 4 * 2
-    return 2 * page + work + 2 * (2 * r8(kvh * qr) * hd * 4 + 2 * kvh * r8(qn) * hd * 4)
+    keys = -(-g * kvh * ps // 128) * 128
+    rows_bytes = r8(kvh * qr) * keys * 4 * 2 + 2 * (
+        2 * r8(kvh * qr) * hd * 4 + 2 * kvh * r8(qn) * hd * 4)
+    return _block_bytes(kvh, rows, hd, itemsize, ps, scaled, g) + rows_bytes
+
+
+def plan_pages(pool, t: int, vmem_budget_bytes: int = VMEM_BUDGET_BYTES) -> int:
+    """Pages the kernel's flash loop takes per block, for ``pool`` and a
+    table of ``t`` columns (see the module doc).
+
+    At most :data:`BLOCK_TOKENS` tokens and ``t`` pages, halved until the
+    block (:func:`_block_bytes`) fits half the VMEM budget; the other half
+    is left to the query rows. Made from the pool's static shapes alone, so
+    the decode (``Q = 1``) and verify (``Q > 1``) calls of one engine walk
+    the same blocks and sum in the same order. The int4 tier takes one page
+    at a time (its per-page recurrence is the tier's exactness contract).
+    """
+    if pool_kind(pool) == "int4":
+        return 1
+    _, kvh, rows, hd = pool["k"].shape
+    ps = page_size(pool)
+    itemsize = jnp.dtype(pool["k"].dtype).itemsize
+    scaled = "k_scale" in pool
+    g = max(1, min(t, BLOCK_TOKENS // ps))
+    while g > 1 and (_block_bytes(kvh, rows, hd, itemsize, ps, scaled, g)
+                     > vmem_budget_bytes // 2):
+        g //= 2
+    return g
 
 
 def paged_attention_kernel(
-    pool, table, pos, q, k_new, v_new, *, interpret: bool = False
+    pool, table, pos, q, k_new, v_new, *, pages_per_block: int = 0,
+    interpret: bool = False,
 ) -> Tuple:
     """Raw pallas_call. q: [B, Q, H, hd] float (post-RoPE, unscaled);
     k_new/v_new: [B, Q, KV, hd]; table: [B, T] int32; pos: [B] int32.
     Returns (out [B, Q, H, hd] f32, new pool — appended in place via
     input/output aliasing).
 
-    One program a lane: it walks the lane's pages, loads each for all KV
-    heads at once, the next page in flight while this one computes, and
-    attends all heads in one pair of matmuls a page."""
+    One program a lane: it walks the lane's pages in blocks of
+    ``pages_per_block`` (0: :func:`plan_pages`), loads each page for all KV
+    heads at once, the next block in flight while this one computes, and
+    attends all heads and pages of a block in one pair of matmuls."""
     b, qn, h, hd = q.shape
     p_pages, kvh, tile_rows, _ = pool["k"].shape  # ps/2 rows when int4
     ps = page_size(pool)
@@ -746,6 +843,9 @@ def paged_attention_kernel(
     qr = qn * rep
     kind = pool_kind(pool)
     scaled = kind in ("int8", "int4")
+    # A full block is waited on as one slice of the pool: never past its end.
+    g = 1 if kind == "int4" else min(pages_per_block or plan_pages(pool, t),
+                                     p_pages)
 
     # [B, KV*QR, hd] f32 pre-scaled, rows head-major
     q2 = _q_rows(q, kvh).reshape(b, kvh * qr, hd)
@@ -767,8 +867,8 @@ def paged_attention_kernel(
     # Input indices include the 2 scalar-prefetch args (table, pos).
     aliases = {5: 1, 6: 2}
     scratch = [
-        pltpu.VMEM((2, kvh, tile_rows, hd), pdt),  # k page tiles, two slots
-        pltpu.VMEM((2, kvh, tile_rows, hd), pdt),  # v page tiles
+        pltpu.VMEM((2, g, kvh, tile_rows, hd), pdt),  # k tiles, two block slots
+        pltpu.VMEM((2, g, kvh, tile_rows, hd), pdt),  # v tiles
     ]
     if scaled:
         ks2 = _scale_rows(pool["k_scale"])
@@ -778,7 +878,7 @@ def paged_attention_kernel(
         out_specs += [any_spec, any_spec]
         out_shape += [jax.ShapeDtypeStruct(ks2.shape, jnp.float32)] * 2
         aliases.update({7: 3, 8: 4})
-        scratch += [pltpu.VMEM((2, 1, ks2.shape[-1]), jnp.float32)] * 2
+        scratch += [pltpu.VMEM((2, g, 1, ks2.shape[-1]), jnp.float32)] * 2
     scratch += [pltpu.SemaphoreType.DMA((2, 4))]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -790,7 +890,7 @@ def paged_attention_kernel(
     )
     res = pl.pallas_call(
         functools.partial(_paged_attn_kernel, ps=ps, qn=qn, rep=rep, t=t,
-                          kind=kind),
+                          g=g, kind=kind),
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
@@ -818,21 +918,23 @@ def paged_attention(
     """Shape-safe wrapper: fused append + paged flash-decode attention.
 
     Falls back to the gather-free XLA formulation when one program's
-    resident tiles (:func:`_vmem_bytes`, all KV heads of a lane) would not
-    fit the VMEM budget. Dispatching between this and the
+    resident tiles (:func:`_vmem_bytes`, a block of pages for all KV heads
+    of a lane) would not fit the VMEM budget. Dispatching between this and the
     XLA/gather paths lives in :func:`repro.kernels.ops.paged_attention`.
     """
     b, qn, h, hd = q.shape
     _, kvh, rows, _ = pool["k"].shape  # rows: ps/2 for packed int4
     itemsize = jnp.dtype(pool["k"].dtype).itemsize
     scaled = pool_kind(pool) in ("int8", "int4")
+    g = plan_pages(pool, table.shape[1], vmem_budget_bytes)
     need = _vmem_bytes(kvh, rows, hd, itemsize, page_size(pool),
-                       qn * (h // kvh), qn, scaled)
+                       qn * (h // kvh), qn, scaled, g)
     if need > vmem_budget_bytes:
         reroute("paged_attention", pool["k"].shape, need, vmem_budget_bytes)
         return paged_attention_xla(
             pool, table, pos, q, k_new, v_new, block_tokens=block_tokens
         )
     return paged_attention_kernel(
-        pool, table, pos, q, k_new, v_new, interpret=interpret
+        pool, table, pos, q, k_new, v_new, pages_per_block=g,
+        interpret=interpret,
     )

@@ -89,9 +89,9 @@ float model in low precision. This engine is that provider's serving loop:
   ``jit_serving_prefill``, ``jit_serving_prefill_chunk``,
   ``jit_serving_replay``); ``EngineConfig.profile_dir`` wraps
   :meth:`ServingEngine.run` in a ``jax.profiler`` trace window;
-* **stats** — a typed :class:`EngineStats` (schema v12: v8 plus the
-  precision-tier fields and the held-expert counters, less four unread
-  ones), *derived from the metrics registry* —
+* **stats** — a typed :class:`EngineStats` (schema v13: v8 plus the
+  precision-tier fields, the held-expert counters and the attention
+  kernel's pages per block, less four unread ones), *derived from the metrics registry* —
   percentiles come from registry histograms, counts from registry
   counters; ``stats()`` keeps returning the flat dict view and
   :meth:`ServingEngine.metrics_text` renders the same registry as
@@ -114,6 +114,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels import paged_attention as pa
 from repro.models import attention as attn_mod
 from repro.models import layers
 from repro.models import moe as moe_mod
@@ -248,14 +249,17 @@ class TokenEvent:
 
 @dataclasses.dataclass
 class EngineStats:
-    """Typed serving counters (stats schema v12).
+    """Typed serving counters (stats schema v13).
 
     The dict view (:meth:`as_dict`, what ``ServingEngine.stats()`` returns)
     is the stable cross-PR schema consumed by benchmarks — append fields,
-    never rename. v12 adds the held-expert counters of a moe model (0
-    elsewhere), each ``_decode`` and ``_prefill``: ``moe_rows`` (routed rows
-    its held experts computed), ``moe_experts_touched`` ((layer, call, held
-    expert) triples with a row) and ``moe_dropped`` (held assignments left
+    never rename. v13 adds ``attn_pages_per_block``: the pages each step
+    of the Pallas paged-attention kernel's flash loop takes, where that
+    kernel is the live path (0 elsewhere). v12 adds the held-expert
+    counters of a moe model (0 elsewhere), each ``_decode`` and
+    ``_prefill``: ``moe_rows`` (routed rows its held experts computed),
+    ``moe_experts_touched`` ((layer, call, held expert) triples with a row)
+    and ``moe_dropped`` (held assignments left
     without a row: 0, the layer is dropless). v11 removed four fields no
     consumer read: ``prefill_time_s`` and ``decode_time_s`` (the warm wall
     times stay engine attributes, behind ``prefill_tok_per_s`` /
@@ -332,6 +336,7 @@ class EngineStats:
     kv_bytes_per_token: float = 0.0
     kv_pool_capacity_tokens: float = 0.0
     attn_step_ms: float = 0.0
+    attn_pages_per_block: float = 0.0
     spec_rounds: float = 0.0
     spec_k: float = 0.0
     spec_proposed: float = 0.0
@@ -2539,6 +2544,16 @@ class ServingEngine:
             return "xla"
         return self.attn_kernel
 
+    def _attn_pages_per_block(self) -> float:
+        """Pages each step of the Pallas paged-attention kernel's flash loop
+        takes (:func:`repro.kernels.paged_attention.plan_pages`), where that
+        kernel is the live decode-attention path; 0 elsewhere."""
+        if self._attn_kernel_stat() != "pallas":
+            return 0.0
+        pool = next(layer["attn"] for layer in self.caches["layers"]
+                    if "attn" in layer)
+        return float(pa.plan_pages(pool, self.caches["table"].shape[1]))
+
     def _refresh_gauges(self) -> None:
         """Mirror point-in-time engine state into registry gauges, so the
         Prometheus exposition, the JSONL snapshots, and the v8 stats view
@@ -2650,7 +2665,7 @@ class ServingEngine:
         return self._drift.report() if self._drift is not None else {}
 
     def engine_stats(self) -> EngineStats:
-        """The typed v12 stats record (``stats()`` is its flat dict view),
+        """The typed v13 stats record (``stats()`` is its flat dict view),
         derived from the metrics registry: counts read registry counters
         (through the legacy attribute facade), percentiles read the
         bounded-reservoir registry histograms booked live at the event
@@ -2718,6 +2733,7 @@ class ServingEngine:
             kv_bytes_per_token=gv("kv_bytes_per_token"),
             kv_pool_capacity_tokens=gv("kv_pool_capacity_tokens"),
             attn_step_ms=self._attn_step_ms(),
+            attn_pages_per_block=self._attn_pages_per_block(),
             queue_wait_p50_s=self._hist_qwait.percentile(50),
             queue_wait_p95_s=self._hist_qwait.percentile(95),
             sched_policy=self.config.sched_policy,
@@ -2746,7 +2762,7 @@ class ServingEngine:
         return s
 
     def stats(self) -> Dict:
-        """The flat dict view of :meth:`engine_stats` (stats schema v12)."""
+        """The flat dict view of :meth:`engine_stats` (stats schema v13)."""
         return self.engine_stats().as_dict()
 
 

@@ -219,6 +219,141 @@ def test_q4_rows_equal_sequential_q1():
 
 
 # ---------------------------------------------------------------------------
+# Page blocks: the kernel's flash loop takes G pages a step
+
+G = 3  # pages per block in these cases: several blocks in an 8-page table
+BLOCK_LANES = (1, G - 1, G, G + 1, 2 * G + 1)  # active pages per lane
+TRASH_AT = (4, 1)  # (lane, column): a trash page inside the lane's first block
+
+
+def _mk_block_case(rng, int8, qn, kv, rep, ps=8, hd=16, t=8):
+    """One lane per entry of BLOCK_LANES, each owning that many pages, its
+    last page partly filled, the rest of its table trash. The last lane's
+    column 1 is the trash page: inside its first block, whose slot its
+    third block (one page past its end) finds stale."""
+    b = len(BLOCK_LANES)
+    p = b * t + 1
+    pool = _mk_pool(rng, int8, p, kv, ps, hd)
+    table = np.full((b, t), kvc.TRASH_PAGE, np.int32)
+    pages = iter(range(1, p))
+    pos = []
+    for lane, n in enumerate(BLOCK_LANES):
+        for col in range(n):
+            table[lane, col] = next(pages)
+        pos.append(max(n * ps - qn - lane % 3, 0))
+    table[TRASH_AT] = kvc.TRASH_PAGE
+    h = kv * rep
+    return (
+        pool, jnp.asarray(table), jnp.asarray(pos, jnp.int32),
+        jnp.asarray(rng.randn(b, qn, h, hd), jnp.float32),  # q
+        jnp.asarray(rng.randn(b, qn, kv, hd), jnp.float32),  # k_new
+        jnp.asarray(rng.randn(b, qn, kv, hd), jnp.float32),  # v_new
+    )
+
+
+@pytest.mark.parametrize("kv,rep", [(2, 16), (16, 1)], ids=["gqa", "mha"])
+@pytest.mark.parametrize("qn", [1, 4])
+@pytest.mark.parametrize("int8", [False, True])
+def test_page_blocks_match_gather_oracle_and_xla(kv, rep, qn, int8):
+    """Lanes of 1, G-1, G, G+1 and 2G+1 pages, a trash page inside a
+    block, last blocks partly past their lanes' ends: the blocked kernel
+    agrees with the gather oracle and the XLA fallback, and appends the
+    same pool bitwise."""
+    rng = np.random.RandomState(31 + kv + qn + 2 * int8)
+    args = _mk_block_case(rng, int8, qn, kv, rep)
+    o_ref, p_ref = ops.paged_attention(*args, force="gather")
+    o_xla, p_xla = ops.paged_attention(*args, force="ref")
+    o_krn, p_krn = pa.paged_attention_kernel(*args, pages_per_block=G,
+                                             interpret=True)
+    ref = np.asarray(o_ref)
+    # The tolerances of test_kernel_and_xla_match_gather_oracle.
+    if not int8:
+        np.testing.assert_allclose(np.asarray(o_krn), ref, atol=2e-6, rtol=2e-6)
+        np.testing.assert_allclose(np.asarray(o_xla), ref, atol=2e-6, rtol=2e-6)
+    else:
+        scale = np.abs(ref).max()
+        assert np.abs(np.asarray(o_krn) - ref).max() / scale < 0.02
+        assert np.abs(np.asarray(o_xla) - ref).max() / scale < 0.15
+    for key in p_ref:
+        assert (np.asarray(p_ref[key]) == np.asarray(p_krn[key])).all(), key
+
+
+@pytest.mark.parametrize("int8", [False, True])
+def test_nan_poisoned_trash_page_inside_a_block_does_not_reach_outputs(int8):
+    """The trash page loaded in the middle of a block, poisoned with NaN,
+    and left stale in its slot for a later partial block: no output moves."""
+    rng = np.random.RandomState(37)
+    pool, *rest = _mk_block_case(rng, int8, 1, 2, 2)
+    clean, _ = pa.paged_attention_kernel(pool, *rest, pages_per_block=G,
+                                         interpret=True)
+    dirty, _ = pa.paged_attention_kernel(_poison(pool), *rest,
+                                         pages_per_block=G, interpret=True)
+    assert np.isfinite(np.asarray(dirty)).all()
+    np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
+
+
+@pytest.mark.parametrize("g", [1, 2, G, 8])
+def test_every_block_size_gives_one_answer(g):
+    """One page a block up to the whole table: the same outputs to float
+    tolerance (only the order of summation moves)."""
+    rng = np.random.RandomState(41)
+    args = _mk_block_case(rng, False, 2, 2, 2)
+    o_ref, _ = ops.paged_attention(*args, force="gather")
+    o_krn, _ = pa.paged_attention_kernel(*args, pages_per_block=g,
+                                         interpret=True)
+    np.testing.assert_allclose(np.asarray(o_krn), np.asarray(o_ref),
+                               atol=2e-6, rtol=2e-6)
+
+
+def _pool_shapes(kv, t, lanes, kind="int8", ps=16, hd=128):
+    p = lanes * t + 1
+    if kind == "float":
+        return {"k": jax.ShapeDtypeStruct((p, kv, ps, hd), jnp.float32),
+                "v": jax.ShapeDtypeStruct((p, kv, ps, hd), jnp.float32)}
+    dt, rows = (jnp.int8, ps) if kind == "int8" else (jnp.uint8, ps // 2)
+    return {"k": jax.ShapeDtypeStruct((p, kv, rows, hd), dt),
+            "v": jax.ShapeDtypeStruct((p, kv, rows, hd), dt),
+            "k_scale": jax.ShapeDtypeStruct((p, kv, ps), jnp.float32),
+            "v_scale": jax.ShapeDtypeStruct((p, kv, ps), jnp.float32)}
+
+
+@pytest.mark.parametrize("kv,t,lanes,heads,want", [(2, 256, 8, 32, 16),
+                                                   (16, 32, 32, 16, 8)],
+                         ids=["glm4-9b", "deepseek-moe-16b"])
+def test_plan_pages_fits_the_budget_whatever_q(kv, t, lanes, heads, want):
+    """The block comes from the pool's shapes and the table alone, so decode
+    (Q = 1) and verify (Q = 4) walk the same blocks; with any Q the
+    program's resident bytes stay within the VMEM budget."""
+    pool = _pool_shapes(kv, t, lanes)
+    g = pa.plan_pages(pool, t)
+    assert g == want
+    assert pa.plan_pages(_pool_shapes(kv, t, lanes, "int4"), t) == 1
+    assert pa.plan_pages(pool, 4) == 4  # never more pages than the table
+    for qn in (1, 4):
+        need = pa._vmem_bytes(kv, 16, 128, 1, 16, qn * heads // kv, qn, True, g)
+        assert need <= pa.VMEM_BUDGET_BYTES, (qn, need)
+    # A tighter budget takes smaller blocks before it reroutes.
+    tight = pa._block_bytes(kv, 16, 128, 1, 16, True, g) * 2 - 1
+    assert pa.plan_pages(pool, t, tight) == g // 2
+
+
+def test_decode_and_verify_calls_take_the_same_blocks(monkeypatch):
+    """The wrapper hands the kernel one block size for Q = 1 and Q = 4."""
+    seen = []
+    real = pa.paged_attention_kernel
+
+    def spy(*args, pages_per_block=0, **kw):
+        seen.append(pages_per_block)
+        return real(*args, pages_per_block=pages_per_block, **kw)
+
+    monkeypatch.setattr(pa, "paged_attention_kernel", spy)
+    for qn in (1, 4):
+        rng = np.random.RandomState(43)
+        pa.paged_attention(*_mk_block_case(rng, True, qn, 2, 2), interpret=True)
+    assert seen == [8, 8]  # the whole 8-page table: within BLOCK_TOKENS
+
+
+# ---------------------------------------------------------------------------
 # Trash-page invariant: page 0 poisoned with NaN changes nothing
 
 
@@ -369,3 +504,19 @@ def test_stats_report_attention_path(dense_setup):
     eng2, _ = _run_engine(cfg, params)
     assert eng2.stats()["attn_step_ms"] == 0.0  # probe off by default
     assert eng2.stats()["attn_kernel"] == "gather"  # KernelChoice vocabulary
+
+
+def test_stats_report_pages_per_block(dense_setup, monkeypatch):
+    """Where the Pallas kernel is the live path, stats() names its planned
+    pages per block; the gather and XLA paths read 0."""
+    cfg, params = dense_setup
+    eng, _ = _run_engine(cfg, params, attn="pallas")
+    if jax.default_backend() != "tpu":
+        assert eng.stats()["attn_pages_per_block"] == 0.0  # the XLA loop
+        monkeypatch.setattr(eng, "_attn_kernel_stat", lambda: "pallas")
+    pool = eng.caches["layers"][0]["attn"]
+    want = pa.plan_pages(pool, eng.caches["table"].shape[1])
+    assert want >= 1
+    assert eng.stats()["attn_pages_per_block"] == float(want)
+    eng2, _ = _run_engine(cfg, params)
+    assert eng2.stats()["attn_pages_per_block"] == 0.0  # gather path

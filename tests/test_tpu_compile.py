@@ -101,6 +101,30 @@ def test_paged_attention_compiles_at_mha_head_counts(sds, kv, lanes, qn):
     assert budget.reroutes() == {}
 
 
+@pytest.mark.parametrize("qn", [1, 4])
+@pytest.mark.parametrize("kv,heads,lanes,t,g", [(2, 32, 8, 256, 16),
+                                                (16, 16, 32, 32, 8)],
+                         ids=["glm4-9b.chat", "deepseek-moe-16b.short-chat"])
+def test_paged_attention_blocks_compile_at_cell_shapes(sds, kv, heads, lanes,
+                                                       t, g, qn):
+    """The blocked kernel at the two cells' shapes, int8 pages of 16: 8
+    lanes of 256 pages at 2 KV heads and 32 lanes of 32 pages at 16, the
+    planned blocks (16 and 8 pages) within the budget, no reroute."""
+    budget.clear_reroutes()
+    p = lanes * t + 1
+    pool = {"k": sds((p, kv, PS, HD), jnp.int8),
+            "v": sds((p, kv, PS, HD), jnp.int8),
+            "k_scale": sds((p, kv, PS), jnp.float32),
+            "v_scale": sds((p, kv, PS), jnp.float32)}
+    assert pa.plan_pages(pool, t) == g
+    args = (pool, sds((lanes, t), jnp.int32), sds((lanes,), jnp.int32),
+            sds((lanes, qn, heads, HD), jnp.bfloat16),
+            sds((lanes, qn, kv, HD), jnp.bfloat16),
+            sds((lanes, qn, kv, HD), jnp.bfloat16))
+    assert "tpu_custom_call" in _hlo(pa.paged_attention, *args)
+    assert budget.reroutes() == {}
+
+
 @pytest.mark.parametrize("tokens", [32, 128, 512])
 @pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
 def test_moe_gmm_compiles(sds, tokens, k, n):
